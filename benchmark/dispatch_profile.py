@@ -331,9 +331,9 @@ def bench_fused_step(model="base", steps=20, batch=8, units=0, layers=0,
     # the process pointed at the throwaway cache root, and the tempdir is
     # removed either way.
     import shutil
-    saved_cache_dir = os.environ.get("MXNET_COMPILE_CACHE_DIR")
+    saved_cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     cache_tmp = tempfile.mkdtemp(prefix="mxnet-fused-step-bench-")
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = cache_tmp
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_tmp
     # pin the health diagnostics tail OFF for the whole referee: the
     # committed fused_step_*/telemetry_overhead_*/cost_overhead_*
     # trajectory isolates dispatch amortization, and on this
@@ -350,9 +350,9 @@ def bench_fused_step(model="base", steps=20, batch=8, units=0, layers=0,
     finally:
         mxhealth.enable(None)
         if saved_cache_dir is None:
-            os.environ.pop("MXNET_COMPILE_CACHE_DIR", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
         else:
-            os.environ["MXNET_COMPILE_CACHE_DIR"] = saved_cache_dir
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = saved_cache_dir
         shutil.rmtree(cache_tmp, ignore_errors=True)
 
 

@@ -3,9 +3,8 @@
 Measures the NATIVE path (C++ RecordIO read -> libjpeg decode -> fused
 augment) in ms/batch at several thread counts on THIS host.  On the 1-vCPU
 dev VM this yields the single-core constant plus the (absence of) thread
-overhead — the core-scaling curve for the multi-core claim in
-docs/ROADMAP.md should be refreshed on a many-core box with the same
-script.
+overhead — the core-scaling curve should be refreshed on a many-core box
+with the same script.
 
 ``--record`` appends an ``io_scaling`` record through io_overlap's shared
 atomic-writer helper (``util.write_json_records``; bench.py's rewrite

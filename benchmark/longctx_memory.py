@@ -99,7 +99,7 @@ def main():
     # memory_analysis without the alias table, which would misread the
     # donating lean program's peak on a second invocation
     import tempfile
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = tempfile.mkdtemp(
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
         prefix="mxnet-longctx-bench-")
 
     import numpy as onp

@@ -55,7 +55,7 @@ __all__ = ["CapturedProgram", "GraphPass", "PassPipeline", "DCEPass",
 
 _LOG = logging.getLogger("mxnet_tpu.compile.passes")
 
-#: jit'd marker-function names the quantized layers stage as ``pjit``
+#: jit'd marker-function names the quantized layers stage as ``jit``
 #: equations (contrib/quantization.py) — the int8_residency pass's
 #: pattern anchors.
 QUANTIZE_MARKER = "_mx_quantize_act"
@@ -111,7 +111,7 @@ def _aval_bytes(aval):
 #: ``bytes accessed`` lands in the cost ledger at compile time and stays
 #: the authoritative figure (docs/COMPILE_PASSES.md).
 _BARRIER_PRIMS = frozenset((
-    "dot_general", "conv_general_dilated", "pjit", "custom_jvp_call",
+    "dot_general", "conv_general_dilated", "jit", "custom_jvp_call",
     "custom_vjp_call", "while", "scan", "cond",
 ))
 
@@ -211,15 +211,15 @@ class CapturedProgram:
         return {"flops": float(flops + transc), "bytes": float(byts)}
 
     def eqn_summary(self):
-        """Primitive names in order, pjit markers resolved — the
+        """Primitive names in order, jit markers resolved — the
         structural assertion handle for tests."""
         out = []
         for eqn in self.closed.jaxpr.eqns:
             name = eqn.primitive.name
-            if name == "pjit":
+            if name == "jit":
                 inner = eqn.params.get("name")
                 if inner:
-                    name = f"pjit:{inner}"
+                    name = f"jit:{inner}"
             out.append(name)
         return out
 
@@ -554,14 +554,14 @@ class DCEPass(GraphPass):
 # built-in pass: int8 residency
 # ---------------------------------------------------------------------------
 def _marker_name(eqn):
-    if eqn.primitive.name == "pjit":
+    if eqn.primitive.name == "jit":
         return eqn.params.get("name")
     return None
 
 
 def _is_relu(eqn):
-    """jax.nn.relu stages as custom_jvp_call whose call_jaxpr is a pjit
-    named 'relu' (or a bare max-with-0 on inlining versions)."""
+    """jax.nn.relu stages as custom_jvp_call whose call_jaxpr is a jit
+    named 'relu'."""
     if eqn.primitive.name != "custom_jvp_call" or len(eqn.invars) != 1:
         return False
     inner = eqn.params.get("call_jaxpr")
@@ -570,9 +570,7 @@ def _is_relu(eqn):
     inner = getattr(inner, "jaxpr", inner)
     for e in inner.eqns:
         nm = e.primitive.name
-        if nm == "pjit" and e.params.get("name") == "relu":
-            return True
-        if nm == "max":
+        if nm == "jit" and e.params.get("name") == "relu":
             return True
     return False
 
@@ -582,11 +580,11 @@ class Int8ResidencyPass(GraphPass):
     """Keep layer-to-layer activations int8.
 
     The PTQ layers (contrib/quantization.py) stage their scale handling
-    as named ``pjit`` markers, so a two-quantized-layer program contains
+    as named ``jit`` markers, so a two-quantized-layer program contains
     the bridge::
 
-        ... dot_general(int8) -> pjit:_mx_dequantize_act -> [glue]
-            -> pjit:_mx_quantize_act -> dot_general(int8) ...
+        ... dot_general(int8) -> jit:_mx_dequantize_act -> [glue]
+            -> jit:_mx_quantize_act -> dot_general(int8) ...
 
     where the glue (bias add, relu, reshapes, bf16 round-trips) runs in
     float and costs an HBM round-trip per layer boundary.  This pass

@@ -136,8 +136,8 @@ _MARKERS = None
 def _marker_fns():
     """The jit'd quantize/dequantize helpers shared by every quantized
     layer.  Calling a module-level ``jax.jit`` function inside an outer
-    trace stages ONE named ``pjit`` equation per call, so the captured
-    program carries ``pjit:_mx_quantize_act`` / ``pjit:_mx_dequantize_act``
+    trace stages ONE named ``jit`` equation per call, so the captured
+    program carries ``jit:_mx_quantize_act`` / ``jit:_mx_dequantize_act``
     markers the ``int8_residency`` compile pass
     (``mxnet_tpu.compile.passes``) pattern-matches to fold layer-to-layer
     dequantize->glue->quantize bridges into int8-resident requantizes.

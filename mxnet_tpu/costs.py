@@ -20,10 +20,11 @@ hardware roof is this program running".
   ``costs/ledger_upgrades``).
 * **MFU per execution** — when a flush / serving dispatch runs a program
   the ledger knows, its wall duration turns into achieved FLOP/s and
-  **MFU** against a per-backend peak-FLOP table (``MXNET_PEAK_FLOPS``
-  overrides unknown chips), surfaced as ``costs/*`` metrics and as
-  ``flops=``/``mfu=`` attributes on ``step_flush`` and serving
-  ``execute`` spans (``tools/trace_report.py`` grows the columns).
+  **MFU** against the ``device_kind``-keyed peak table (:data:`PEAKS`;
+  ``MXNET_PEAK_FLOPS`` overrides; the CPU has none), surfaced as
+  ``costs/*`` metrics and as ``flops=``/``mfu=`` attributes on
+  ``step_flush`` and serving ``execute`` spans
+  (``tools/trace_report.py`` grows the columns).
 * **Block-level attribution** — at segment compile time the engine hands
   over the captured op list (each op knows its fun, input avals and the
   originating HybridBlock from the recording-time block scope);
@@ -59,7 +60,8 @@ from .util import getenv
 __all__ = [
     "enabled", "enable", "attribution_enabled", "record_program",
     "ledger", "ledger_entry", "ledger_flops", "hottest_programs",
-    "ledger_upgrades", "peak_flops", "peak_bytes_per_s", "peak_info",
+    "ledger_upgrades", "PEAKS", "peak_flops", "peak_bytes_per_s",
+    "peak_info",
     "record_execution", "execution_attrs", "last_execution",
     "record_pass", "pass_ledger",
     "attribute_segment", "attribution", "attributions",
@@ -99,22 +101,25 @@ def attribution_enabled():
 
 
 # ---------------------------------------------------------------------------
-# peak-FLOP table (per backend, bf16/accumulate peak) + HBM bandwidth.
-# Sources: public TPU spec sheets; the CPU row is a NOMINAL placeholder so
-# MFU stays finite on dev hosts — override with MXNET_PEAK_FLOPS (and
-# MXNET_PEAK_BYTES_PER_S) for unknown chips (docs/OBSERVABILITY.md).
+# Peak table: per-chip bf16 peak FLOP/s and HBM bytes/s, keyed by jax's
+# ``Device.device_kind`` (spellings: jax/_src/pallas/mosaic/tpu_info.py).
+# Source of the figures: Google Cloud TPU documentation, system
+# architecture pages for each generation.  A kind that is not here is an
+# error, not a default — add its row with a source, or set
+# MXNET_PEAK_FLOPS / MXNET_PEAK_BYTES_PER_S.  The host CPU has no row: a
+# CPU run has no utilization (docs/OBSERVABILITY.md).
 # ---------------------------------------------------------------------------
-_PEAK_TABLE = (
-    # (device_kind substring, peak FLOP/s, peak bytes/s)
-    ("v5 lite", 197e12, 819e9),     # v5e: 197 bf16 TFLOP/s, 819 GB/s
-    ("v5e", 197e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-    ("cpu", 1e11, 50e9),            # nominal dev-host placeholder
-)
-_DEFAULT_PEAK = (197e12, 819e9)     # unknown accelerator: v5e figures
+_V5E = (197e12, 819e9)
+_V5P = (459e12, 2765e9)
+PEAKS = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+    "TPU v5": _V5P,
+    "TPU v5p": _V5P,
+}
 
 _peak = [None]                      # (flops, bytes_per_s, source) | None
 
@@ -124,40 +129,36 @@ def _resolve_peak():
     backend's device_kind is consulted ONLY when a backend is already
     live (the same no-backend-contact discipline as
     ``memory._probe_backend`` — resolving a peak must never initialize a
-    device).  Stays unresolved until then."""
+    device).  None while unresolved, and always on the CPU."""
     p = _peak[0]
     if p is not None:
         return p
     env_f = float(getenv("MXNET_PEAK_FLOPS"))
     env_b = float(getenv("MXNET_PEAK_BYTES_PER_S"))
-    kind = None
-    try:
-        from jax._src import xla_bridge as _xb
-        if getattr(_xb, "_backends", None):
-            import jax
-            d = jax.local_devices()[0]
-            kind = f"{d.platform} {getattr(d, 'device_kind', '')}".lower()
-    except Exception:               # noqa: BLE001 — probing must never raise
-        kind = None
-    if kind is None and not (env_f > 0):
-        return None                 # no backend yet, no override: wait
-    flops, bw, source = None, None, None
-    if kind is not None:
-        for sub, f, b in _PEAK_TABLE:
-            if sub in kind:
-                flops, bw, source = f, b, f"table:{sub}"
-                break
-        if flops is None:
-            flops, bw = _DEFAULT_PEAK
-            source = f"default:{kind.strip()}"
+    flops = bw = source = None
+    from jax._src import xla_bridge as _xb
+    if _xb._backends:
+        import jax
+        d = jax.local_devices()[0]
+        if d.platform != "cpu":
+            row = PEAKS.get(d.device_kind)
+            if row is not None:
+                flops, bw = row
+                source = f"table:{d.device_kind}"
+            elif not env_f > 0:
+                from .base import MXNetError
+                raise MXNetError(
+                    f"device_kind {d.device_kind!r} is not in "
+                    f"mxnet_tpu.costs.PEAKS ({sorted(PEAKS)}): add its row "
+                    f"with a source, or set MXNET_PEAK_FLOPS")
     if env_f > 0:
         flops = env_f
         source = "env" if source is None else f"env(+{source})"
+    if flops is None:
+        return None                 # CPU, or no backend yet: no peak
     if env_b > 0:
         bw = env_b
-    if bw is None:
-        bw = _DEFAULT_PEAK[1]
-    p = _peak[0] = (float(flops), float(bw), source)
+    p = _peak[0] = (float(flops), None if bw is None else float(bw), source)
     return p
 
 
@@ -459,12 +460,9 @@ def _eqn_cost(eqn):
     one flop per output element, transcendentals booked separately)."""
     prim = eqn.primitive.name
     # higher-order primitives: recurse into the inner jaxpr
-    if prim in ("pjit", "custom_jvp_call", "custom_vjp_call",
-                "custom_vjp_call_jaxpr", "remat", "checkpoint",
-                "custom_jvp_call_jaxpr", "closed_call", "core_call",
-                "xla_call", "remat_call", "named_call"):
-        inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr") \
-            or eqn.params.get("fun_jaxpr")
+    if prim in ("jit", "custom_jvp_call", "custom_vjp_call", "remat2",
+                "closed_call"):
+        inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if inner is None:
             return 0.0, 0.0
         return jaxpr_cost(getattr(inner, "jaxpr", inner))
@@ -858,5 +856,6 @@ _telemetry.register_collector("costs", _telemetry_collect, {
                                   "accounted execution"),
     "costs/peak_flops": ("gauge",
                          "resolved peak FLOP/s (0 while unresolved — no "
-                         "live backend and no MXNET_PEAK_FLOPS override)"),
+                         "live backend, or the CPU, and no "
+                         "MXNET_PEAK_FLOPS override)"),
 })

@@ -590,32 +590,20 @@ def _aot_compile(jit_fn, raws, label):
     lowered = jit_fn.lower(*raws)
     try:
         key = _compile.fingerprint_lowered(lowered)
-        blob = pc.get(key)
     except Exception:
         return None, None
-    if blob is not None:
-        try:
-            import pickle
-            from jax.experimental import serialize_executable as _se
-            payload, in_tree, out_tree = pickle.loads(blob)
-            exe = _se.deserialize_and_load(payload, in_tree, out_tree)
-            _stats["op_cache_persist_hits"] += 1
-            # warm=True: a deserialized executable's memory_analysis has
-            # no alias table — the ledger flags it so a donating
-            # program's peak is not misread (docs/OBSERVABILITY.md); the
-            # cost ledger flags its analysis the same way
-            _memory.record_program(exe, key=key, label=label or "",
-                                   kind=_persist_kind(label), warm=True)
-            _costs.record_program(exe, key=key, label=label or "",
-                                  kind=_persist_kind(label), warm=True)
-            return exe, key
-        except Exception:
-            # hash-clean blob that will not deserialize (jaxlib rebuild at
-            # the same version string): set aside, fall through to compile
-            try:
-                pc.invalidate(key)
-            except Exception:
-                pass
+    exe = _compile.load_executable(pc, key, lowered)
+    if exe is not None:
+        _stats["op_cache_persist_hits"] += 1
+        # warm=True: a deserialized executable's memory_analysis has
+        # no alias table — the ledger flags it so a donating
+        # program's peak is not misread (docs/OBSERVABILITY.md); the
+        # cost ledger flags its analysis the same way
+        _memory.record_program(exe, key=key, label=label or "",
+                               kind=_persist_kind(label), warm=True)
+        _costs.record_program(exe, key=key, label=label or "",
+                              kind=_persist_kind(label), warm=True)
+        return exe, key
     t0 = time.perf_counter()
     with _telemetry.phase("compile", label=label or ""):
         compiled = lowered.compile()
@@ -631,14 +619,7 @@ def _aot_compile(jit_fn, raws, label):
         # cheap compile: recompiling beats a disk round-trip; jax's own
         # persistent cache (when enabled) still covers it
         return compiled, key
-    try:
-        import pickle
-        from jax.experimental import serialize_executable as _se
-        payload, in_tree, out_tree = _se.serialize(compiled)
-        pc.put(key, pickle.dumps((payload, in_tree, out_tree)),
-               meta={"label": label or "", "kind": _persist_kind(label)})
-    except Exception:
-        pass
+    _pc_store(pc, key, compiled, label)
     return compiled, key
 
 
@@ -654,25 +635,14 @@ def _pc_warm_load(jit_fn, raws):
     lowered = jit_fn.lower(*raws)
     try:
         key = _compile.fingerprint_lowered(lowered)
-        blob = pc.get(key)
     except Exception:
         return None, None, None, None
-    if blob is not None:
-        try:
-            import pickle
-            from jax.experimental import serialize_executable as _se
-            payload, in_tree, out_tree = pickle.loads(blob)
-            exe = _se.deserialize_and_load(payload, in_tree, out_tree)
-            _stats["op_cache_persist_hits"] += 1
-            _memory.record_program(exe, key=key, kind="op", warm=True)
-            _costs.record_program(exe, key=key, kind="op", warm=True)
-            return exe, lowered, key, pc
-        except Exception:
-            try:
-                pc.invalidate(key)
-            except Exception:
-                pass
-    return None, lowered, key, pc
+    exe = _compile.load_executable(pc, key, lowered)
+    if exe is not None:
+        _stats["op_cache_persist_hits"] += 1
+        _memory.record_program(exe, key=key, kind="op", warm=True)
+        _costs.record_program(exe, key=key, kind="op", warm=True)
+    return exe, lowered, key, pc
 
 
 def _pc_store(pc, key, compiled, label):
@@ -680,14 +650,10 @@ def _pc_store(pc, key, compiled, label):
     callers must hand over the compiled artifact (never re-compile just to
     persist; for the slow programs worth persisting that doubles the
     dominant cost)."""
-    try:
-        import pickle
-        from jax.experimental import serialize_executable as _se
-        payload, in_tree, out_tree = _se.serialize(compiled)
-        pc.put(key, pickle.dumps((payload, in_tree, out_tree)),
-               meta={"label": label or "", "kind": _persist_kind(label)})
-    except Exception:
-        pass
+    from . import compile as _compile
+    _compile.store_executable(
+        pc, key, compiled,
+        meta={"label": label or "", "kind": _persist_kind(label)})
 
 
 _vjp_jit_cache: dict = {}

@@ -992,7 +992,7 @@ def _fuse_from():
     the MXU mostly idle, measured in benchmark/r50_stage_sweep.py).
     Tunable via MXNET_R50_FUSE_STAGES: "all" (=1), "none", or a contiguous
     trailing set like "2,3,4" / "4"; default = fastest measured on v5e
-    (table in docs/ROADMAP.md).  Returns 5 for "none" (no fused stages)."""
+    (benchmark/r50_roofline.md).  Returns 5 for "none" (no fused stages)."""
     import os
     env = os.environ.get("MXNET_R50_FUSE_STAGES", "").strip().lower()
     if env in ("", "auto"):

@@ -327,9 +327,6 @@ def ffn_gelu_ref(x3, w1, b1, w2, b2, act="gelu"):
 # ---------------------------------------------------------------------------
 # dispatch + NDArray surface
 # ---------------------------------------------------------------------------
-_check_cache = {}
-
-
 def use_fused_ffn(B, L, units, hidden, dtype="bfloat16", act="gelu",
                   dropout=0.0):
     """True when the fused FFN kernel applies and compiles on this
@@ -340,7 +337,7 @@ def use_fused_ffn(B, L, units, hidden, dtype="bfloat16", act="gelu",
     own executable on first step."""
     import jax
     import jax.numpy as jnp
-    from .flash_attention import kernel_dispatch_allowed
+    from .flash_attention import kernel_dispatch_allowed, probe_compile
     if not kernel_dispatch_allowed():
         return False
     if _pick_rows2d(B * L, units, hidden) is None \
@@ -348,31 +345,28 @@ def use_fused_ffn(B, L, units, hidden, dtype="bfloat16", act="gelu",
         return False
     if act not in ("gelu", "relu"):
         return False
-    key = (B, L, units, hidden, str(dtype), act, float(dropout))
-    hit = _check_cache.get(key)
-    if hit is None:
-        try:
-            dt = jnp.dtype(dtype)
-            xr = jnp.zeros((B, L, units), dt)
-            sd = jnp.zeros((1,), jnp.int32) if dropout > 0 else None
 
-            # probe through jax.grad: compiles the want_u=True forward +
-            # the backward — the EXACT kernel pair a training step runs
-            # (the primal-only kernel is a strict subset)
-            def probe_loss(*a):
-                return ffn_gelu(*a, float(dropout), sd, act) \
-                    .astype(jnp.float32).sum()
+    def compile_fn():
+        dt = jnp.dtype(dtype)
+        xr = jnp.zeros((B, L, units), dt)
+        sd = jnp.zeros((1,), jnp.int32) if dropout > 0 else None
 
-            jax.jit(jax.grad(probe_loss, argnums=(0, 1, 2, 3, 4))) \
-                .lower(xr, jnp.zeros((hidden, units), dt),
-                       jnp.zeros((hidden,), dt),
-                       jnp.zeros((units, hidden), dt),
-                       jnp.zeros((units,), dt)).compile()
-            hit = True
-        except Exception:
-            hit = False
-        _check_cache[key] = hit
-    return hit
+        # probe through jax.grad: compiles the want_u=True forward +
+        # the backward — the EXACT kernel pair a training step runs
+        # (the primal-only kernel is a strict subset)
+        def probe_loss(*a):
+            return ffn_gelu(*a, float(dropout), sd, act) \
+                .astype(jnp.float32).sum()
+
+        jax.jit(jax.grad(probe_loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(xr, jnp.zeros((hidden, units), dt),
+                   jnp.zeros((hidden,), dt),
+                   jnp.zeros((units, hidden), dt),
+                   jnp.zeros((units,), dt)).compile()
+
+    return probe_compile(
+        "ffn_fused_fwd_bwd",
+        (B, L, units, hidden, str(dtype), act, float(dropout)), compile_fn)
 
 
 def ffn_gelu_nd(x3, w1, b1, w2, b2, dropout=0.0, act="gelu"):

@@ -222,9 +222,6 @@ def residual_ln_ref(x3, inner, gamma, beta, eps=1e-12):
             + beta.astype(jnp.float32)).astype(x3.dtype)
 
 
-_check_cache = {}
-
-
 def use_residual_ln(B, L, d, dtype="bfloat16", dropout=0.0,
                     param_dtype=None):
     """True when the fused residual+dropout+LN op applies and compiles on
@@ -237,7 +234,7 @@ def use_residual_ln(B, L, d, dtype="bfloat16", dropout=0.0,
     dot_general)."""
     import jax
     import jax.numpy as jnp
-    from .flash_attention import kernel_dispatch_allowed
+    from .flash_attention import kernel_dispatch_allowed, probe_compile
     if not kernel_dispatch_allowed():
         return False
     itemsize = jnp.dtype(dtype).itemsize
@@ -251,26 +248,23 @@ def use_residual_ln(B, L, d, dtype="bfloat16", dropout=0.0,
         return False
     pdt = jnp.dtype(param_dtype) if param_dtype is not None \
         else jnp.dtype(dtype)
-    key = (B, L, d, str(dtype), float(dropout), str(pdt))
-    hit = _check_cache.get(key)
-    if hit is None:
-        try:
-            dt = jnp.dtype(dtype)
-            xr = jnp.zeros((B, L, d), dt)
-            sd = jnp.zeros((1,), jnp.int32) if dropout > 0 else None
 
-            def probe_loss(*a):
-                return residual_ln(*a, float(dropout), sd) \
-                    .astype(jnp.float32).sum()
+    def compile_fn():
+        dt = jnp.dtype(dtype)
+        xr = jnp.zeros((B, L, d), dt)
+        sd = jnp.zeros((1,), jnp.int32) if dropout > 0 else None
 
-            jax.jit(jax.grad(probe_loss, argnums=(0, 1, 2, 3))) \
-                .lower(xr, xr, jnp.zeros((d,), pdt),
-                       jnp.zeros((d,), pdt)).compile()
-            hit = True
-        except Exception:
-            hit = False
-        _check_cache[key] = hit
-    return hit
+        def probe_loss(*a):
+            return residual_ln(*a, float(dropout), sd) \
+                .astype(jnp.float32).sum()
+
+        jax.jit(jax.grad(probe_loss, argnums=(0, 1, 2, 3))) \
+            .lower(xr, xr, jnp.zeros((d,), pdt),
+                   jnp.zeros((d,), pdt)).compile()
+
+    return probe_compile(
+        "residual_ln_fwd_bwd",
+        (B, L, d, str(dtype), float(dropout), str(pdt)), compile_fn)
 
 
 def residual_ln_nd(x3, inner, gamma, beta, dropout=0.0, eps=1e-12):

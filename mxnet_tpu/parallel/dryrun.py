@@ -129,7 +129,7 @@ def _chained_collective_wall_ms(trainer, reps=24):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from . import global_put, shard_map_compat
+    from . import global_put
 
     mesh = trainer._mesh
     axis = trainer._data_axis
@@ -162,8 +162,8 @@ def _chained_collective_wall_ms(trainer, reps=24):
         return sum(outs)
 
     specs = tuple(P(axis, *([None] * len(s))) for s, _ in shs)
-    fn = jax.jit(shard_map_compat(body, mesh, in_specs=specs,
-                                  out_specs=P()))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=specs,
+                               out_specs=P(), check_vma=False))
     rng = onp.random.RandomState(0)
     xs = [global_put(jnp.asarray(rng.randn(dp, *s).astype("float32")),
                      NamedSharding(mesh, sp))

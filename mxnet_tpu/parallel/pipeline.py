@@ -102,10 +102,11 @@ def spmd_pipeline(stage_fn, stage_params, x, mesh, axis="pipe",
     stage_params = jax.tree_util.tree_map(
         lambda v: _place(v, P(axis)), stage_params)
     x = _place(x, x_spec)
-    from . import shard_map_compat
-    out = shard_map_compat(worker, mesh,
-                           in_specs=(p_specs, x_spec),
-                           out_specs=out_spec)(stage_params, x)
+    # check_vma off: the circulating ppermute produces device-varying
+    # values the replication checker mis-models
+    out = jax.shard_map(worker, mesh=mesh, in_specs=(p_specs, x_spec),
+                        out_specs=out_spec,
+                        check_vma=False)(stage_params, x)
     return out[-1]
 
 

@@ -26,11 +26,17 @@ _CPP_DIR = os.path.normpath(os.path.join(_HERE, "..", "..", "cpp"))
 
 
 def _build():
+    """``make -C cpp``; a failed build is reported with the compiler's
+    message (the Python implementations then serve), never dropped."""
     try:
         subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                       capture_output=True, timeout=120)
+                       capture_output=True, text=True, timeout=120)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        import warnings
+        warnings.warn(f"native runtime build failed, using the Python "
+                      f"implementations: {e}: "
+                      f"{(getattr(e, 'stderr', '') or '')[-2000:]}")
         return False
 
 

@@ -177,6 +177,8 @@ class InferenceEngine:
                 return entry
         if self._kind == "block":
             import jax
+            from .. import compile as _compile
+            _compile.enable_persistent_cache()  # lazy buckets warm-start too
             pure_fn, read_params = self._base
             fn = pure_fn if self._pipeline is None \
                 else self._rewritten_callable(key)

@@ -10,7 +10,7 @@ stack into that service:
   ``InferenceEngine`` → ``DynamicBatcher`` → ``ModelServer`` stack on an
   ephemeral loopback port, warm-starting bucket programs from the
   *shared* on-disk ProgramCache index (point ``spec.env`` at one
-  ``MXNET_COMPILE_CACHE_DIR`` — docs/COMPILE.md) so replica N+1 pays a
+  ``JAX_COMPILATION_CACHE_DIR`` — docs/COMPILE.md) so replica N+1 pays a
   deserialize, not an XLA compile.
 * :class:`ReplicaSupervisor` — spawns the workers, health-checks them
   (heartbeat + progress + ``/healthz`` probe) and restarts crashed or
@@ -341,7 +341,7 @@ class ReplicaSpec:
     or a plain callable.  ``warmup_example`` (per-example arrays, no
     batch dim) warms every bucket at startup; with ``precompile=True``
     the warmup goes through ``InferenceEngine.precompile`` so a fleet
-    sharing one ``MXNET_COMPILE_CACHE_DIR`` (via ``env``) deserializes
+    sharing one ``JAX_COMPILATION_CACHE_DIR`` (via ``env``) deserializes
     yesterday's — or replica 0's — programs instead of recompiling.
     ``apply_weights(model, payload)`` applies a rolling-swap payload; the
     default handles ``HybridBlock`` (a ``{param_name: ndarray}`` dict via

@@ -20,15 +20,12 @@ Covers, all on CPU:
 * the serving integration: ``InferenceEngine(compile_passes=...)``
   parity + ``serving/int8_*`` counters, non-block models degrade with a
   warning;
-* ``util.probe_backend``'s parseable ``tpu_backend_unavailable``
-  fail-fast line (the rc-124 diagnosis regression guard);
 * lint coverage: the new env knob and metric names are seen by
   ``check_env_vars`` / ``check_metric_names`` in both directions.
 
 Heavyweight R50/BERT-geometry drift parities are ``@pytest.mark.slow``
 (tier-1 margin rule, ROADMAP).
 """
-import json
 import os
 import pickle
 import sys
@@ -134,14 +131,14 @@ def test_int8_residency_structure_and_numerics():
     prog, raws, sds = _capture_quantized(qnet)
     before = prog.eqn_summary()
     # the PTQ epilogue round-trips through float between every layer
-    assert before.count("pjit:" + P.DEQUANTIZE_MARKER) == 3
+    assert before.count("jit:" + P.DEQUANTIZE_MARKER) == 3
     pipe = P.resolve_pipeline("int8_residency")
     new, reports = pipe.run(prog, example_args=(raws, *sds), label="int8:t")
     assert reports[0]["changed"] and reports[0]["validated"]
     after = new.eqn_summary()
     # inter-layer dequantize markers folded: only the graph output
     # dequantizes, so layer-to-layer activations stay int8-resident
-    assert after.count("pjit:" + P.DEQUANTIZE_MARKER) == 1
+    assert after.count("jit:" + P.DEQUANTIZE_MARKER) == 1
     assert reports[0]["bytes_after"] < reports[0]["bytes_before"]
     # numerics: rewritten program vs the unrewritten quantized forward
     x = onp.random.RandomState(2).randn(4, 16).astype("float32")
@@ -240,7 +237,7 @@ def test_program_cache_key_distinct_with_passes(tmp_path, monkeypatch):
     import jax.numpy as jnp
     from mxnet_tpu import compile as mxcompile
 
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
     def f(x):
         return jnp.tanh(x @ x.T).sum()
@@ -371,7 +368,7 @@ def test_generation_engine_prefill_pipeline(tmp_path, monkeypatch):
     from mxnet_tpu.models.lm import tiny_lm
     from mxnet_tpu.serving.generate import GenerationEngine
 
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     mx.random.seed(7)
     net = tiny_lm(vocab_size=32, num_layers=1, units=16, hidden_size=32,
                   num_heads=2, max_length=64)
@@ -380,42 +377,16 @@ def test_generation_engine_prefill_pipeline(tmp_path, monkeypatch):
         nd.array(onp.asarray([4], onp.int32)))
 
     eng = GenerationEngine(net, slots=2, max_len=16, prefill_buckets=(8,),
-                           compile_passes="dce", cache="t_passes_gen")
+                           compile_passes="dce")
     toks = list(eng.submit([3, 5, 7], max_new_tokens=4))
     eng.stop()
-    eng2 = GenerationEngine(net, slots=2, max_len=16, prefill_buckets=(8,),
-                            cache="t_passes_gen2")
+    eng2 = GenerationEngine(net, slots=2, max_len=16, prefill_buckets=(8,))
     toks2 = list(eng2.submit([3, 5, 7], max_new_tokens=4))
     eng2.stop()
     assert toks == toks2 and len(toks) == 4
     info = eng.compile_passes_info()
     assert info["spec"] == "dce" and "passes:generate:prefill:L8" \
         in info["programs"]
-
-
-# ---------------------------------------------------------------------------
-# bench fail-fast line (satellite: the rc-124 diagnosis guard)
-# ---------------------------------------------------------------------------
-def test_probe_backend_emits_parseable_fail_fast_line(capfd):
-    from mxnet_tpu.util import probe_backend
-
-    # a subprocess budget this small always trips TimeoutExpired — the
-    # hang case the round-5 rc-124 artifacts made parseable
-    with pytest.raises(MXNetError, match="tpu_backend_unavailable"):
-        probe_backend(timeout_s=0.01)
-    out = capfd.readouterr().out
-    lines = [ln for ln in out.splitlines()
-             if ln.startswith('{"error"')]
-    assert len(lines) == 1, out
-    rec = json.loads(lines[0])
-    assert rec["error"] == "tpu_backend_unavailable"
-    assert "detail" in rec and rec["detail"]
-    # the custom-tag path benches use stays parseable too
-    with pytest.raises(MXNetError):
-        probe_backend(timeout_s=0.01, tag="custom_probe_tag")
-    rec2 = json.loads([ln for ln in capfd.readouterr().out.splitlines()
-                       if ln.startswith('{"error"')][0])
-    assert rec2["error"] == "custom_probe_tag"
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, costs, engine, faults, memory, nd, telemetry
 from mxnet_tpu.gluon import Trainer, loss as gloss, nn
+from mxnet_tpu.base import MXNetError
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _TOOLS = os.path.join(_REPO, "tools")
@@ -114,7 +115,7 @@ def test_ledger_captures_all_three_sites(tmp_path, monkeypatch):
     import jax.numpy as jnp
     from mxnet_tpu import compile as mxcompile
 
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
     def f(x):
         return jnp.tanh(x @ x.T).sum()
@@ -142,7 +143,8 @@ def test_segment_compile_site_and_flush_span_mfu(tmp_path, monkeypatch):
     """The engine's segment-compile site: a fused lazy segment lands in
     the cost ledger under its ProgramCache key, the step_flush/lazy_flush
     span carries flops= and mfu=, and executions are accounted."""
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e11")  # the CPU has no peak
     engine.reset_op_cache()
     costs.reset()
     telemetry.reset()
@@ -165,7 +167,7 @@ def test_segment_compile_site_and_flush_span_mfu(tmp_path, monkeypatch):
     # the cache-HIT flush is a pure execution: flops + mfu + accounting
     args = spans[-1].get("args") or {}
     assert args.get("flops") == int(entries[-1]["flops"])
-    assert args.get("mfu", 0) > 0       # peak resolves: backend is live
+    assert args.get("mfu", 0) > 0       # peak resolves: env override
     assert costs.last_execution()["key"] == entries[-1]["key"]
     snap = telemetry.snapshot()
     assert snap["counters"]["costs/executions"] >= 1
@@ -206,7 +208,7 @@ def test_block_attribution_sums_to_program_total(tmp_path, monkeypatch):
     to within 10% of the program's own cost_analysis() total, and every
     dense layer is attributed to its own block path (forward + backward
     folded together via the VJP CSE correction)."""
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     engine.reset_op_cache()
     costs.reset()
     _captured_steps(layers=4, units=128, batch=16)
@@ -249,7 +251,7 @@ def test_block_scope_helpers_and_tags():
 
 
 def test_attribution_disabled_by_env(tmp_path, monkeypatch):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setenv("MXNET_COST_ATTRIBUTION", "0")
     engine.reset_op_cache()
     costs.reset()
@@ -270,7 +272,7 @@ def test_mfu_referee_dense_ledger_vs_analytic(tmp_path, monkeypatch):
     from mxnet_tpu import parallel
     from mxnet_tpu import optimizer as opt
 
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     B, U, LAYERS = 32, 256, 4
     mx.random.seed(0)
     net = nn.HybridSequential()
@@ -336,13 +338,40 @@ def test_peak_flops_env_override(monkeypatch):
     assert out["mfu"] == pytest.approx(expect, abs=1e-4)
 
 
+def test_cpu_has_no_peak_and_unknown_kind_is_an_error(monkeypatch):
+    """One table keyed by device_kind: the CPU has no utilization (no
+    made-up peak keeps MFU 'finite'), and an accelerator kind that is not
+    in the table is an error, not v5e's figures."""
+    import jax
+    costs.reset()
+    jax.devices()                       # backend live: the peak can resolve
+    assert costs.peak_info() is None and costs.peak_flops() is None
+    compiled, _ = _compiled_tanh_matmul()
+    costs.record_program(compiled, key="q" * 64)
+    assert "mfu" not in costs.record_execution("q" * 64, 1000.0)
+    assert costs.PEAKS["TPU v5 lite"] == (197e12, 819e9)
+
+    class FakeDev:
+        platform, device_kind = "tpu", "TPU v99"
+
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [FakeDev()])
+    costs.reset()
+    with pytest.raises(MXNetError, match="TPU v99"):
+        costs.peak_flops()
+    FakeDev.device_kind = "TPU v5 lite"
+    assert costs.peak_info() == {"flops": 197e12, "bytes_per_s": 819e9,
+                                 "source": "table:TPU v5 lite"}
+    costs.reset()
+
+
 # ---------------------------------------------------------------------------
 # serving execute span
 # ---------------------------------------------------------------------------
 def test_serving_execute_span_carries_flops_and_mfu(tmp_path, monkeypatch):
     from mxnet_tpu import serving
 
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e11")  # the CPU has no peak
     telemetry.reset()
     costs.reset()
     net = nn.HybridSequential()
@@ -370,7 +399,10 @@ def test_serving_execute_span_carries_flops_and_mfu(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 def test_crash_report_costs_section_and_cost_report_render(tmp_path,
                                                            monkeypatch):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    # the roofline needs a peak and the CPU has none: supply one
+    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e11")
+    monkeypatch.setenv("MXNET_PEAK_BYTES_PER_S", "50e9")
     engine.reset_op_cache()
     costs.reset()
     _captured_steps(layers=2, units=32, batch=4)

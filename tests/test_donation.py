@@ -91,7 +91,7 @@ def test_donation_aliases_in_ledger(monkeypatch, tmp_path):
     # fresh ProgramCache root: a warm-loaded (deserialized) executable
     # reports memory_analysis WITHOUT the alias table — the ledger
     # flags it analysis="warm", but this referee needs fresh numbers
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "pc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "pc"))
     def seg_peak():
         segs = [e for e in memory.ledger() if e["kind"] == "step_segment"]
         assert segs, "no step_segment ledger entry"

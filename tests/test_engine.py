@@ -386,7 +386,7 @@ def test_invalid_call_does_not_blacklist_op():
 
 
 def test_op_cache_persists_through_program_cache(monkeypatch, tmp_path):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setenv("MXNET_OP_CACHE_PERSIST_MIN_MS", "0")
     engine.reset_op_cache()
     a, b = _arr((32, 32)), _arr((32, 32), seed=1)

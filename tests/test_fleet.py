@@ -89,6 +89,30 @@ class _ResetStub:
 
 # -- classification ---------------------------------------------------------
 
+def test_fleet_parent_initialises_no_backend():
+    """A chip belongs to one process, and the replicas are the processes
+    that need it: importing ``mxnet_tpu.serving`` and constructing the
+    supervisor and router in the parent must start no jax backend
+    (docs/SERVING.md "Replicas and chips")."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import mxnet_tpu.serving\n"
+        "from mxnet_tpu.serving import fleet\n"
+        "sup = fleet.ReplicaSupervisor("
+        "fleet.ReplicaSpec(model_factory=int), n_replicas=2)\n"
+        "router = fleet.Router(sup)\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, xla_bridge._backends\n"
+        "print('NO_BACKEND')\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env=dict(os.environ, PYTHONPATH=repo))
+    assert r.returncode == 0 and "NO_BACKEND" in r.stdout, r.stderr[-2000:]
+
+
 def test_classify_exit():
     assert faults.classify_exit(None) == faults.TRANSIENT
     assert faults.classify_exit(-9) == faults.TRANSIENT       # SIGKILL

@@ -235,7 +235,7 @@ def test_census_vs_memory_analysis_referee(tmp_path, monkeypatch):
     """The census estimate and XLA's buffer assignment agree within 10%
     on a referee program: a fused lazy segment whose every slot stays
     live, so ledger output+temp bytes == the bytes the census gains."""
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     engine.reset_op_cache()
     memory.reset()
     x = nd.zeros((128, 256))
@@ -265,7 +265,7 @@ def test_census_vs_memory_analysis_referee(tmp_path, monkeypatch):
 
 
 def test_ledger_and_flush_span_bytes(tmp_path, monkeypatch):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     engine.reset_op_cache()
     memory.reset()
     telemetry.reset()
@@ -363,7 +363,7 @@ def test_oom_acceptance_crash_report_and_memory_report(tmp_path,
     produces a crash report whose memory section names the top origin
     classes and the peak-owning ProgramCache key, and
     tools/memory_report.py renders a per-phase peak table from it."""
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "pc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "pc"))
     engine.reset_op_cache()
     memory.reset()
     net = _mlp()

@@ -421,7 +421,7 @@ def test_captured_step_program_cache_warm_restart(tmp_path, monkeypatch):
     from the ProgramCache instead of recompiling (and computes the same
     loss)."""
     env = dict(os.environ)
-    env["MXNET_COMPILE_CACHE_DIR"] = str(tmp_path)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     # force the capture compile over the persistence threshold gate
     env["MXNET_OP_CACHE_PERSIST_MIN_MS"] = "1"

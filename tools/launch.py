@@ -20,6 +20,13 @@ Launchers: ``local`` forks N processes on this machine (the reference's
 nightly-test pattern — multi-node semantics without a cluster); ``ssh``/
 ``mpi`` print the equivalent per-node command for external orchestration
 (cluster schedulers own process placement on TPU pods).
+
+``--launcher local -n N`` is the CPU-collectives path: it pins no chip per
+worker, and a chip belongs to one process, so on a TPU host N workers would
+all reach for the same chips and all but one fail or hang at backend
+start-up.  Run it with ``JAX_PLATFORMS=cpu`` (tests/test_dist_launch.py
+does).  One process drives all the chips of a host through
+``parallel.make_mesh`` — that, not this launcher, is the one-host TPU path.
 """
 import argparse
 import os
