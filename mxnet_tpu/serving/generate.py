@@ -89,6 +89,13 @@ class GenerationMetrics:
             "prefill_cache_hits": 0,
             "decode_compiles": 0,
             "decode_cache_hits": 0,
+            # where spans would cost too much (a token, a request, a
+            # thread): microsecond sums, read as ratios over the counts
+            "loop_offcpu_us": 0,    # stage + emit wall less loop-thread CPU
+            "queue_wait_us": 0,     # submit -> slot taken, over prefills
+            "emit_to_wire_us": 0,   # _emit -> flush returned, per token
+            "stream_write_us": 0,   # json.dumps + write + flush, per token
+            "stream_tokens_written": 0,
         }
         self._gauges = {
             "free_kv_slots": 0,
@@ -105,6 +112,12 @@ class GenerationMetrics:
         with self._lock:
             self._counters[counter] += n
 
+    def add(self, **deltas):
+        """Several counters under one lock acquisition."""
+        with self._lock:
+            for counter, n in deltas.items():
+                self._counters[counter] += n
+
     def set_gauge(self, gauge, value):
         with self._lock:
             self._gauges[gauge] = value
@@ -113,9 +126,17 @@ class GenerationMetrics:
         with self._lock:
             self.ttft.observe(ms)
 
-    def observe_decode_step(self, ms):
+    def record_decode_step(self, riders, step_ms, offcpu_us):
+        """Everything one whole-batch decode step counts, under one lock:
+        ``step_ms`` runs from the start of ``stage`` to the end of
+        ``emit``."""
         with self._lock:
-            self.decode_step.observe(ms)
+            c = self._counters
+            c["decode_steps"] += 1
+            c["tokens_generated"] += riders
+            c["loop_offcpu_us"] += offcpu_us
+            self._gauges["batch_occupancy"] = riders
+            self.decode_step.observe(step_ms)
 
     def stats(self):
         with self._lock:
@@ -199,6 +220,22 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
                                  "miss)"),
     "generate/decode_cache_hits": ("counter",
                                    "decode program-index warm loads"),
+    "generate/loop_offcpu_us": ("counter",
+                                "us the loop thread was runnable and not "
+                                "running in stage + emit (wall less "
+                                "thread CPU time)"),
+    "generate/queue_wait_us": ("counter",
+                               "us from submit to a slot taken, summed "
+                               "over prefills"),
+    "generate/emit_to_wire_us": ("counter",
+                                 "us from a token's emit to its flush "
+                                 "returning, summed over streamed tokens"),
+    "generate/stream_write_us": ("counter",
+                                 "us inside json.dumps + write + flush, "
+                                 "summed over streamed tokens"),
+    "generate/stream_tokens_written": ("counter",
+                                       "token lines written by the HTTP "
+                                       "stream handlers"),
     "generate/free_kv_slots": ("gauge", "unallocated KV-cache slots"),
     "generate/active_streams": ("gauge", "requests in the decode batch"),
     "generate/queue_depth": ("gauge", "admitted requests awaiting a slot"),
@@ -208,7 +245,8 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
                                  "active slots in the latest decode step"),
     "generate/ttft_ms": ("histogram", "submit -> first-token ms"),
     "generate/decode_step_ms": ("histogram",
-                                "whole-batch decode step wall ms"),
+                                "whole-batch decode step wall ms (stage "
+                                "start to emit end)"),
 })
 
 
@@ -233,7 +271,9 @@ class GenerationStream:
 
     # engine-side ----------------------------------------------------------
     def _emit(self, token):
-        self._q.put(int(token))
+        # the queue carries the emit stamp: what a token waits between here
+        # and the socket is summed by whoever writes it out
+        self._q.put((int(token), time.perf_counter_ns()))
 
     def _complete(self, result):
         self._result = result
@@ -250,10 +290,11 @@ class GenerationStream:
     def done(self):
         return self._done.is_set()
 
-    def tokens(self, timeout=None):
-        """Yield token ids as they are generated; raises the generation's
-        error (if any) after the stream closes.  ``timeout`` bounds the
-        wait for EACH token (``TimeoutError`` past it)."""
+    def stamped_tokens(self, timeout=None):
+        """Yield ``(token id, emit stamp)`` as tokens are generated, the
+        stamp in ``time.perf_counter_ns()``; raises the generation's error
+        (if any) after the stream closes.  ``timeout`` bounds the wait for
+        EACH token (``TimeoutError`` past it)."""
         while True:
             try:
                 t = self._q.get(timeout=timeout)
@@ -263,6 +304,11 @@ class GenerationStream:
                 if self._exc is not None:
                     raise self._exc
                 return
+            yield t
+
+    def tokens(self, timeout=None):
+        """:meth:`stamped_tokens` without the stamps."""
+        for t, _emit_ns in self.stamped_tokens(timeout):
             yield t
 
     def __iter__(self):
@@ -491,7 +537,7 @@ class GenerationEngine:
             f"{self._prefill_buckets[-1]} (max_len={self._max_len})")
 
     # -- pure functions (params + caches ride as jit arguments) ------------
-    def _prefill_pure(self):
+    def _prefill_pure(self, bucket):
         import jax
         import jax.numpy as jnp
         from ..gluon.block import _run_with_params
@@ -522,6 +568,8 @@ class GenerationEngine:
                 out += [kc, vc]
             return tuple(out)
 
+        # the jitted module's name in a device trace: jit_pure_prefill_L32
+        pure.__name__ = pure.__qualname__ = f"pure_prefill_L{bucket}"
         return pure
 
     def _decode_pure(self):
@@ -534,7 +582,7 @@ class GenerationEngine:
         key = jax.random.PRNGKey(0)
         model, ps = self._model, self._ps
 
-        def pure(raws, tok, pos, act, *cache_flat):
+        def pure_decode(raws, tok, pos, act, *cache_flat):
             caches = [(NDArray(cache_flat[2 * i]),
                        NDArray(cache_flat[2 * i + 1]))
                       for i in range(len(cache_flat) // 2)]
@@ -553,7 +601,7 @@ class GenerationEngine:
                 out += [unwrap(k), unwrap(v)]
             return tuple(out)
 
-        return pure
+        return pure_decode
 
     def _read_params(self):
         # live read per dispatch (load_parameters hot-swap = jit cache hit)
@@ -572,7 +620,7 @@ class GenerationEngine:
                jax.ShapeDtypeStruct((), onp.int32)]
         sds += [jax.ShapeDtypeStruct(self._cache_shape, onp.float32)
                 for _ in self._cache_flat]
-        fn, extra = self._prefill_pure(), None
+        fn, extra = self._prefill_pure(bucket), None
         if self._pipeline is not None:
             from ..compile import passes as _passes
             label = f"passes:generate:prefill:L{bucket}"
@@ -665,23 +713,27 @@ class GenerationEngine:
     # -- engine loop (single thread owns slots/positions/caches) -----------
     def _loop(self):
         while True:
-            admitted = self._admit_ready()
-            active = [r for r in self._by_slot if r is not None]
-            if not active:
-                if self._closed and self._q.empty():
+            first = None
+            if len(self._free) == self._slots and self._q.empty():
+                # nothing in flight, nothing queued: wait outside any step
+                if self._closed:
                     return
-                if not admitted:
-                    try:
-                        req = self._q.get(timeout=0.05)
-                    except queue.Empty:
-                        continue
+                try:
+                    first = self._q.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+            # one iteration with work = one step; the memory sampler runs
+            # when its envelope closes, never once a phase
+            with _telemetry.step_span("generate", sample_phases=False):
+                if first is not None:
                     self._metrics.set_gauge("queue_depth", self._q.qsize())
-                    self._admit(req)
-                continue
-            self._decode_once(active)
+                    self._admit(first)
+                self._admit_ready()
+                active = [r for r in self._by_slot if r is not None]
+                if active:
+                    self._decode_once(active)
 
     def _admit_ready(self):
-        n = 0
         while self._free:
             try:
                 req = self._q.get_nowait()
@@ -689,10 +741,8 @@ class GenerationEngine:
                 break
             self._metrics.set_gauge("queue_depth", self._q.qsize())
             self._admit(req)
-            n += 1
-        return n
 
-    def _dispatch(self, prog, args, what):
+    def _dispatch(self, prog, raws, args, what):
         """Run one compiled program with transient-failure retries.  Safe
         to retry: the program is functional — scheduler/cache state
         commits only from its returned arrays."""
@@ -705,7 +755,7 @@ class GenerationEngine:
                     # `generate.decode@N:...` fails / delays / kills this
                     # replica mid-generation (docs/RESILIENCE.md)
                     _faults.point("generate.decode")
-                return prog(self._read_params(), *args)
+                return prog(raws, *args)
             except (_faults.TransientFault, ConnectionResetError,
                     TimeoutError):
                 if attempt >= self._decode_retries:
@@ -715,10 +765,20 @@ class GenerationEngine:
 
     def _admit(self, req):
         slot = self._free.pop()
-        self._metrics.inc("slot_allocs")
-        self._metrics.inc("prefills")
+        wait_us = (time.perf_counter() - req.t_submit) * 1e6
+        self._metrics.add(slot_allocs=1, prefills=1,
+                          queue_wait_us=int(wait_us))
         P = int(req.prompt.size)
         bucket = self._bucket_for(P)
+        if req.trace:
+            req.trace.add_span("generate_queue",
+                               _telemetry._wall_us() - int(wait_us), wait_us,
+                               slot=slot)
+        with _telemetry.phase("admit", bucket=bucket, slot=slot,
+                              prompt_len=P):
+            self._admit_into(req, slot, P, bucket)
+
+    def _admit_into(self, req, slot, P, bucket):
         tok = onp.zeros((1, bucket), dtype=onp.int32)
         tok[0, :P] = req.prompt
         vl = onp.asarray([P], dtype=onp.int32)
@@ -726,9 +786,10 @@ class GenerationEngine:
             prog, label = self._compile_prefill(bucket)
             with req.trace.span("generate_prefill", bucket=bucket,
                                 program=label, slot=slot, prompt_len=P):
+                # live read per dispatch (a hot-swap is a jit cache hit)
                 out = self._dispatch(
-                    prog, (tok, vl, onp.int32(slot), *self._cache_flat),
-                    "prefill")
+                    prog, self._read_params(),
+                    (tok, vl, onp.int32(slot), *self._cache_flat), "prefill")
         except Exception as e:      # noqa: BLE001 — fail one request only
             self._free.append(slot)
             self._metrics.inc("slot_frees")
@@ -753,19 +814,32 @@ class GenerationEngine:
 
     def _decode_once(self, active):
         S = self._slots
-        tok = onp.zeros(S, dtype=onp.int32)
-        act = onp.zeros(S, dtype=onp.float32)
-        for r in active:
-            tok[r.slot] = r.generated[-1]
-            act[r.slot] = 1.0
-            if r.t_decode0 is None:
-                r.t_decode0 = time.perf_counter()
-        t0 = time.perf_counter()
         try:
+            # lazy on the first step (ModelServer does not precompile): a
+            # failed compile fails this step's riders like a failed dispatch
             prog, _label = self._compile_decode()
-            out = self._dispatch(
-                prog, (tok, self._positions.copy(), act, *self._cache_flat),
-                "decode")
+            # loop_offcpu_us: wall less this thread's CPU time over the two
+            # phases that never wait for the device (four thread-clock reads)
+            t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+            with _telemetry.phase("stage"):
+                tok = onp.zeros(S, dtype=onp.int32)
+                act = onp.zeros(S, dtype=onp.float32)
+                now = time.perf_counter()
+                for r in active:
+                    tok[r.slot] = r.generated[-1]
+                    act[r.slot] = 1.0
+                    if r.t_decode0 is None:
+                        r.t_decode0 = now
+                pos = self._positions.copy()
+                raws = self._read_params()
+            offcpu_ns = (time.perf_counter_ns() - t0) - (time.thread_time_ns()
+                                                         - c0)
+            with _telemetry.phase("dispatch"):
+                out = self._dispatch(prog, raws,
+                                     (tok, pos, act, *self._cache_flat),
+                                     "decode")
+            with _telemetry.phase("readback"):
+                nxt = onp.asarray(out[0])       # waits for the device
         except Exception as e:      # noqa: BLE001
             # state is uncommitted (functional programs), but a
             # non-transient decode failure has no healthy path forward
@@ -774,27 +848,32 @@ class GenerationEngine:
                 self._release(r)
                 self._fail(r, e)
             return
-        step_ms = (time.perf_counter() - t0) * 1000.0
-        nxt = onp.asarray(out[0])
         self._cache_flat = list(out[1:])
-        self._metrics.inc("decode_steps")
-        self._metrics.inc("tokens_generated", len(active))
-        self._metrics.observe_decode_step(step_ms)
-        self._metrics.set_gauge("batch_occupancy", len(active))
-        for r in active:
-            t = int(nxt[r.slot])
-            self._positions[r.slot] += 1
-            r.steps += 1
-            r.generated.append(t)
-            if not r.wrapped and int(self._positions[r.slot]) >= \
-                    self._max_len:
-                r.wrapped = True
-                self._metrics.inc("cache_wraps")
-            r.stream._emit(t)
-            if r.eos_id is not None and t == r.eos_id:
-                self._complete(r, "eos")
-            elif len(r.generated) >= r.max_new:
-                self._complete(r, "length")
+        t2, c2 = time.perf_counter_ns(), time.thread_time_ns()
+        with _telemetry.phase("emit", riders=len(active)):
+            for r in active:
+                t = int(nxt[r.slot])
+                self._positions[r.slot] += 1
+                r.steps += 1
+                r.generated.append(t)
+                if not r.wrapped and int(self._positions[r.slot]) >= \
+                        self._max_len:
+                    r.wrapped = True
+                    self._metrics.inc("cache_wraps")
+                r.stream._emit(t)
+                if r.eos_id is not None and t == r.eos_id:
+                    self._complete(r, "eos")
+                elif len(r.generated) >= r.max_new:
+                    self._complete(r, "length")
+        t3 = time.perf_counter_ns()
+        offcpu_ns += (t3 - t2) - (time.thread_time_ns() - c2)
+        self._metrics.record_decode_step(len(active), (t3 - t0) / 1e6,
+                                         max(0, offcpu_ns) // 1000)
+        with _telemetry.phase("release"):
+            # the step's arrays go back here and not at this function's
+            # return, so that the time freeing them takes has a name
+            # (docs/OBSERVABILITY.md, `release`)
+            del out, nxt, raws, tok, pos, act
 
     # -- completion --------------------------------------------------------
     def _release(self, req):

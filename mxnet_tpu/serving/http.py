@@ -425,37 +425,51 @@ class _Handler(BaseHTTPRequestHandler):
             self.close_connection = True
             spool()
             return
+        # what a token waits between the engine's emit and the socket, and
+        # what writing it costs: summed here, added to the engine's
+        # counters once when the stream ends (no shared lock per token)
+        i = wire_ns = write_ns = 0
         try:
-            self.send_response(200)
-            self.send_header("Content-Type", "application/x-ndjson")
-            self.send_header("Connection", "close")
-            self.end_headers()
-            i = 0
-            for tok in stream.tokens(timeout=_DEFAULT_RESULT_TIMEOUT_S):
-                self.wfile.write(json.dumps(
-                    {"token": int(tok), "index": i}).encode() + b"\n")
+            try:
+                self.send_response(200)
+                self.send_header("Content-Type", "application/x-ndjson")
+                self.send_header("Connection", "close")
+                self.end_headers()
+                for tok, t_emit in stream.stamped_tokens(
+                        timeout=_DEFAULT_RESULT_TIMEOUT_S):
+                    t_write = time.perf_counter_ns()
+                    self.wfile.write(json.dumps(
+                        {"token": int(tok), "index": i}).encode() + b"\n")
+                    self.wfile.flush()
+                    t_flushed = time.perf_counter_ns()
+                    wire_ns += t_flushed - t_emit
+                    write_ns += t_flushed - t_write
+                    i += 1
+                final = final_payload(
+                    stream.result(timeout=_DEFAULT_RESULT_TIMEOUT_S))
+            except (BrokenPipeError, ConnectionResetError):
+                # client hung up mid-stream; the engine finishes on its own
+                self.close_connection = True
+                spool()
+                return
+            except Exception as e:           # noqa: BLE001
+                # generation died AFTER the 200 + some tokens went out: the
+                # only honest wire move on an unframed stream is a typed
+                # error line (the client raises GenerationStreamBroken)
+                final = {"error": "stream_broken", "detail": str(e),
+                         "trace_id": trace.trace_id if trace else None}
+            try:
+                self.wfile.write(json.dumps(final).encode() + b"\n")
                 self.wfile.flush()
-                i += 1
-            final = final_payload(
-                stream.result(timeout=_DEFAULT_RESULT_TIMEOUT_S))
-        except (BrokenPipeError, ConnectionResetError):
-            # client hung up mid-stream; the engine finishes on its own
+            except (BrokenPipeError, ConnectionResetError):
+                pass
             self.close_connection = True
             spool()
-            return
-        except Exception as e:           # noqa: BLE001
-            # generation died AFTER the 200 + some tokens went out: the
-            # only honest wire move on an unframed stream is a typed
-            # error line (the client raises GenerationStreamBroken)
-            final = {"error": "stream_broken", "detail": str(e),
-                     "trace_id": trace.trace_id if trace else None}
-        try:
-            self.wfile.write(json.dumps(final).encode() + b"\n")
-            self.wfile.flush()
-        except (BrokenPipeError, ConnectionResetError):
-            pass
-        self.close_connection = True
-        spool()
+        finally:
+            if i:
+                gen.metrics.add(emit_to_wire_us=wire_ns // 1000,
+                                stream_write_us=write_ns // 1000,
+                                stream_tokens_written=i)
 
 
 class _FleetHTTPServer(ThreadingHTTPServer):

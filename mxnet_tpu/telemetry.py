@@ -22,7 +22,11 @@ module is the single pane of glass over all of them:
   bounded ring and, when the profiler is running, mirror into the
   chrome-trace dump (``phase/<name>`` events carrying the step id) —
   ``tools/trace_report.py`` folds either source into a per-step phase
-  breakdown table.
+  breakdown table.  The same call site also enters a
+  ``jax.profiler.TraceAnnotation`` named ``mx:<kind>.<phase>``
+  (``mx:<kind>.step`` for the envelope): inside a ``jax.profiler`` session
+  the span lands in the host plane of the ``.xplane.pb`` beside the device
+  ops, on the profiler's clock; outside one it is a check of one flag.
 * **Flight recorder** — the span ring is capped
   (``MXNET_TELEMETRY_RING``) and :func:`flight_recorder_payload` groups
   its tail into a last-K-steps timeline: the ``telemetry`` section of
@@ -55,7 +59,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "counter", "gauge", "histogram", "register_collector", "snapshot",
     "prometheus_text", "enabled", "enable", "phase", "step_boundary",
-    "end_step", "step_span", "current_step", "add_span", "flight_recorder",
+    "end_step", "step_span", "add_span", "flight_recorder",
     "flight_recorder_payload", "serve_metrics", "MetricsServer", "reset",
     "RequestTrace", "NULL_TRACE", "new_trace", "continue_trace",
     "tracing_enabled", "set_trace_sample", "request_scope", "request_span",
@@ -498,10 +502,11 @@ def add_span(phase_name, ts_us, dur_us, step=None, kind=None, **attrs):
     the calling thread's current step id (None outside any step)."""
     if not enabled():
         return
+    sample = True
     if step is None:
         cur = getattr(_tls, "step", None)
         if cur is not None:
-            step, kind = cur[0], cur[1]
+            step, kind, sample = cur[0], cur[1], cur[4]
     rec = {"step": step, "kind": kind, "phase": phase_name,
            "ts_us": int(ts_us), "dur_us": round(float(dur_us), 3),
            "tid": threading.get_ident() % 100000}
@@ -514,7 +519,7 @@ def add_span(phase_name, ts_us, dur_us, step=None, kind=None, **attrs):
         ring.append(rec)
     _SPANS.inc()
     sampler = _mem_sampler[0]
-    if sampler is not None:
+    if sampler is not None and sample:
         # phase-correlated memory sample (docs/OBSERVABILITY.md memory/*):
         # best-effort — observability must never fail the observed step
         try:
@@ -528,6 +533,26 @@ def add_span(phase_name, ts_us, dur_us, step=None, kind=None, **attrs):
             args.update(attrs)
         _profiler.record_event(f"phase/{phase_name}", "phase",
                                int(ts_us), float(dur_us), args=args)
+
+
+_annotation_cls = [None]
+
+
+def _annotate(kind, phase_name, step):
+    """Enter the ``jax.profiler`` sink of a span: a ``TraceAnnotation``
+    named ``mx:<kind>.<phase>`` (``mx:<phase>`` outside any step) that
+    carries the step id.  Outside a profiler session the annotation is a
+    check of one flag; inside one it is an event in the host plane of the
+    same ``.xplane.pb`` as the device ops, on the profiler's clock, under
+    the thread that ran it."""
+    cls = _annotation_cls[0]
+    if cls is None:
+        from jax.profiler import TraceAnnotation as cls
+        _annotation_cls[0] = cls
+    ann = cls(f"mx:{kind}.{phase_name}" if kind else f"mx:{phase_name}",
+              step=step if step is not None else -1)
+    ann.__enter__()
+    return ann
 
 
 class _NullSpan:
@@ -550,13 +575,16 @@ _NULL = _NullSpan()
 
 
 class _Phase:
-    __slots__ = ("_name", "_attrs", "_t0")
+    __slots__ = ("_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, name, attrs):
         self._name = name
         self._attrs = attrs
 
     def __enter__(self):
+        cur = getattr(_tls, "step", None)
+        sid, kind = cur[:2] if cur is not None else (None, None)
+        self._ann = _annotate(kind, self._name, sid)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -568,6 +596,7 @@ class _Phase:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         add_span(self._name, self._t0 // 1000, (t1 - self._t0) / 1000,
                  **self._attrs)
         return False
@@ -580,6 +609,16 @@ def phase(name, **attrs):
     if not enabled():
         return _NULL
     return _Phase(name, attrs)
+
+
+def _open_step(kind, sample_phases=True):
+    """A fresh monotonic step id becomes the calling thread's current
+    step: ``(id, kind, start ns, profiler annotation, sample_phases)``."""
+    sid = next(_step_seq)
+    ann = _annotate(kind, "step", sid)
+    _tls.step = (sid, kind, time.perf_counter_ns(), ann, sample_phases)
+    _STEPS.inc()
+    return sid
 
 
 def step_boundary(kind="train"):
@@ -595,10 +634,7 @@ def step_boundary(kind="train"):
         _tls.step = None
         return None
     end_step()
-    sid = next(_step_seq)
-    _tls.step = (sid, kind, time.perf_counter_ns())
-    _STEPS.inc()
-    return sid
+    return _open_step(kind)
 
 
 def end_step():
@@ -608,8 +644,9 @@ def end_step():
     if cur is None:
         return
     _tls.step = None
-    sid, kind, t0 = cur
+    sid, kind, t0, ann, _sample = cur
     t1 = time.perf_counter_ns()
+    ann.__exit__(None, None, None)
     _STEP_MS.observe((t1 - t0) / 1e6)
     add_span("step", t0 // 1000, (t1 - t0) / 1000, step=sid, kind=kind)
 
@@ -619,16 +656,15 @@ class _StepSpan:
     surrounding step so a serve step nested in a training thread cannot
     orphan the trainer's attribution."""
 
-    __slots__ = ("_kind", "_prev", "step_id")
+    __slots__ = ("_kind", "_sample_phases", "_prev", "step_id")
 
-    def __init__(self, kind):
+    def __init__(self, kind, sample_phases):
         self._kind = kind
+        self._sample_phases = sample_phases
 
     def __enter__(self):
         self._prev = getattr(_tls, "step", None)
-        self.step_id = next(_step_seq)
-        _tls.step = (self.step_id, self._kind, time.perf_counter_ns())
-        _STEPS.inc()
+        self.step_id = _open_step(self._kind, self._sample_phases)
         return self
 
     def __exit__(self, *exc):
@@ -639,17 +675,15 @@ class _StepSpan:
         return False
 
 
-def step_span(kind="serve"):
-    """Context manager for a fully-bracketed step (one serving batch)."""
+def step_span(kind="serve", sample_phases=True):
+    """Context manager for a fully-bracketed step (one serving batch, one
+    iteration of the generation loop).  ``sample_phases=False`` keeps the
+    span-boundary memory sampler off the step's phases: it then runs once
+    a step, when the envelope closes — for loops whose steps are a few
+    milliseconds and whose phases would each pay a ``memory_stats()``."""
     if not enabled():
         return _NULL
-    return _StepSpan(kind)
-
-
-def current_step():
-    """The calling thread's current step id, or None."""
-    cur = getattr(_tls, "step", None)
-    return cur[0] if cur is not None else None
+    return _StepSpan(kind, sample_phases)
 
 
 def flight_recorder():
