@@ -84,6 +84,8 @@ class GenerationMetrics:
             "slot_frees": 0,
             "cache_wraps": 0,       # requests whose ring wrapped (window slid)
             "dispatch_retries": 0,  # transient prefill/decode failures retried
+            "kv_inplace_dispatches": 0,  # dispatches that consumed the rings
+            "kv_ring_rebuilds": 0,  # rings lost to a failure, zeroed anew
             "rejected_queue_full": 0,
             "prefill_compiles": 0,
             "prefill_cache_hits": 0,
@@ -208,6 +210,13 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
     "generate/dispatch_retries": ("counter",
                                   "transient prefill/decode failures "
                                   "retried"),
+    "generate/kv_inplace_dispatches": ("counter",
+                                       "prefill/decode dispatches that "
+                                       "consumed the donated KV rings "
+                                       "(updated in place)"),
+    "generate/kv_ring_rebuilds": ("counter",
+                                  "KV rings reallocated after a failure "
+                                  "that consumed them"),
     "generate/rejected_queue_full": ("counter",
                                      "admission-control fast-rejects"),
     "generate/prefill_compiles": ("counter",
@@ -382,9 +391,9 @@ class GenerationEngine:
         compiles to the loop thread at first use: only safe when nothing
         else touches this model while requests are in flight.
     decode_retries : int
-        Transient-failure retries per prefill/decode dispatch.  Retrying
-        is always safe: programs are functional — cache arrays commit
-        only after a dispatch returns.
+        Transient-failure retries per prefill/decode dispatch.  A retry
+        runs on the same rings, so it happens only while they are alive:
+        the programs consume them (see :meth:`_dispatch`).
     """
 
     def __init__(self, model, slots=None, max_len=None, prefill_buckets=None,
@@ -455,13 +464,7 @@ class GenerationEngine:
                 f"{self._cache_shape}) > MXNET_KV_BUDGET_BYTES={budget} — "
                 "shrink MXNET_KV_SLOTS / MXNET_KV_MAX_LEN or raise the "
                 "budget")
-        self._cache_flat = []
-        from .. import memory as _memory
-        for _ in range(L * 2):
-            buf = jnp.zeros(self._cache_shape, jnp.float32)
-            if _memory._census_active:
-                _memory.tag(buf, "kv_cache")
-            self._cache_flat.append(buf)
+        self._cache_flat = self._zero_rings(L * 2)
         self.kv_cache_bytes = kv_bytes
         self._metrics.set_gauge("kv_cache_bytes", kv_bytes)
         self._metrics.set_gauge("free_kv_slots", S)
@@ -527,6 +530,17 @@ class GenerationEngine:
                 lab: [dict(r) for r in reps]
                 for lab, reps in sorted(self._passes_reports.items())},
         }
+
+    def _zero_rings(self, n):
+        import jax.numpy as jnp
+        from .. import memory as _memory
+        rings = []
+        for _ in range(n):
+            buf = jnp.zeros(self._cache_shape, jnp.float32)
+            if _memory._census_active:
+                _memory.tag(buf, "kv_cache")
+            rings.append(buf)
+        return rings
 
     def _bucket_for(self, n):
         for b in self._prefill_buckets:
@@ -609,6 +623,19 @@ class GenerationEngine:
             return [p._nd._data for p in self._ps]
 
     # -- compilation -------------------------------------------------------
+    def _lower(self, fn, sds):
+        """Lower a serving program with its rings donated: every argument
+        after the weights and the three inputs.  The outputs are then the
+        same buffers, so the program writes only the rows it changes.
+        Never the weights: they live on across steps and are shared with
+        ``load_parameters``."""
+        import jax
+        # donation-recovery: tests/test_generate.py::test_failure_that_consumes_the_rings_fails_riders_and_rebuilds
+        rings = tuple(range(4, 4 + len(self._cache_flat)))
+        with self._trace_lock:
+            return jax.jit(fn, donate_argnums=rings).lower(
+                self._read_params(), *sds)
+
     def _compile_prefill(self, bucket):
         entry = self._prefill_progs.get(bucket)
         if entry is not None:
@@ -635,10 +662,8 @@ class GenerationEngine:
             # brand the cache key even when every rewrite was discarded:
             # a pipeline-on engine must never alias the pipeline-off twin
             extra = self._pipeline.fingerprint()
-        with self._trace_lock:
-            lowered = jax.jit(fn).lower(self._read_params(), *sds)
         compiled, info = _compile.aot_compile_lowered(
-            lowered, cache=self._cache_label,
+            self._lower(fn, sds), cache=self._cache_label,
             label=f"generate:prefill:L{bucket}", extra_key=extra)
         self._metrics.inc("prefill_cache_hits" if info["cache_hit"]
                           else "prefill_compiles")
@@ -657,11 +682,9 @@ class GenerationEngine:
                jax.ShapeDtypeStruct((S,), onp.float32)]
         sds += [jax.ShapeDtypeStruct(self._cache_shape, onp.float32)
                 for _ in self._cache_flat]
-        with self._trace_lock:
-            lowered = jax.jit(self._decode_pure()).lower(
-                self._read_params(), *sds)
         compiled, info = _compile.aot_compile_lowered(
-            lowered, cache=self._cache_label, label="generate:decode")
+            self._lower(self._decode_pure(), sds), cache=self._cache_label,
+            label="generate:decode")
         self._metrics.inc("decode_cache_hits" if info["cache_hit"]
                           else "decode_compiles")
         self._decode_prog = (compiled, "generate:decode")
@@ -742,12 +765,18 @@ class GenerationEngine:
             self._metrics.set_gauge("queue_depth", self._q.qsize())
             self._admit(req)
 
-    def _dispatch(self, prog, raws, args, what):
-        """Run one compiled program with transient-failure retries.  Safe
-        to retry: the program is functional — scheduler/cache state
-        commits only from its returned arrays."""
+    def _dispatch(self, prog, raws, inputs, what):
+        """Run one compiled program on the rings.  It consumes them (they
+        are donated, see :meth:`_lower`): the caller makes the returned
+        rings the engine's once the first output has been read.
+
+        A transient failure is retried in place only while the rings are
+        alive, as they are after the injected fault, which fires before
+        the call.  Whatever else fails, here or at the read, goes to
+        :meth:`_rings_lost`."""
         from .. import faults as _faults
         attempt = 0
+        rings = self._cache_flat
         while True:
             try:
                 if what == "decode":
@@ -755,13 +784,36 @@ class GenerationEngine:
                     # `generate.decode@N:...` fails / delays / kills this
                     # replica mid-generation (docs/RESILIENCE.md)
                     _faults.point("generate.decode")
-                return prog(raws, *args)
+                out = prog(raws, *inputs, *rings)
             except (_faults.TransientFault, ConnectionResetError,
                     TimeoutError):
-                if attempt >= self._decode_retries:
+                if attempt >= self._decode_retries \
+                        or any(r.is_deleted() for r in rings):
                     raise
                 attempt += 1
                 self._metrics.inc("dispatch_retries")
+                continue
+            if rings[0].is_deleted():
+                self._metrics.inc("kv_inplace_dispatches")
+            return out
+
+    def _rings_lost(self):
+        """After a failed dispatch or read: did it take the rings?  While
+        every ring is alive the failure came before a program took them
+        and nothing is lost.  Otherwise the keys and values of every slot
+        went with them: the engine serves on from fresh zero rings, and
+        the caller fails every request that holds a slot."""
+        if not any(r.is_deleted() for r in self._cache_flat):
+            return False
+        self._cache_flat = self._zero_rings(len(self._cache_flat))
+        self._metrics.inc("kv_ring_rebuilds")
+        return True
+
+    def _fail_riders(self, exc):
+        for r in self._by_slot:
+            if r is not None:
+                self._release(r)
+                self._fail(r, exc)
 
     def _admit(self, req):
         slot = self._free.pop()
@@ -789,13 +841,17 @@ class GenerationEngine:
                 # live read per dispatch (a hot-swap is a jit cache hit)
                 out = self._dispatch(
                     prog, self._read_params(),
-                    (tok, vl, onp.int32(slot), *self._cache_flat), "prefill")
-        except Exception as e:      # noqa: BLE001 — fail one request only
+                    (tok, vl, onp.int32(slot)), "prefill")
+            first = int(out[0])             # waits for the device
+        except Exception as e:      # noqa: BLE001 — fail one request only,
+            # unless the rings went with it
+            out = None      # e's traceback keeps this frame, not the rings
             self._free.append(slot)
             self._metrics.inc("slot_frees")
+            if self._rings_lost():
+                self._fail_riders(e)
             self._fail(req, e)
             return
-        first = int(out[0])
         self._cache_flat = list(out[1:])
         req.slot = slot
         req.t_first = time.perf_counter()
@@ -835,18 +891,16 @@ class GenerationEngine:
             offcpu_ns = (time.perf_counter_ns() - t0) - (time.thread_time_ns()
                                                          - c0)
             with _telemetry.phase("dispatch"):
-                out = self._dispatch(prog, raws,
-                                     (tok, pos, act, *self._cache_flat),
-                                     "decode")
+                out = self._dispatch(prog, raws, (tok, pos, act), "decode")
             with _telemetry.phase("readback"):
                 nxt = onp.asarray(out[0])       # waits for the device
         except Exception as e:      # noqa: BLE001
-            # state is uncommitted (functional programs), but a
-            # non-transient decode failure has no healthy path forward
-            # for the riders — fail them honestly, keep serving
-            for r in active:
-                self._release(r)
-                self._fail(r, e)
+            # a non-transient decode failure has no healthy path forward
+            # for the riders, with or without their rings — fail them
+            # honestly, keep serving
+            out = None      # e's traceback keeps this frame, not the rings
+            self._rings_lost()
+            self._fail_riders(e)        # `active` is every rider
             return
         self._cache_flat = list(out[1:])
         t2, c2 = time.perf_counter_ns(), time.thread_time_ns()

@@ -538,6 +538,66 @@ def test_engine_block_precompile_parallel_and_serve(cache_dir):
     onp.testing.assert_allclose(eng.run_batch([x])[0], 0.0, atol=1e-6)
 
 
+def _generation_engine(seed):
+    from mxnet_tpu.models.lm import tiny_lm
+    from mxnet_tpu.serving.generate import GenerationEngine
+    mx.random.seed(seed)
+    net = tiny_lm(vocab_size=32, num_layers=2, units=16, hidden_size=32,
+                  num_heads=2, max_length=32)
+    net.initialize()
+    net(nd.array(onp.zeros((1, 4), onp.int32)),
+        nd.array(onp.asarray([4], onp.int32)))
+    return GenerationEngine(net, slots=2, max_len=16, prefill_buckets=(8,))
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_donated_serving_program_warm_loads_and_consumes(cache_dir, program):
+    """The generation engine's programs take the KV rings as donated
+    arguments.  Read back from the ProgramCache they still consume them,
+    and the donor attributes are in the key: the undonated lowering of the
+    same function is another program."""
+    import jax
+    cold = _generation_engine(seed=7)
+    try:
+        want = cold.generate([3, 1, 4], max_new_tokens=4, timeout=120)
+        c = cold.metrics.stats()["counters"]
+        assert c[program + "_compiles"] == 1
+    finally:
+        cold.stop()
+    warm = _generation_engine(seed=7)
+    try:
+        c = warm.metrics.stats()["counters"]
+        assert c[program + "_cache_hits"] == 1
+        assert c[program + "_compiles"] == 0
+        rings = list(warm._cache_flat)
+        got = warm.generate([3, 1, 4], max_new_tokens=1 if program ==
+                            "prefill" else 4, timeout=120)
+        assert got["tokens"] == want["tokens"][:len(got["tokens"])]
+        assert all(r.is_deleted() for r in rings)
+        c = warm.metrics.stats()["counters"]
+        assert c["kv_inplace_dispatches"] == c["prefills"] + c["decode_steps"]
+        # the same function and shapes, lowered with and without donors
+        S = warm.slots
+        if program == "decode":
+            fn = warm._decode_pure()
+            inputs = [((S,), onp.int32), ((S,), onp.int32),
+                      ((S,), onp.float32)]
+        else:
+            fn = warm._prefill_pure(8)
+            inputs = [((1, 8), onp.int32), ((1,), onp.int32), ((), onp.int32)]
+        sds = [jax.ShapeDtypeStruct(sh, dt) for sh, dt in inputs]
+        sds += [jax.ShapeDtypeStruct(warm._cache_shape, onp.float32)
+                for _ in rings]
+        donated = mxcompile.fingerprint_lowered(warm._lower(fn, sds))
+        plain = mxcompile.fingerprint_lowered(
+            jax.jit(fn).lower(warm._read_params(), *sds))
+        pc = mxcompile.default_program_cache()
+        keys = {e["key"] for e in pc.entries()}
+        assert donated in keys and plain not in keys
+    finally:
+        warm.stop()
+
+
 def test_engine_precompile_rejects_unknown_bucket(cache_dir):
     eng = serving.InferenceEngine(_mlp(seed=6), batch_buckets=(1, 2))
     with pytest.raises(mx.MXNetError):

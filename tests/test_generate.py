@@ -116,6 +116,11 @@ def test_concurrent_churn_compiles_once_and_keeps_parity(lm):
         assert c["prefill_compiles"] + c["prefill_cache_hits"] == 2
         assert c["decode_compiles"] + c["decode_cache_hits"] == 1
         assert c["slot_allocs"] == 7 and c["slot_frees"] == 7
+        # every dispatch consumed the rings it was given (they are updated
+        # in place) and none was lost
+        assert c["prefills"] == 7 and c["decode_steps"] > 0
+        assert c["kv_inplace_dispatches"] == c["decode_steps"] + c["prefills"]
+        assert c["kv_ring_rebuilds"] == 0
     finally:
         eng.stop()
 
@@ -163,6 +168,38 @@ def test_cache_wraparound_is_a_sliding_window():
     assert not onp.allclose(a[M - 1], b[M - 1])
     for p in range(M, steps):                 # ...until the ring evicts it
         onp.testing.assert_allclose(a[p], b[p], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("active", [None, "ones", "mixed", "zeros"])
+def test_decode_step_writes_one_row_a_sequence(active):
+    # the ring-write contract, whatever writes it: a writing sequence gets
+    # its new key and value at position % M (past M: the ring wraps) and
+    # no other entry of any ring moves; a gated sequence changes nothing
+    from mxnet_tpu.models.bert import MultiHeadAttention
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    mx.random.seed(13)
+    B, H, M, D = 5, 2, 6, 4
+    att = MultiHeadAttention(H * D, H, causal=True)
+    att.initialize()
+    rng = onp.random.RandomState(3)
+    x = rng.randn(B, 1, H * D).astype("float32")
+    k0 = rng.randn(B, H, M, D).astype("float32")
+    v0 = rng.randn(B, H, M, D).astype("float32")
+    pos = onp.asarray([0, 5, 6, 3, 2 * M + 1], onp.int32)  # 6, 13: wrapped
+    gate = {None: None, "ones": onp.ones(B, "float32"),
+            "mixed": onp.asarray([1, 0, 1, 0, 1], "float32"),
+            "zeros": onp.zeros(B, "float32")}[active]
+    _out, k1, v1 = att.decode_step(
+        NDArray(x), NDArray(k0), NDArray(v0), NDArray(pos),
+        active=None if gate is None else NDArray(gate))
+    qkv = att.qkv(NDArray(x)).asnumpy().reshape(B, 3, H, D)
+    want_k, want_v = k0.copy(), v0.copy()
+    for b in range(B):
+        if gate is None or gate[b] > 0:
+            want_k[b, :, pos[b] % M] = qkv[b, 1]
+            want_v[b, :, pos[b] % M] = qkv[b, 2]
+    onp.testing.assert_array_equal(k1.asnumpy(), want_k)
+    onp.testing.assert_array_equal(v1.asnumpy(), want_v)
 
 
 def test_engine_wraparound_counts_and_stays_deterministic(lm):
@@ -273,6 +310,92 @@ def test_generate_decode_permanent_fault_fails_one_request(lm):
         # the engine keeps serving after failing that one request
         got = eng.generate([3, 1, 4], max_new_tokens=3, timeout=120)
         assert got["tokens"] == _full_forward_greedy(lm, [3, 1, 4], 3)
+    finally:
+        eng.stop()
+
+
+# -- the rings are donated: updated in place, lost only with a failure ------
+
+def _ring_buffers(eng):
+    import jax
+    return [a for a in jax.live_arrays()
+            if a.shape == eng._cache_shape and not a.is_deleted()]
+
+
+def test_rings_are_consumed_and_no_second_copy_is_kept():
+    # a model of its own: the module's engines must not share this shape
+    net = _lm(units=48, heads=3, seed=5)
+    eng = _engine(net, slots=3, max_len=32, prefill_buckets=(8,))
+    try:
+        n = 2 * net.num_layers
+        before = list(eng._cache_flat)
+        assert len(_ring_buffers(eng)) == n
+        got = eng.generate([3, 1, 4, 1], max_new_tokens=5, timeout=120)
+        assert got["tokens"] == _full_forward_greedy(net, [3, 1, 4, 1], 5)
+        assert all(r.is_deleted() for r in before)
+        assert all(not r.is_deleted() for r in eng._cache_flat)
+        assert len(_ring_buffers(eng)) == n
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("where", ["decode", "prefill"])
+def test_failure_that_consumes_the_rings_fails_riders_and_rebuilds(lm, where):
+    # a program that dies after it has taken its donated inputs: the keys
+    # and values of every slot are gone, so every rider fails, the engine
+    # allocates fresh rings and the next request is served correctly
+    eng = _engine(lm)
+    try:
+        rider = eng.submit([5, 6, 7], max_new_tokens=40)
+        next(iter(rider.tokens(timeout=120)))         # rider holds a slot
+        real = eng._decode_prog if where == "decode" \
+            else eng._prefill_progs[8]
+
+        def dies(raws, *args):
+            for ring in args[3:]:
+                ring.delete()
+            raise RuntimeError("device fell over mid-program")
+
+        if where == "decode":
+            eng._decode_prog = (dies, real[1])
+        else:
+            eng._prefill_progs[8] = (dies, real[1])
+            victim = eng.submit([1, 2], max_new_tokens=3)
+            with pytest.raises(RuntimeError, match="fell over"):
+                victim.result(timeout=120)
+        with pytest.raises(RuntimeError, match="fell over"):
+            rider.result(timeout=120)
+        if where == "decode":
+            eng._decode_prog = real
+        else:
+            eng._prefill_progs[8] = real
+        c = eng.metrics.stats()["counters"]
+        assert c["kv_ring_rebuilds"] == 1
+        assert c["errors"] == (1 if where == "decode" else 2)
+        assert c["slot_allocs"] == c["slot_frees"]
+        assert all(not r.is_deleted() for r in eng._cache_flat)
+        got = eng.generate([3, 1, 4], max_new_tokens=4, timeout=120)
+        assert got["tokens"] == _full_forward_greedy(lm, [3, 1, 4], 4)
+        assert eng.metrics.stats()["counters"]["kv_ring_rebuilds"] == 1
+    finally:
+        eng.stop()
+
+
+def test_failure_before_the_call_keeps_the_rings(lm):
+    # the permanent injected fault fires before the program runs: the
+    # rider fails, the rings live on and nothing is rebuilt
+    eng = _engine(lm)
+    try:
+        before = list(eng._cache_flat)
+        with faults.inject("generate.decode@1:permanent"):
+            with pytest.raises(Exception):
+                eng.generate([3, 1, 4], max_new_tokens=5, timeout=120)
+        c = eng.metrics.stats()["counters"]
+        assert c["kv_ring_rebuilds"] == 0
+        # the prefill consumed the first rings; its outputs are the ones
+        # the failed step left alone
+        assert all(r.is_deleted() for r in before)
+        assert all(not r.is_deleted() for r in eng._cache_flat)
     finally:
         eng.stop()
 
