@@ -18,3 +18,4 @@ from .yolo import (  # noqa: F401
     DarknetV3, darknet53, YOLOV3, YOLOV3Loss, yolo3_targets,
     yolo3_darknet53_voc, yolo3_darknet53_coco, yolo3_tiny,
 )
+from .deepseek import DeepSeekV32LM, tiny_v32  # noqa: F401

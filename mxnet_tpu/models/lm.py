@@ -57,6 +57,15 @@ class TransformerLM(HybridBlock):
     def units(self):
         return self._units
 
+    def cache_spec(self, max_len):
+        """What the generation engine keeps for this model: for each layer
+        the ``(kind, trailing shape, dtype)`` of its rings, a key and a
+        value ring of (H, M, D) float32 (docs/SERVING.md)."""
+        H = self.num_heads
+        shape = (H, int(max_len), self._units // H)
+        return [[("key", shape, "float32"), ("value", shape, "float32")]
+                for _ in range(self.num_layers)]
+
     def _embed(self, tokens):
         """Token + learned-position embedding for a left-aligned batch."""
         x = self.embed(tokens)

@@ -1125,7 +1125,7 @@ from .ring_attention import ring_attention as ring_attention_fn  # noqa: E402,F4
 from . import pipeline  # noqa: E402,F401
 from .pipeline import spmd_pipeline, GPipe  # noqa: E402,F401
 from . import moe  # noqa: E402,F401
-from .moe import MoE, moe_sharding_rules  # noqa: E402,F401
+from .moe import MoE, DroplessMoE, moe_sharding_rules  # noqa: E402,F401
 
 from .. import telemetry as _telemetry_mod  # noqa: E402
 

@@ -14,6 +14,11 @@ Pieces:
   [T,E,C] combine tensor + load-balance aux loss.
 - :class:`MoE` — Gluon ``HybridBlock`` position-wise FFN MoE layer; expert
   weights are stacked ``(E, ...)`` so one regex rule shards them.
+- :func:`noaux_route` / :func:`dropless_experts` / :class:`DroplessMoE` —
+  the other kind of layer: sigmoid scores with a selection bias,
+  group-limited top-k, no capacity and so no dropped pair, one grouped
+  product a projection (``jax.lax.ragged_dot``) over the experts this chip
+  *holds* of ``num_experts``, and a shared expert.
 - :func:`moe_sharding_rules` — ``shard_params`` rules for the EP axis.
 - :func:`aux_loss_scope` — collects router aux losses during a forward so
   the training loss can add them (pure-function-friendly: the collected
@@ -29,7 +34,8 @@ from ..gluon.parameter import Parameter
 from .. import initializer as init
 
 __all__ = ["MoE", "moe_dispatch", "moe_sharding_rules", "aux_loss_scope",
-           "collected_aux_loss"]
+           "collected_aux_loss", "DroplessMoE", "noaux_route",
+           "dropless_experts", "dropless_moe", "held_load", "swiglu"]
 
 _moe_tls = threading.local()
 
@@ -209,15 +215,187 @@ class MoE(HybridBlock):
         return y2d.reshape(shape)
 
 
+# ---------------------------------------------------------------------------
+# dropless routing over the experts held here (DeepSeek-V3 style)
+# ---------------------------------------------------------------------------
+def noaux_route(scores, bias, k, n_group=1, topk_group=1, route_scale=1.0):
+    """Group-limited top-k of ``scores`` [T, E] (sigmoid, float32) by
+    ``scores + bias``: a group's score is the sum of its two largest
+    biased scores, the best ``topk_group`` of ``n_group`` groups stay, and
+    the ``k`` largest biased scores among their experts are chosen.  Gates
+    are the *unbiased* scores of the chosen, normalised to sum to
+    ``route_scale``.  Returns ``(idx [T, k] int32, gates [T, k])``."""
+    import jax
+    import jax.numpy as jnp
+
+    T, E = scores.shape
+    biased = scores + bias[None, :]
+    if n_group > 1:
+        g = biased.reshape(T, n_group, E // n_group)
+        group_score = jax.lax.top_k(g, 2)[0].sum(-1)            # [T, G]
+        _, keep = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        biased = jnp.where(kept[:, :, None], g, -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(biased, k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = route_scale * chosen / chosen.sum(-1, keepdims=True)
+    return idx.astype(jnp.int32), gates
+
+
+def swiglu(x, w1, w3, w2):
+    """``W2(SiLU(W1 x) * W3 x)`` with weights stored [in, out]; products
+    accumulate in float32, activations keep ``x``'s type."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    h = jax.nn.silu(jnp.dot(x, w1, preferred_element_type=f32)) \
+        * jnp.dot(x, w3, preferred_element_type=f32)
+    return jnp.dot(h.astype(x.dtype), w2, preferred_element_type=f32)
+
+
+def dropless_experts(x2d, idx, gates, w1, w3, w2, first):
+    """What the experts ``first .. first + count`` (the stacks' leading
+    axis) add for tokens ``x2d`` [T, d] routed by ``idx`` / ``gates``
+    [T, k]: every pair whose expert is held is computed, whatever the
+    load.  Pairs are sorted by held expert (the others last), and each
+    projection is one ``ragged_dot`` over the stack.  float32 [T, d]."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    T, k = idx.shape
+    count = w1.shape[0]
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)
+    order = jnp.argsort(key)                          # stable
+    token = (jnp.arange(T * k, dtype=jnp.int32) // k)[order]
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    xs = x2d[token]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,
+                                       preferred_element_type=f32)) \
+        * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
+    y = jax.lax.ragged_dot(h.astype(x2d.dtype), w2, sizes,
+                           preferred_element_type=f32)
+    # rows past the held pairs belong to no group: whatever is there is
+    # not a result
+    g = jnp.where(held, gates.reshape(-1), 0.0)[order].astype(f32)
+    live = jnp.arange(T * k) < sizes.sum()
+    y = jnp.where(live[:, None], y, 0.0) * g[:, None]
+    return jnp.zeros((T, x2d.shape[-1]), f32).at[token].add(y)
+
+
+def held_load(idx, first, count, weight=None):
+    """[pairs, pairs on held experts, held experts touched, largest load
+    of a held expert] as int32, over the tokens ``weight`` [T] marks
+    (all, if None)."""
+    import jax.numpy as jnp
+    T, k = idx.shape
+    w = jnp.ones((T,), jnp.int32) if weight is None \
+        else weight.astype(jnp.int32)
+    local = idx - first
+    held = ((local >= 0) & (local < count)) * w[:, None]
+    load = jnp.zeros((count + 1,), jnp.int32).at[
+        jnp.where(held > 0, local, count).reshape(-1)].add(1)[:count]
+    return jnp.stack([w.sum() * k, held.sum(), (load > 0).sum(),
+                      load.max()]).astype(jnp.int32)
+
+
+def dropless_moe(x2d, w, k, first, n_group=1, topk_group=1, route_scale=1.0,
+                 with_shared=True):
+    """One expert layer on raw tokens [T, d] from its raw weights ``w``
+    (``gate_weight`` [d, E], ``select_bias`` [E], ``held_w1/w3/w2``,
+    optionally ``shared_w1/w3/w2``).  The router's product and its sigmoid
+    are float32.  Returns ``(y [T, d] float32, idx, gates, scores)``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.dot(x2d.astype(f32),
+                                    w["gate_weight"].astype(f32)))
+    idx, gates = noaux_route(scores, w["select_bias"].astype(f32), k,
+                             n_group, topk_group, route_scale)
+    y = dropless_experts(x2d, idx, gates, w["held_w1"], w["held_w3"],
+                         w["held_w2"], first)
+    if with_shared and "shared_w1" in w:
+        y = y + swiglu(x2d, w["shared_w1"], w["shared_w3"], w["shared_w2"])
+    return y, idx, gates, scores
+
+
+class DroplessMoE(HybridBlock):
+    """Expert layer that holds ``held=(first, count)`` of ``num_experts``
+    routed SwiGLU experts and a shared expert.
+
+    The router and the selection run over all ``num_experts``
+    (:func:`noaux_route`); this layer computes the part of the result
+    that its own experts give (:func:`dropless_experts`) plus the shared
+    expert, which every holder computes alike.  On one chip it runs
+    without its exchange: what the absent experts would add is left out.
+    The parts of all the holders, the shared expert counted once, add up
+    to the whole layer (``tests/test_deepseek.py``).  Weights are stored
+    [in, out]; ``select_bias`` is the ``noaux_tc`` selection bias."""
+
+    def __init__(self, units, hidden_size, num_experts, k, held=None,
+                 n_group=1, topk_group=1, route_scale=1.0, shared_experts=1,
+                 dtype="float32", weight_initializer=None,
+                 bias_initializer=None, grad_req="write", prefix=None,
+                 params=None):
+        super().__init__(prefix, params)
+        first, count = held if held is not None else (0, num_experts)
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise ValueError(f"held={held} outside 0..{num_experts}")
+        self._k = k
+        self._first, self._count = int(first), int(count)
+        self._n_group, self._topk_group = n_group, topk_group
+        self._route_scale = route_scale
+        winit = weight_initializer or init.Xavier()
+        sh = shared_experts * hidden_size
+
+        def mat(name, *shape):
+            setattr(self, name, Parameter(name, shape=shape, dtype=dtype,
+                                          init=winit, grad_req=grad_req))
+        mat("gate_weight", units, num_experts)
+        self.select_bias = Parameter(
+            "select_bias", shape=(num_experts,), dtype="float32",
+            init=bias_initializer or init.Zero(), grad_req=grad_req)
+        mat("held_w1", count, units, hidden_size)
+        mat("held_w3", count, units, hidden_size)
+        mat("held_w2", count, hidden_size, units)
+        if shared_experts:
+            mat("shared_w1", units, sh)
+            mat("shared_w3", units, sh)
+            mat("shared_w2", sh, units)
+
+    @property
+    def held(self):
+        return self._first, self._count
+
+    def apply(self, x, with_shared=True):
+        """Raw [..., d] -> ``(y raw [..., d], idx [T, k], scores [T, E])``:
+        the layer's result and what the router decided."""
+        w = {name: unwrap(p.data()) for name, p in self._reg_params.items()}
+        y, idx, _gates, scores = dropless_moe(
+            x.reshape(-1, x.shape[-1]), w, k=self._k, first=self._first,
+            n_group=self._n_group, topk_group=self._topk_group,
+            route_scale=self._route_scale, with_shared=with_shared)
+        return y.astype(x.dtype).reshape(x.shape), idx, scores
+
+    def forward(self, x):
+        return NDArray(self.apply(unwrap(x))[0])
+
+    hybrid_forward = None
+
+
 def moe_sharding_rules(expert_axis="expert"):
     """``shard_params`` rules placing stacked expert weights on the EP axis.
 
-    The router gate stays replicated; every ``expert_*`` tensor shards its
-    leading E dimension.  Compose with TP/DP rules by concatenation (first
+    The router gate stays replicated (with :class:`DroplessMoE`'s
+    selection bias and shared expert); every ``expert_*`` and ``held_*``
+    stack shards its leading E dimension.  Compose with TP/DP rules by concatenation (first
     match wins in ``shard_params``).
     """
     from jax.sharding import PartitionSpec as P
     return [
         (r"expert_w1$|expert_b1$|expert_w2$|expert_b2$", P(expert_axis)),
-        (r"gate_weight$", P()),
+        (r"held_w1$|held_w3$|held_w2$", P(expert_axis)),
+        (r"gate_weight$|select_bias$|shared_w[123]$", P()),
     ]

@@ -110,6 +110,19 @@ class GenerationMetrics:
         weakref.finalize(self, _retire_gen_metrics, self._counters,
                          self.ttft, self.decode_step)
 
+    def declare(self, counters=(), gauges=()):
+        """What a served model brings, as ``(name, help)``: the counters
+        of its decode step (they start at 0 like the engine's own) and the
+        gauges of its kinds of ring, declared to the ``generate``
+        collector under the names the engine's own metrics have."""
+        with self._lock:
+            for name, _help in counters:
+                self._counters.setdefault(name, 0)
+        _telemetry.extend_collector("generate", {
+            "generate/" + name: (kind, text)
+            for kind, entries in (("counter", counters), ("gauge", gauges))
+            for name, text in entries})
+
     def inc(self, counter, n=1):
         with self._lock:
             self._counters[counter] += n
@@ -335,9 +348,9 @@ class GenerationStream:
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "stream", "trace",
                  "t_submit", "t_first", "t_decode0", "slot", "generated",
-                 "wrapped", "steps")
+                 "wrapped", "steps", "probe")
 
-    def __init__(self, prompt, max_new, eos_id, stream):
+    def __init__(self, prompt, max_new, eos_id, stream, probe=False):
         self.prompt = prompt
         self.max_new = max_new
         self.eos_id = eos_id
@@ -350,6 +363,8 @@ class _GenRequest:
         self.generated = []
         self.wrapped = False
         self.steps = 0
+        # a probed request keeps, a token, what the program computed it from
+        self.probe = [] if probe else None
 
 
 # ---------------------------------------------------------------------------
@@ -399,13 +414,12 @@ class GenerationEngine:
     def __init__(self, model, slots=None, max_len=None, prefill_buckets=None,
                  max_queue=256, metrics=None, precompile=True,
                  cache="default", decode_retries=3, compile_passes=None):
-        for attr in ("prefill", "decode_step", "num_layers", "num_heads",
-                     "units"):
+        for attr in ("prefill", "decode_step", "cache_spec"):
             if not hasattr(model, attr):
                 raise ServingError(
                     f"{type(model).__name__} does not speak the "
                     f"incremental-decode protocol (missing .{attr} — see "
-                    "models.TransformerLM)")
+                    "models.TransformerLM and docs/SERVING.md)")
         self._model = model
         self._slots = int(slots) if slots is not None \
             else int(getenv("MXNET_KV_SLOTS"))
@@ -449,24 +463,40 @@ class GenerationEngine:
                 "GenerationEngine: uninitialized or deferred parameters — "
                 "initialize() and run one forward with real data first")
 
-        # -- device-resident ring caches: (S, H, M, D) per layer, k + v --
-        import jax.numpy as jnp
-        L = int(model.num_layers)
-        H = int(model.num_heads)
-        D = int(model.units) // H
+        # -- device-resident ring caches: what the model says it keeps, a
+        # layer: [(kind, trailing shape, dtype), ...], each (S,) + shape --
+        from ..base import np_dtype
         S, M = self._slots, self._max_len
-        self._cache_shape = (S, H, M, D)
-        kv_bytes = L * 2 * S * H * M * D * 4      # float32
+        spec = model.cache_spec(M)
+        self._rings_per_layer = [len(layer) for layer in spec]
+        self._ring_specs = [(kind, (S,) + tuple(shape), np_dtype(dtype))
+                            for layer in spec
+                            for kind, shape, dtype in layer]
+        by_kind: dict = {}
+        for kind, shape, dtype in self._ring_specs:
+            by_kind[kind] = by_kind.get(kind, 0) \
+                + int(onp.prod(shape)) * onp.dtype(dtype).itemsize
+        kv_bytes = sum(by_kind.values())
         budget = int(getenv("MXNET_KV_BUDGET_BYTES"))
         if budget > 0 and kv_bytes > budget:
             raise ServingError(
-                f"KV cache needs {kv_bytes} bytes ({L} layers x 2 x "
-                f"{self._cache_shape}) > MXNET_KV_BUDGET_BYTES={budget} — "
+                f"KV cache needs {kv_bytes} bytes ({S} slots x {len(spec)} "
+                f"layers of {spec[0]}) > MXNET_KV_BUDGET_BYTES={budget} — "
                 "shrink MXNET_KV_SLOTS / MXNET_KV_MAX_LEN or raise the "
                 "budget")
-        self._cache_flat = self._zero_rings(L * 2)
+        self._cache_flat = self._zero_rings()
         self.kv_cache_bytes = kv_bytes
+        self.kv_cache_bytes_by_kind = by_kind
         self._metrics.set_gauge("kv_cache_bytes", kv_bytes)
+        # what the model's decode step counts on the device, read back
+        # with the step's tokens: (name, help) each
+        counters = tuple(getattr(model, "step_counters", ()))
+        self._step_counters = tuple(name for name, _help in counters)
+        self._metrics.declare(counters, [
+            (f"kv_cache_bytes_{kind}", f"bytes of the {kind} rings")
+            for kind in by_kind])
+        for kind, nbytes in by_kind.items():
+            self._metrics.set_gauge(f"kv_cache_bytes_{kind}", nbytes)
         self._metrics.set_gauge("free_kv_slots", S)
 
         # -- scheduler state (single loop thread owns all of it) --
@@ -475,6 +505,7 @@ class GenerationEngine:
         self._free = list(range(S - 1, -1, -1))     # pop() -> lowest slot
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(max_queue)))
         self._closed = False
+        self._aborted = False
         # param-swap serializer: the PROCESS-WIDE trace lock, not a private
         # one — the loop thread traces against the same Parameter objects a
         # caller-thread full forward swaps (gluon.block.PARAM_TRACE_LOCK)
@@ -482,6 +513,7 @@ class GenerationEngine:
         self._trace_lock = PARAM_TRACE_LOCK
         self._prefill_progs: dict = {}              # bucket -> (prog, label)
         self._decode_prog = None                    # (prog, label)
+        self._probe_progs: dict = {}    # bucket, None: decode -> the same
         if precompile:
             self.precompile()
         self._thread = threading.Thread(target=self._loop,
@@ -531,16 +563,30 @@ class GenerationEngine:
                 for lab, reps in sorted(self._passes_reports.items())},
         }
 
-    def _zero_rings(self, n):
+    def _zero_rings(self):
         import jax.numpy as jnp
         from .. import memory as _memory
         rings = []
-        for _ in range(n):
-            buf = jnp.zeros(self._cache_shape, jnp.float32)
+        for _kind, shape, dtype in self._ring_specs:
+            buf = jnp.zeros(shape, dtype)
             if _memory._census_active:
                 _memory.tag(buf, "kv_cache")
             rings.append(buf)
         return rings
+
+    def _ring_sds(self):
+        import jax
+        return [jax.ShapeDtypeStruct(shape, dtype)
+                for _kind, shape, dtype in self._ring_specs]
+
+    def _by_layer(self, flat):
+        """The flat list of rings as the model takes them: a tuple a
+        layer."""
+        out, i = [], 0
+        for n in self._rings_per_layer:
+            out.append(tuple(flat[i:i + n]))
+            i += n
+        return out
 
     def _bucket_for(self, n):
         for b in self._prefill_buckets:
@@ -551,7 +597,7 @@ class GenerationEngine:
             f"{self._prefill_buckets[-1]} (max_len={self._max_len})")
 
     # -- pure functions (params + caches ride as jit arguments) ------------
-    def _prefill_pure(self, bucket):
+    def _prefill_pure(self, bucket, probe=False):
         import jax
         import jax.numpy as jnp
         from ..gluon.block import _run_with_params
@@ -560,33 +606,42 @@ class GenerationEngine:
         from .. import random as _random
         key = jax.random.PRNGKey(0)
         model, ps = self._model, self._ps
+        kw = self._probe_kw(probe)
 
         def pure(raws, tok, vl, slot, *cache_flat):
             def call():
                 with autograd._Scope(recording=False, training=False), \
                         _random.key_scope(key):
-                    return model.prefill(NDArray(tok), NDArray(vl))
+                    return model.prefill(NDArray(tok), NDArray(vl), **kw)
 
-            (logits, kvs), _aux = _run_with_params(ps, raws, call)
-            lraw = unwrap(logits)                       # (1, Lb, V)
-            first = jnp.argmax(
-                jnp.take(lraw[0], vl[0] - 1, axis=0)).astype(jnp.int32)
+            res, _aux = _run_with_params(ps, raws, call)
+            lraw = unwrap(res[0])                       # (1, Lb, V)
+            last = jnp.take(lraw[0], vl[0] - 1, axis=0)
+            first = jnp.argmax(last).astype(jnp.int32)
             out = [first]
-            for i, (k, v) in enumerate(kvs):
+            rows = [unwrap(r) for layer in res[1] for r in layer]
+            for ring, new in zip(cache_flat, rows):
                 # padded rows beyond vl are dead: decode overwrites index
                 # j at position j before the mask reaches it
-                kc = jax.lax.dynamic_update_slice(
-                    cache_flat[2 * i], unwrap(k), (slot, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    cache_flat[2 * i + 1], unwrap(v), (slot, 0, 0, 0))
-                out += [kc, vc]
+                out.append(jax.lax.dynamic_update_slice(
+                    ring, new.astype(ring.dtype),
+                    (slot,) + (0,) * (ring.ndim - 1)))
+            if probe:
+                out.append({"logits": last, **(res[2] if kw else {})})
             return tuple(out)
 
         # the jitted module's name in a device trace: jit_pure_prefill_L32
-        pure.__name__ = pure.__qualname__ = f"pure_prefill_L{bucket}"
+        pure.__name__ = pure.__qualname__ = \
+            f"{'probe' if probe else 'pure'}_prefill_L{bucket}"
         return pure
 
-    def _decode_pure(self):
+    def _probe_kw(self, probe):
+        """A probed program asks a model that says it ``probes`` for what
+        it chose; of any other it shows the logits alone."""
+        return {"probe": True} if probe and getattr(
+            self._model, "probes", False) else {}
+
+    def _decode_pure(self, probe=False):
         import jax
         import jax.numpy as jnp
         from ..gluon.block import _run_with_params
@@ -595,26 +650,34 @@ class GenerationEngine:
         from .. import random as _random
         key = jax.random.PRNGKey(0)
         model, ps = self._model, self._ps
+        kw = self._probe_kw(probe)
 
         def pure_decode(raws, tok, pos, act, *cache_flat):
-            caches = [(NDArray(cache_flat[2 * i]),
-                       NDArray(cache_flat[2 * i + 1]))
-                      for i in range(len(cache_flat) // 2)]
+            caches = [tuple(NDArray(r) for r in layer)
+                      for layer in self._by_layer(cache_flat)]
 
             def call():
                 with autograd._Scope(recording=False, training=False), \
                         _random.key_scope(key):
                     return model.decode_step(NDArray(tok), caches,
                                              NDArray(pos),
-                                             active=NDArray(act))
+                                             active=NDArray(act), **kw)
 
-            (logits, new_caches), _aux = _run_with_params(ps, raws, call)
-            nxt = jnp.argmax(unwrap(logits), axis=-1).astype(jnp.int32)
-            out = [nxt]
-            for k, v in new_caches:
-                out += [unwrap(k), unwrap(v)]
-            return tuple(out)
+            res, _aux = _run_with_params(ps, raws, call)
+            nxt = jnp.argmax(unwrap(res[0]), axis=-1).astype(jnp.int32)
+            if self._step_counters:
+                # one array back to the host: the tokens, then the counts
+                nxt = jnp.concatenate(
+                    [nxt, unwrap(res[2]).astype(jnp.int32)])
+            out = (nxt,) + tuple(unwrap(r) for layer in res[1]
+                                 for r in layer)
+            if probe:
+                out += ({"logits": unwrap(res[0]),
+                         **(res[-1] if kw else {})},)
+            return out
 
+        if probe:
+            pure_decode.__name__ = pure_decode.__qualname__ = "probe_decode"
         return pure_decode
 
     def _read_params(self):
@@ -636,17 +699,40 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=rings).lower(
                 self._read_params(), *sds)
 
+    def _input_sds(self, bucket=None):
+        """The arguments after the weights, of a prefill bucket's program
+        or (None) of the decode program: three inputs, then the rings."""
+        import jax
+        S = self._slots
+        shapes = [((S,), onp.int32), ((S,), onp.int32), ((S,), onp.float32)] \
+            if bucket is None else \
+            [((1, bucket), onp.int32), ((1,), onp.int32), ((), onp.int32)]
+        return [jax.ShapeDtypeStruct(*s) for s in shapes] + self._ring_sds()
+
+    def _compile_probe(self, bucket=None):
+        """The probed twin of a prefill bucket's program or (None) of the
+        decode program: the same function on the same rings, donated the
+        same, with one more output (see :meth:`submit`).  Compiled on the
+        loop thread when the first probed request needs it."""
+        entry = self._probe_progs.get(bucket)
+        if entry is None:
+            from .. import compile as _compile
+            fn = self._decode_pure(True) if bucket is None \
+                else self._prefill_pure(bucket, True)
+            label = "generate:probe:" + (
+                "decode" if bucket is None else f"prefill:L{bucket}")
+            compiled, _info = _compile.aot_compile_lowered(
+                self._lower(fn, self._input_sds(bucket)),
+                cache=self._cache_label, label=label)
+            entry = self._probe_progs[bucket] = (compiled, label)
+        return entry
+
     def _compile_prefill(self, bucket):
         entry = self._prefill_progs.get(bucket)
         if entry is not None:
             return entry
-        import jax
         from .. import compile as _compile
-        sds = [jax.ShapeDtypeStruct((1, bucket), onp.int32),
-               jax.ShapeDtypeStruct((1,), onp.int32),
-               jax.ShapeDtypeStruct((), onp.int32)]
-        sds += [jax.ShapeDtypeStruct(self._cache_shape, onp.float32)
-                for _ in self._cache_flat]
+        sds = self._input_sds(bucket)
         fn, extra = self._prefill_pure(bucket), None
         if self._pipeline is not None:
             from ..compile import passes as _passes
@@ -674,17 +760,10 @@ class GenerationEngine:
     def _compile_decode(self):
         if self._decode_prog is not None:
             return self._decode_prog
-        import jax
         from .. import compile as _compile
-        S = self._slots
-        sds = [jax.ShapeDtypeStruct((S,), onp.int32),
-               jax.ShapeDtypeStruct((S,), onp.int32),
-               jax.ShapeDtypeStruct((S,), onp.float32)]
-        sds += [jax.ShapeDtypeStruct(self._cache_shape, onp.float32)
-                for _ in self._cache_flat]
         compiled, info = _compile.aot_compile_lowered(
-            self._lower(self._decode_pure(), sds), cache=self._cache_label,
-            label="generate:decode")
+            self._lower(self._decode_pure(), self._input_sds()),
+            cache=self._cache_label, label="generate:decode")
         self._metrics.inc("decode_cache_hits" if info["cache_hit"]
                           else "decode_compiles")
         self._decode_prog = (compiled, "generate:decode")
@@ -701,10 +780,22 @@ class GenerationEngine:
         self._compile_decode()
 
     # -- submission --------------------------------------------------------
-    def submit(self, tokens, max_new_tokens=32, eos_id=None, trace=None):
+    def submit(self, tokens, max_new_tokens=32, eos_id=None, trace=None,
+               probe=False):
         """Queue one prompt; returns a :class:`GenerationStream`
         immediately.  ``max_new_tokens`` counts every emitted token
-        (including the prefill's first and any EOS)."""
+        (including the prefill's first and any EOS).
+
+        ``probe``: show what the serving programs computed for this
+        request, to hold a deployment against a reference.  Its prefill
+        and every decode step it rides in run the probed twins of the
+        programs (:meth:`_compile_probe`), in its slot of the live rings
+        beside whatever else is in flight, and its result carries
+        ``"probe"``: for each emitted token a dict of host arrays, the
+        float32 ``"logits"`` the token is the largest of and, from a
+        model that ``probes``, what its ``prefill`` / ``decode_step``
+        return with ``probe=True`` (of a decode step this request's row).
+        A probed step is slower: keep it out of what is timed."""
         if self._closed:
             raise EngineClosedError("GenerationEngine is stopped")
         prompt = onp.asarray(tokens, dtype=onp.int32).reshape(-1)
@@ -714,7 +805,8 @@ class GenerationEngine:
         stream = GenerationStream(
             trace if trace is not None else _telemetry.new_trace())
         req = _GenRequest(prompt, max(1, int(max_new_tokens)),
-                          None if eos_id is None else int(eos_id), stream)
+                          None if eos_id is None else int(eos_id), stream,
+                          probe)
         try:
             self._q.put_nowait(req)
         except queue.Full:
@@ -736,6 +828,9 @@ class GenerationEngine:
     # -- engine loop (single thread owns slots/positions/caches) -----------
     def _loop(self):
         while True:
+            if self._aborted:
+                self._fail_riders(EngineClosedError("engine aborted"))
+                return
             first = None
             if len(self._free) == self._slots and self._q.empty():
                 # nothing in flight, nothing queued: wait outside any step
@@ -793,7 +888,7 @@ class GenerationEngine:
                 attempt += 1
                 self._metrics.inc("dispatch_retries")
                 continue
-            if rings[0].is_deleted():
+            if all(r.is_deleted() for r in rings):
                 self._metrics.inc("kv_inplace_dispatches")
             return out
 
@@ -805,7 +900,7 @@ class GenerationEngine:
         the caller fails every request that holds a slot."""
         if not any(r.is_deleted() for r in self._cache_flat):
             return False
-        self._cache_flat = self._zero_rings(len(self._cache_flat))
+        self._cache_flat = self._zero_rings()
         self._metrics.inc("kv_ring_rebuilds")
         return True
 
@@ -831,11 +926,13 @@ class GenerationEngine:
             self._admit_into(req, slot, P, bucket)
 
     def _admit_into(self, req, slot, P, bucket):
+        import jax
         tok = onp.zeros((1, bucket), dtype=onp.int32)
         tok[0, :P] = req.prompt
         vl = onp.asarray([P], dtype=onp.int32)
         try:
-            prog, label = self._compile_prefill(bucket)
+            prog, label = self._compile_prefill(bucket) \
+                if req.probe is None else self._compile_probe(bucket)
             with req.trace.span("generate_prefill", bucket=bucket,
                                 program=label, slot=slot, prompt_len=P):
                 # live read per dispatch (a hot-swap is a jit cache hit)
@@ -843,6 +940,10 @@ class GenerationEngine:
                     prog, self._read_params(),
                     (tok, vl, onp.int32(slot)), "prefill")
             first = int(out[0])             # waits for the device
+            if req.probe is not None:
+                req.probe.append(jax.tree_util.tree_map(onp.asarray,
+                                                        out[-1]))
+                out = out[:-1]
         except Exception as e:      # noqa: BLE001 — fail one request only,
             # unless the rings went with it
             out = None      # e's traceback keeps this frame, not the rings
@@ -873,7 +974,9 @@ class GenerationEngine:
         try:
             # lazy on the first step (ModelServer does not precompile): a
             # failed compile fails this step's riders like a failed dispatch
-            prog, _label = self._compile_decode()
+            probed = [r for r in active if r.probe is not None]
+            prog, _label = self._compile_probe() if probed \
+                else self._compile_decode()
             # loop_offcpu_us: wall less this thread's CPU time over the two
             # phases that never wait for the device (four thread-clock reads)
             t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
@@ -894,6 +997,16 @@ class GenerationEngine:
                 out = self._dispatch(prog, raws, (tok, pos, act), "decode")
             with _telemetry.phase("readback"):
                 nxt = onp.asarray(out[0])       # waits for the device
+            if self._step_counters:
+                self._metrics.add(**dict(zip(
+                    self._step_counters, (int(n) for n in nxt[S:]))))
+            if probed:
+                import jax
+                seen = jax.tree_util.tree_map(onp.asarray, out[-1])
+                for r in probed:
+                    r.probe.append(jax.tree_util.tree_map(
+                        lambda a, slot=r.slot: a[slot], seen))
+                out = out[:-1]
         except Exception as e:      # noqa: BLE001
             # a non-transient decode failure has no healthy path forward
             # for the riders, with or without their rings — fail them
@@ -965,12 +1078,15 @@ class GenerationEngine:
             _telemetry.inflight_remove(req.trace.trace_id)
             _telemetry.maybe_spool(req.trace, wall_s * 1000.0, "generate")
         self._metrics.inc("completed")
-        req.stream._complete({
+        result = {
             "tokens": [int(t) for t in req.generated],
             "finish_reason": reason,
             "ttft_ms": round(ttft_ms, 3),
             "tokens_per_s": round(tokens_per_s, 3),
-        })
+        }
+        if req.probe is not None:
+            result["probe"] = req.probe
+        req.stream._complete(result)
 
     def _fail(self, req, exc):
         self._metrics.inc("errors")
@@ -994,3 +1110,14 @@ class GenerationEngine:
             self._fail(req, EngineClosedError("engine stopped"))
 
     close = stop
+
+    def abort(self, timeout=30.0):
+        """Stop without draining: what is in flight fails with
+        :class:`EngineClosedError` at the next step's boundary, as does
+        what is queued, and the rings are given back to the device.  For a
+        host that must have the memory now (a long generation is minutes
+        of decode steps)."""
+        self._aborted = True
+        self.stop(timeout)
+        if not self._thread.is_alive():
+            self._cache_flat = []

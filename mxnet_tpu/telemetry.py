@@ -273,6 +273,15 @@ class MetricsRegistry:
                             f"({sub!r} and {subsystem!r})")
             self._collectors[subsystem] = (fn, dict(spec))
 
+    def extend_collector(self, subsystem, spec):
+        """Declare further metrics of a registered collector: names known
+        only at run time (what a served model counts).  Under the rules
+        of :meth:`register_collector`; a name declared before keeps its
+        declaration."""
+        with self._lock:
+            fn, old = self._collectors[subsystem]
+        self.register_collector(subsystem, fn, {**spec, **old})
+
     # -- snapshot ----------------------------------------------------------
     @staticmethod
     def _decl_type(decl):
@@ -417,6 +426,10 @@ def histogram(name, help="", bounds=None):      # noqa: A002
 
 def register_collector(subsystem, fn, spec):
     return _registry.register_collector(subsystem, fn, spec)
+
+
+def extend_collector(subsystem, spec):
+    return _registry.extend_collector(subsystem, spec)
 
 
 def snapshot():

@@ -586,8 +586,7 @@ def test_donated_serving_program_warm_loads_and_consumes(cache_dir, program):
             fn = warm._prefill_pure(8)
             inputs = [((1, 8), onp.int32), ((1,), onp.int32), ((), onp.int32)]
         sds = [jax.ShapeDtypeStruct(sh, dt) for sh, dt in inputs]
-        sds += [jax.ShapeDtypeStruct(warm._cache_shape, onp.float32)
-                for _ in rings]
+        sds += warm._ring_sds()
         donated = mxcompile.fingerprint_lowered(warm._lower(fn, sds))
         plain = mxcompile.fingerprint_lowered(
             jax.jit(fn).lower(warm._read_params(), *sds))

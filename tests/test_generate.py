@@ -319,7 +319,7 @@ def test_generate_decode_permanent_fault_fails_one_request(lm):
 def _ring_buffers(eng):
     import jax
     return [a for a in jax.live_arrays()
-            if a.shape == eng._cache_shape and not a.is_deleted()]
+            if a.shape == eng._ring_specs[0][1] and not a.is_deleted()]
 
 
 def test_rings_are_consumed_and_no_second_copy_is_kept():

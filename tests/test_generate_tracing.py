@@ -470,7 +470,10 @@ def test_new_metrics_are_entries_of_the_benchmark_except_queue_wait():
            "loop_admit_ms.decode", "idle_unnamed_ms.decode",
            "loop_offcpu_us.decode", "emit_to_wire_us", "wire_write_us",
            "loop_release_ms.decode"]
-    assert [m["name"] for m in bench["per_layer"]][-len(new):] == new
+    # appended in this order by PR 25; later PRs append after them
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(new[0])
+    assert names[at:at + len(new)] == new
     for name in new:
         assert entries[name]["workloads"] == ["gpt1.decode_full"]
     # like wire_ttft_ms: a file, and no entry until a cell judges TTFT
