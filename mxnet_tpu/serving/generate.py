@@ -27,6 +27,15 @@ programs, and both carry the param-swap discipline of
 ``HybridBlock.inference_fn``: weights ride as jit *arguments*, so a
 hot-swap is a jit cache hit, never a recompile.
 
+The loop keeps **one decode step in flight** (docs/SERVING.md): every
+slot's last token lives on the device, in an ``int32[slots]`` vector both
+programs consume and return beside the rings, so step n+1 is dispatched
+before step n's tokens are read, and step n is read, emitted and completed
+while the device runs step n+1.  An admission dispatches its prefill and
+does not wait for it: the first token is read with whatever else is
+unread.  Greedy tokens do not depend on it; a request that ends on
+``eos_id`` is found one step late and that step's token is thrown away.
+
 Ring-buffer semantics: a slot's position ``p`` writes cache index
 ``p % max_len`` and attends over ``min(p+1, max_len)`` entries — past
 ``max_len`` the cache is a sliding window over the last ``max_len``
@@ -80,6 +89,9 @@ class GenerationMetrics:
             "tokens_generated": 0,
             "prefills": 0,
             "decode_steps": 0,      # whole-batch steps dispatched
+            # ... of them, with the step before still unread on the host
+            "decode_steps_overlapped": 0,
+            "slot_steps_discarded": 0,  # tokens thrown away (EOS a step late)
             "slot_allocs": 0,
             "slot_frees": 0,
             "cache_wraps": 0,       # requests whose ring wrapped (window slid)
@@ -141,14 +153,18 @@ class GenerationMetrics:
         with self._lock:
             self.ttft.observe(ms)
 
-    def record_decode_step(self, riders, step_ms, offcpu_us):
-        """Everything one whole-batch decode step counts, under one lock:
-        ``step_ms`` runs from the start of ``stage`` to the end of
-        ``emit``."""
+    def record_decode_step(self, riders, emitted, step_ms, offcpu_us,
+                           overlapped):
+        """Everything one turn of the loop that dispatched a decode step
+        counts, under one lock: the step's ``riders``, the decode tokens
+        ``emitted`` beside it (the step before's), whether that step was
+        still unread at the dispatch, and ``step_ms`` from the start of
+        ``stage`` to the end of ``emit``."""
         with self._lock:
             c = self._counters
             c["decode_steps"] += 1
-            c["tokens_generated"] += riders
+            c["decode_steps_overlapped"] += bool(overlapped)
+            c["tokens_generated"] += emitted
             c["loop_offcpu_us"] += offcpu_us
             self._gauges["batch_occupancy"] = riders
             self.decode_step.observe(step_ms)
@@ -215,6 +231,14 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
     "generate/tokens_generated": ("counter", "total tokens emitted"),
     "generate/prefills": ("counter", "prompt prefill dispatches"),
     "generate/decode_steps": ("counter", "whole-batch decode steps"),
+    "generate/decode_steps_overlapped": ("counter",
+                                         "decode steps dispatched while the "
+                                         "step before was still unread on "
+                                         "the host"),
+    "generate/slot_steps_discarded": ("counter",
+                                      "tokens of a decode step thrown away: "
+                                      "their request had ended on eos_id one "
+                                      "step before"),
     "generate/slot_allocs": ("counter", "KV slots allocated"),
     "generate/slot_frees": ("counter", "KV slots freed"),
     "generate/cache_wraps": ("counter",
@@ -267,8 +291,9 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
                                  "active slots in the latest decode step"),
     "generate/ttft_ms": ("histogram", "submit -> first-token ms"),
     "generate/decode_step_ms": ("histogram",
-                                "whole-batch decode step wall ms (stage "
-                                "start to emit end)"),
+                                "wall ms of one turn of the loop: stage of "
+                                "a decode step to the end of the emit of "
+                                "the step before"),
 })
 
 
@@ -348,7 +373,7 @@ class GenerationStream:
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "stream", "trace",
                  "t_submit", "t_first", "t_decode0", "slot", "generated",
-                 "wrapped", "steps", "probe")
+                 "sent", "wrapped", "steps", "probe")
 
     def __init__(self, prompt, max_new, eos_id, stream, probe=False):
         self.prompt = prompt
@@ -361,10 +386,31 @@ class _GenRequest:
         self.t_decode0 = None
         self.slot = None
         self.generated = []
+        # tokens whose program has been dispatched: the prefill's, then one
+        # a decode step ridden; ahead of len(generated) by what is unread
+        self.sent = 0
         self.wrapped = False
         self.steps = 0
         # a probed request keeps, a token, what the program computed it from
         self.probe = [] if probe else None
+
+
+class _Unread:
+    """A dispatched program whose tokens the host has not read yet: a
+    prefill (``req`` and its stamps) or a decode step (``riders``, each a
+    request and the slot it rode in).  ``step_id`` is the loop step that
+    dispatched it."""
+
+    __slots__ = ("tokens", "probe", "step_id", "req", "riders", "span")
+
+    def __init__(self, tokens, probe, step_id, req=None, riders=None,
+                 span=None):
+        self.tokens = tokens
+        self.probe = probe
+        self.step_id = step_id
+        self.req = req
+        self.riders = riders
+        self.span = span
 
 
 # ---------------------------------------------------------------------------
@@ -484,7 +530,8 @@ class GenerationEngine:
                 f"layers of {spec[0]}) > MXNET_KV_BUDGET_BYTES={budget} — "
                 "shrink MXNET_KV_SLOTS / MXNET_KV_MAX_LEN or raise the "
                 "budget")
-        self._cache_flat = self._zero_rings()
+        self._cache_flat, self._last_tok = self._zero_rings(), \
+            self._zero_last()
         self.kv_cache_bytes = kv_bytes
         self.kv_cache_bytes_by_kind = by_kind
         self._metrics.set_gauge("kv_cache_bytes", kv_bytes)
@@ -503,6 +550,8 @@ class GenerationEngine:
         self._positions = onp.zeros(S, dtype=onp.int32)
         self._by_slot: list = [None] * S            # slot -> _GenRequest
         self._free = list(range(S - 1, -1, -1))     # pop() -> lowest slot
+        self._unread: list = []     # dispatched and not read, oldest first
+        self._step_id = None        # the loop step open now (telemetry on)
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(max_queue)))
         self._closed = False
         self._aborted = False
@@ -574,6 +623,12 @@ class GenerationEngine:
             rings.append(buf)
         return rings
 
+    def _zero_last(self):
+        """Every slot's last token, on the device: what the decode program
+        reads its inputs from and a prefill writes its first token into."""
+        import jax.numpy as jnp
+        return jnp.zeros((self._slots,), jnp.int32)
+
     def _ring_sds(self):
         import jax
         return [jax.ShapeDtypeStruct(shape, dtype)
@@ -608,7 +663,7 @@ class GenerationEngine:
         model, ps = self._model, self._ps
         kw = self._probe_kw(probe)
 
-        def pure(raws, tok, vl, slot, *cache_flat):
+        def pure(raws, tok, vl, slot, last, *cache_flat):
             def call():
                 with autograd._Scope(recording=False, training=False), \
                         _random.key_scope(key):
@@ -616,9 +671,12 @@ class GenerationEngine:
 
             res, _aux = _run_with_params(ps, raws, call)
             lraw = unwrap(res[0])                       # (1, Lb, V)
-            last = jnp.take(lraw[0], vl[0] - 1, axis=0)
-            first = jnp.argmax(last).astype(jnp.int32)
-            out = [first]
+            row = jnp.take(lraw[0], vl[0] - 1, axis=0)
+            first = jnp.argmax(row).astype(jnp.int32)
+            # the host reads `first`; the slot's next decode step reads it
+            # from the vector, without waiting for the host
+            out = [first, jax.lax.dynamic_update_slice(
+                last, first[None], (slot,))]
             rows = [unwrap(r) for layer in res[1] for r in layer]
             for ring, new in zip(cache_flat, rows):
                 # padded rows beyond vl are dead: decode overwrites index
@@ -627,7 +685,7 @@ class GenerationEngine:
                     ring, new.astype(ring.dtype),
                     (slot,) + (0,) * (ring.ndim - 1)))
             if probe:
-                out.append({"logits": last, **(res[2] if kw else {})})
+                out.append({"logits": row, **(res[2] if kw else {})})
             return tuple(out)
 
         # the jitted module's name in a device trace: jit_pure_prefill_L32
@@ -652,7 +710,9 @@ class GenerationEngine:
         model, ps = self._model, self._ps
         kw = self._probe_kw(probe)
 
-        def pure_decode(raws, tok, pos, act, *cache_flat):
+        def pure_decode(raws, pos, act, last, *cache_flat):
+            # a slot that does not ride reads as token 0, whatever it held
+            tok = jnp.where(act > 0, last, 0)
             caches = [tuple(NDArray(r) for r in layer)
                       for layer in self._by_layer(cache_flat)]
 
@@ -665,12 +725,14 @@ class GenerationEngine:
 
             res, _aux = _run_with_params(ps, raws, call)
             nxt = jnp.argmax(unwrap(res[0]), axis=-1).astype(jnp.int32)
+            # the riders' new tokens stay on the device for the next step
+            keep = jnp.where(act > 0, nxt, last)
             if self._step_counters:
                 # one array back to the host: the tokens, then the counts
                 nxt = jnp.concatenate(
                     [nxt, unwrap(res[2]).astype(jnp.int32)])
-            out = (nxt,) + tuple(unwrap(r) for layer in res[1]
-                                 for r in layer)
+            out = (nxt, keep) + tuple(unwrap(r) for layer in res[1]
+                                      for r in layer)
             if probe:
                 out += ({"logits": unwrap(res[0]),
                          **(res[-1] if kw else {})},)
@@ -687,26 +749,30 @@ class GenerationEngine:
 
     # -- compilation -------------------------------------------------------
     def _lower(self, fn, sds):
-        """Lower a serving program with its rings donated: every argument
-        after the weights and the three inputs.  The outputs are then the
-        same buffers, so the program writes only the rows it changes.
-        Never the weights: they live on across steps and are shared with
-        ``load_parameters``."""
+        """Lower a serving program with what it carries from step to step
+        donated: its last arguments, the slots' last tokens and the rings.
+        The outputs are then the same buffers, so the program writes only
+        the rows it changes.  Never the weights: they live on across steps
+        and are shared with ``load_parameters``."""
         import jax
         # donation-recovery: tests/test_generate.py::test_failure_that_consumes_the_rings_fails_riders_and_rebuilds
-        rings = tuple(range(4, 4 + len(self._cache_flat)))
+        end = 1 + len(sds)
+        carried = tuple(range(end - 1 - len(self._ring_specs), end))
         with self._trace_lock:
-            return jax.jit(fn, donate_argnums=rings).lower(
+            return jax.jit(fn, donate_argnums=carried).lower(
                 self._read_params(), *sds)
 
     def _input_sds(self, bucket=None):
         """The arguments after the weights, of a prefill bucket's program
-        or (None) of the decode program: three inputs, then the rings."""
+        or (None) of the decode program: the host's inputs (positions and
+        the riders' gate; the padded prompt, its length and the slot), then
+        what the program carries: the slots' last tokens and the rings."""
         import jax
         S = self._slots
-        shapes = [((S,), onp.int32), ((S,), onp.int32), ((S,), onp.float32)] \
+        shapes = [((S,), onp.int32), ((S,), onp.float32)] \
             if bucket is None else \
             [((1, bucket), onp.int32), ((1,), onp.int32), ((), onp.int32)]
+        shapes.append(((S,), onp.int32))
         return [jax.ShapeDtypeStruct(*s) for s in shapes] + self._ring_sds()
 
     def _compile_probe(self, bucket=None):
@@ -832,7 +898,8 @@ class GenerationEngine:
                 self._fail_riders(EngineClosedError("engine aborted"))
                 return
             first = None
-            if len(self._free) == self._slots and self._q.empty():
+            if not self._unread and len(self._free) == self._slots \
+                    and self._q.empty():
                 # nothing in flight, nothing queued: wait outside any step
                 if self._closed:
                     return
@@ -842,14 +909,13 @@ class GenerationEngine:
                     continue
             # one iteration with work = one step; the memory sampler runs
             # when its envelope closes, never once a phase
-            with _telemetry.step_span("generate", sample_phases=False):
+            with _telemetry.step_span("generate", sample_phases=False) as env:
+                self._step_id = getattr(env, "step_id", None)
                 if first is not None:
                     self._metrics.set_gauge("queue_depth", self._q.qsize())
                     self._admit(first)
                 self._admit_ready()
-                active = [r for r in self._by_slot if r is not None]
-                if active:
-                    self._decode_once(active)
+                self._decode_once()
 
     def _admit_ready(self):
         while self._free:
@@ -861,17 +927,21 @@ class GenerationEngine:
             self._admit(req)
 
     def _dispatch(self, prog, raws, inputs, what):
-        """Run one compiled program on the rings.  It consumes them (they
-        are donated, see :meth:`_lower`): the caller makes the returned
-        rings the engine's once the first output has been read.
+        """Run one compiled program on what the engine carries from step
+        to step, the slots' last tokens and the rings, and make what it
+        returns in their place the engine's at once: the next program is
+        dispatched on them before this one's tokens are read.  Returns the
+        output the host reads (the tokens, unread) and a probed program's
+        further output, or None.
 
-        A transient failure is retried in place only while the rings are
-        alive, as they are after the injected fault, which fires before
-        the call.  Whatever else fails, here or at the read, goes to
-        :meth:`_rings_lost`."""
+        The program consumes what it is given (donated, see
+        :meth:`_lower`).  A transient failure is retried in place only
+        while all of it is alive, as it is after the injected fault, which
+        fires before the call.  Whatever else fails, here or at a read,
+        goes to :meth:`_rings_lost`."""
         from .. import faults as _faults
         attempt = 0
-        rings = self._cache_flat
+        carried = [self._last_tok, *self._cache_flat]
         while True:
             try:
                 if what == "decode":
@@ -879,32 +949,47 @@ class GenerationEngine:
                     # `generate.decode@N:...` fails / delays / kills this
                     # replica mid-generation (docs/RESILIENCE.md)
                     _faults.point("generate.decode")
-                out = prog(raws, *inputs, *rings)
+                out = prog(raws, *inputs, *carried)
             except (_faults.TransientFault, ConnectionResetError,
                     TimeoutError):
                 if attempt >= self._decode_retries \
-                        or any(r.is_deleted() for r in rings):
+                        or any(a.is_deleted() for a in carried):
                     raise
                 attempt += 1
                 self._metrics.inc("dispatch_retries")
                 continue
-            if all(r.is_deleted() for r in rings):
+            if all(a.is_deleted() for a in carried):
                 self._metrics.inc("kv_inplace_dispatches")
-            return out
+            end = 1 + len(carried)
+            self._last_tok, self._cache_flat = out[1], list(out[2:end])
+            return out[0], (out[end] if len(out) > end else None)
 
-    def _rings_lost(self):
-        """After a failed dispatch or read: did it take the rings?  While
-        every ring is alive the failure came before a program took them
-        and nothing is lost.  Otherwise the keys and values of every slot
-        went with them: the engine serves on from fresh zero rings, and
+    def _rings_lost(self, at_read=False):
+        """After a failed dispatch or read: are the rings gone?  A failure
+        at a dispatch while everything carried is alive came before a
+        program took it, and nothing is lost.  A program that died after
+        taking its arguments, or one that failed on the device and was
+        found at the read of its tokens (what the engine holds by then is
+        its output, or the output of the step dispatched after it), leaves
+        no row to trust: the engine serves on from fresh zero rings, and
         the caller fails every request that holds a slot."""
-        if not any(r.is_deleted() for r in self._cache_flat):
+        carried = [self._last_tok, *self._cache_flat]
+        if at_read:
+            for a in carried:       # freed before the new ones are made
+                if not a.is_deleted():
+                    a.delete()
+        elif not any(a.is_deleted() for a in carried):
             return False
-        self._cache_flat = self._zero_rings()
+        del carried
+        self._cache_flat, self._last_tok = self._zero_rings(), \
+            self._zero_last()
         self._metrics.inc("kv_ring_rebuilds")
         return True
 
     def _fail_riders(self, exc):
+        """Every request that holds a slot fails, and what was dispatched
+        for them and not read is dropped with them."""
+        self._unread.clear()
         for r in self._by_slot:
             if r is not None:
                 self._release(r)
@@ -926,121 +1011,193 @@ class GenerationEngine:
             self._admit_into(req, slot, P, bucket)
 
     def _admit_into(self, req, slot, P, bucket):
-        import jax
+        """Dispatch the prefill and go on: the device orders it after the
+        step in flight (it takes that step's rings), the slot's first token
+        lands in the vector the next decode step reads, and the host reads
+        it at the next readback (:meth:`_emit_first`)."""
         tok = onp.zeros((1, bucket), dtype=onp.int32)
         tok[0, :P] = req.prompt
         vl = onp.asarray([P], dtype=onp.int32)
         try:
             prog, label = self._compile_prefill(bucket) \
                 if req.probe is None else self._compile_probe(bucket)
-            with req.trace.span("generate_prefill", bucket=bucket,
-                                program=label, slot=slot, prompt_len=P):
-                # live read per dispatch (a hot-swap is a jit cache hit)
-                out = self._dispatch(
-                    prog, self._read_params(),
-                    (tok, vl, onp.int32(slot)), "prefill")
-            first = int(out[0])             # waits for the device
-            if req.probe is not None:
-                req.probe.append(jax.tree_util.tree_map(onp.asarray,
-                                                        out[-1]))
-                out = out[:-1]
+            # live read per dispatch (a hot-swap is a jit cache hit)
+            first, probe = self._dispatch(
+                prog, self._read_params(),
+                (tok, vl, onp.int32(slot)), "prefill")
         except Exception as e:      # noqa: BLE001 — fail one request only,
             # unless the rings went with it
-            out = None      # e's traceback keeps this frame, not the rings
             self._free.append(slot)
             self._metrics.inc("slot_frees")
             if self._rings_lost():
                 self._fail_riders(e)
             self._fail(req, e)
             return
-        self._cache_flat = list(out[1:])
         req.slot = slot
-        req.t_first = time.perf_counter()
-        req.generated.append(first)
+        req.sent = 1
         self._positions[slot] = P
         self._by_slot[slot] = req
-        self._metrics.observe_ttft((req.t_first - req.t_submit) * 1000.0)
         self._metrics.set_gauge("free_kv_slots", len(self._free))
         self._metrics.set_gauge("active_streams",
                                 self._slots - len(self._free))
+        self._unread.append(_Unread(
+            first, probe, self._step_id, req=req,
+            span=(_telemetry._wall_us(), dict(
+                bucket=bucket, program=label, slot=slot, prompt_len=P))
+            if req.trace else None))
+
+    def _emit_first(self, unread, first, seen):
+        """A prefill's token, read: the request's first."""
+        req = unread.req
+        req.t_first = time.perf_counter()
+        req.generated.append(first)
+        if seen is not None:
+            req.probe.append(seen)
+        if unread.span is not None:
+            # dispatch to the token read: queued behind the step in flight,
+            # run, and the wait for the loop's next readback
+            us0, attrs = unread.span
+            req.trace.add_span("generate_prefill", us0,
+                               _telemetry._wall_us() - us0, **attrs)
+        self._metrics.observe_ttft((req.t_first - req.t_submit) * 1000.0)
         req.stream._emit(first)
-        if (req.eos_id is not None and first == req.eos_id):
+        self._finish_if_done(req, first)
+
+    def _finish_if_done(self, req, token):
+        if req.eos_id is not None and token == req.eos_id:
             self._complete(req, "eos")
         elif len(req.generated) >= req.max_new:
             self._complete(req, "length")
 
-    def _decode_once(self, active):
-        S = self._slots
+    def _decode_once(self):
+        """One turn of the pipeline.  Dispatch the next decode step for
+        every request that still has a token to come, then read, emit and
+        complete what was dispatched before it, which the device finished
+        or is finishing while the new step waits its turn there.  With no
+        step to dispatch, everything unread is drained, so that no token
+        waits for an arrival.
+
+        A request at ``max_new`` is known without its last token and rides
+        no further.  One that ends on ``eos_id`` is found when that token
+        is read, a step late: the row it wrote meanwhile is dead (a prefill
+        writes a slot from row 0) and its token is thrown away, counted in
+        ``slot_steps_discarded``."""
+        import jax
+        riders = [r for r in self._by_slot
+                  if r is not None and r.sent < r.max_new]
+        older = list(self._unread)
+        if not riders and not older:
+            return          # an admission that failed, alone in its step
+        # the decode step before, if its tokens are still unread
+        before = next((u for u in older if u.riders is not None), None)
+        reading = False
+        t0, offcpu_ns = time.perf_counter_ns(), 0
         try:
-            # lazy on the first step (ModelServer does not precompile): a
-            # failed compile fails this step's riders like a failed dispatch
-            probed = [r for r in active if r.probe is not None]
-            prog, _label = self._compile_probe() if probed \
-                else self._compile_decode()
-            # loop_offcpu_us: wall less this thread's CPU time over the two
-            # phases that never wait for the device (four thread-clock reads)
-            t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
-            with _telemetry.phase("stage"):
-                tok = onp.zeros(S, dtype=onp.int32)
-                act = onp.zeros(S, dtype=onp.float32)
-                now = time.perf_counter()
-                for r in active:
-                    tok[r.slot] = r.generated[-1]
-                    act[r.slot] = 1.0
-                    if r.t_decode0 is None:
-                        r.t_decode0 = now
-                pos = self._positions.copy()
-                raws = self._read_params()
-            offcpu_ns = (time.perf_counter_ns() - t0) - (time.thread_time_ns()
-                                                         - c0)
-            with _telemetry.phase("dispatch"):
-                out = self._dispatch(prog, raws, (tok, pos, act), "decode")
-            with _telemetry.phase("readback"):
-                nxt = onp.asarray(out[0])       # waits for the device
+            if riders:
+                t0, offcpu_ns = self._dispatch_step(riders)
+            reading = True
+            with _telemetry.phase(
+                    "readback", of_step=before and before.step_id):
+                # waits for the device, which has the new step queued
+                read = [(onp.asarray(u.tokens),
+                         jax.tree_util.tree_map(onp.asarray, u.probe))
+                        for u in older]
+        except Exception as e:      # noqa: BLE001
+            # a non-transient failure has no healthy path forward for the
+            # riders, with or without their rings, and the step still in
+            # flight goes with it: fail them honestly, keep serving
+            older = None    # e's traceback keeps this frame, not the arrays
+            self._rings_lost(at_read=reading)
+            self._fail_riders(e)
+            return
+        del self._unread[:len(older)]
+        t2, c2 = time.perf_counter_ns(), time.thread_time_ns()
+        with _telemetry.phase("emit") as span:
+            firsts, emitted, discarded = self._emit_read(older, read)
+            span.set(riders=firsts + emitted)
+        t3 = time.perf_counter_ns()
+        if riders:
+            offcpu_ns += (t3 - t2) - (time.thread_time_ns() - c2)
+            self._metrics.record_decode_step(
+                len(riders), emitted, (t3 - t0) / 1e6,
+                max(0, offcpu_ns) // 1000, before is not None)
+        elif emitted:
+            self._metrics.inc("tokens_generated", emitted)
+        if discarded:
+            self._metrics.inc("slot_steps_discarded", discarded)
+        with _telemetry.phase("release"):
+            # what was read goes back here and not at this function's
+            # return, so that the time freeing it takes has a name
+            # (docs/OBSERVABILITY.md, `release`)
+            del older, read, before
+
+    def _dispatch_step(self, riders):
+        """Stage and dispatch one decode step for ``riders`` and advance
+        them: positions move without looking at a token.  Returns the
+        start of ``stage`` and the ns of it the loop thread was off the
+        CPU."""
+        # lazy on the first step (ModelServer does not precompile): a
+        # failed compile fails the riders like a failed dispatch
+        probed = any(r.probe is not None for r in riders)
+        prog, _label = self._compile_probe() if probed \
+            else self._compile_decode()
+        # loop_offcpu_us: wall less this thread's CPU time over the two
+        # phases that never wait for the device (four thread-clock reads)
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+        with _telemetry.phase("stage"):
+            act = onp.zeros(self._slots, dtype=onp.float32)
+            now = time.perf_counter()
+            for r in riders:
+                act[r.slot] = 1.0
+                if r.t_decode0 is None:
+                    r.t_decode0 = now
+            pos = self._positions.copy()
+            raws = self._read_params()
+        offcpu_ns = (time.perf_counter_ns() - t0) - (time.thread_time_ns()
+                                                     - c0)
+        with _telemetry.phase("dispatch"):
+            tokens, probe = self._dispatch(prog, raws, (pos, act), "decode")
+        self._unread.append(_Unread(tokens, probe, self._step_id,
+                                    riders=[(r, r.slot) for r in riders]))
+        for r in riders:
+            r.sent += 1
+            self._positions[r.slot] += 1
+            if not r.wrapped and int(self._positions[r.slot]) >= \
+                    self._max_len:
+                r.wrapped = True
+                self._metrics.inc("cache_wraps")
+        return t0, offcpu_ns
+
+    def _emit_read(self, older, read):
+        """Hand what was read to its streams, oldest first: a prefill's
+        first token, a decode step's token a rider.  Returns the counts of
+        first tokens, of decode tokens emitted and of decode tokens thrown
+        away (their request had ended on ``eos_id`` a step before)."""
+        import jax
+        S = self._slots
+        firsts = emitted = discarded = 0
+        for unread, (toks, seen) in zip(older, read):
+            if unread.riders is None:
+                self._emit_first(unread, int(toks), seen)
+                firsts += 1
+                continue
             if self._step_counters:
                 self._metrics.add(**dict(zip(
-                    self._step_counters, (int(n) for n in nxt[S:]))))
-            if probed:
-                import jax
-                seen = jax.tree_util.tree_map(onp.asarray, out[-1])
-                for r in probed:
-                    r.probe.append(jax.tree_util.tree_map(
-                        lambda a, slot=r.slot: a[slot], seen))
-                out = out[:-1]
-        except Exception as e:      # noqa: BLE001
-            # a non-transient decode failure has no healthy path forward
-            # for the riders, with or without their rings — fail them
-            # honestly, keep serving
-            out = None      # e's traceback keeps this frame, not the rings
-            self._rings_lost()
-            self._fail_riders(e)        # `active` is every rider
-            return
-        self._cache_flat = list(out[1:])
-        t2, c2 = time.perf_counter_ns(), time.thread_time_ns()
-        with _telemetry.phase("emit", riders=len(active)):
-            for r in active:
-                t = int(nxt[r.slot])
-                self._positions[r.slot] += 1
+                    self._step_counters, (int(n) for n in toks[S:]))))
+            for r, slot in unread.riders:
+                if r.slot != slot:
+                    discarded += 1
+                    continue
+                t = int(toks[slot])
                 r.steps += 1
                 r.generated.append(t)
-                if not r.wrapped and int(self._positions[r.slot]) >= \
-                        self._max_len:
-                    r.wrapped = True
-                    self._metrics.inc("cache_wraps")
+                if r.probe is not None:
+                    r.probe.append(jax.tree_util.tree_map(
+                        lambda a, slot=slot: a[slot], seen))
                 r.stream._emit(t)
-                if r.eos_id is not None and t == r.eos_id:
-                    self._complete(r, "eos")
-                elif len(r.generated) >= r.max_new:
-                    self._complete(r, "length")
-        t3 = time.perf_counter_ns()
-        offcpu_ns += (t3 - t2) - (time.thread_time_ns() - c2)
-        self._metrics.record_decode_step(len(active), (t3 - t0) / 1e6,
-                                         max(0, offcpu_ns) // 1000)
-        with _telemetry.phase("release"):
-            # the step's arrays go back here and not at this function's
-            # return, so that the time freeing them takes has a name
-            # (docs/OBSERVABILITY.md, `release`)
-            del out, nxt, raws, tok, pos, act
+                self._finish_if_done(r, t)
+                emitted += 1
+        return firsts, emitted, discarded
 
     # -- completion --------------------------------------------------------
     def _release(self, req):
@@ -1120,4 +1277,5 @@ class GenerationEngine:
         self._aborted = True
         self.stop(timeout)
         if not self._thread.is_alive():
-            self._cache_flat = []
+            self._cache_flat, self._last_tok = [], None
+            self._unread.clear()

@@ -577,16 +577,10 @@ def test_donated_serving_program_warm_loads_and_consumes(cache_dir, program):
         c = warm.metrics.stats()["counters"]
         assert c["kv_inplace_dispatches"] == c["prefills"] + c["decode_steps"]
         # the same function and shapes, lowered with and without donors
-        S = warm.slots
         if program == "decode":
-            fn = warm._decode_pure()
-            inputs = [((S,), onp.int32), ((S,), onp.int32),
-                      ((S,), onp.float32)]
+            fn, sds = warm._decode_pure(), warm._input_sds()
         else:
-            fn = warm._prefill_pure(8)
-            inputs = [((1, 8), onp.int32), ((1,), onp.int32), ((), onp.int32)]
-        sds = [jax.ShapeDtypeStruct(sh, dt) for sh, dt in inputs]
-        sds += warm._ring_sds()
+            fn, sds = warm._prefill_pure(8), warm._input_sds(8)
         donated = mxcompile.fingerprint_lowered(warm._lower(fn, sds))
         plain = mxcompile.fingerprint_lowered(
             jax.jit(fn).lower(warm._read_params(), *sds))

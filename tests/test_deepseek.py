@@ -409,10 +409,10 @@ def test_transformer_lm_programs_keep_rings_donation_and_shapes():
         assert [(s, onp.dtype(d).name) for _k, s, d in eng._ring_specs] \
             == [((3, 4, 16, 8), "float32")] * 4
         text = eng._decode_prog[0].as_text()
-        # four ring arguments, each aliased to an output; tokens only (no
-        # counts) in the first output
+        # four ring arguments and the slots' last tokens, each aliased to
+        # an output; tokens only (no counts) in the first output
         header = text[:text.index("\n")]
-        assert header.count("may-alias") + header.count("must-alias") == 4
+        assert header.count("may-alias") + header.count("must-alias") == 5
         assert "s32[3]" in text and "s32[9]" not in text
         got = eng.generate([5, 6, 7], max_new_tokens=6, timeout=120)
         assert len(got["tokens"]) == 6

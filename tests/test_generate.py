@@ -400,6 +400,168 @@ def test_failure_before_the_call_keeps_the_rings(lm):
         eng.stop()
 
 
+# -- one decode step in flight -----------------------------------------------
+
+def _drains(kind="generate"):
+    """Loop steps in the flight recorder that read and dispatched nothing."""
+    steps = {}
+    for s in telemetry.flight_recorder():
+        if s["kind"] == kind:
+            steps.setdefault(s["step"], set()).add(s["phase"])
+    return sum("readback" in ph and "stage" not in ph
+               for ph in steps.values())
+
+
+def test_pipelined_loop_keeps_parity_through_churn(lm):
+    # the prompt lengths of test_incremental_decode_matches_full_forward,
+    # nine requests over three slots, outputs from one token (the
+    # prefill's alone) up: joins, leaves and refills all land while a
+    # decode step is in flight, and every token is the reference's
+    eng = _engine(lm, slots=3)
+    try:
+        plens = (1, 5, 8, 11, 16, 3, 9, 2, 14)
+        lens = (6, 1, 9, 2, 7, 12, 3, 5, 8)
+        prompts = [[(3 * i + 1 + k) % 60 for i in range(n)]
+                   for k, n in enumerate(plens)]
+        streams = [eng.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, lens)]
+        for p, n, s in zip(prompts, lens, streams):
+            got = s.result(timeout=120)
+            assert got["tokens"] == _full_forward_greedy(lm, p, n), (p, n)
+            assert got["finish_reason"] == "length"
+        eng.stop()      # the loop counts a step's tokens after it emits them
+        c = eng.metrics.stats()["counters"]
+        assert c["tokens_generated"] == sum(lens) - len(lens)
+        assert c["slot_steps_discarded"] == 0
+        assert 0 < c["decode_steps_overlapped"] < c["decode_steps"]
+        assert c["kv_inplace_dispatches"] == c["decode_steps"] + c["prefills"]
+        assert eng._unread == [] and len(eng._free) == 3
+    finally:
+        eng.stop()
+
+
+def test_eos_mid_batch_is_found_a_step_late_and_the_slot_serves_on(lm):
+    prompt, other, nxt = [7, 3, 5], [2, 9, 4, 1], [6, 6, 2]
+    ref = _full_forward_greedy(lm, prompt, 12)
+    eos = ref[3]
+    want = ref[:ref.index(eos) + 1]
+    assert len(want) < 11       # its next step is dispatched by then
+    eng = _engine(lm, slots=2)
+    try:
+        long = eng.submit(other, max_new_tokens=30)
+        next(iter(long.tokens(timeout=120)))         # rides beside it
+        got = eng.submit(prompt, max_new_tokens=12,
+                         eos_id=eos).result(timeout=120)
+        assert got["finish_reason"] == "eos" and got["tokens"] == want
+        # the slot it left, with the dead row in it, serves the next
+        again = eng.generate(nxt, max_new_tokens=9, timeout=120)
+        assert again["tokens"] == _full_forward_greedy(lm, nxt, 9)
+        assert long.result(timeout=120)["tokens"] == \
+            _full_forward_greedy(lm, other, 30)
+        # the step dispatched before the eos was read rode for nothing:
+        # counted when that step is read, one loop step after the result
+        eng.stop()
+        c = eng.metrics.stats()["counters"]
+        assert c["slot_steps_discarded"] == 1
+        assert c["slot_allocs"] == c["slot_frees"] == 3
+        # a discarded token is not a generated one
+        assert c["tokens_generated"] == len(want) - 1 + 8 + 29
+    finally:
+        eng.stop()
+
+
+def test_a_lone_request_gets_every_token_without_another_arrival(lm):
+    # nothing else arrives: the last step's tokens are drained, never held
+    # back for a next dispatch to read them
+    eng = _engine(lm)
+    try:
+        stream = eng.submit([1, 2, 3], max_new_tokens=5)
+        seen = [t for t in stream.tokens(timeout=30)]
+        assert seen == _full_forward_greedy(lm, [1, 2, 3], 5)
+        assert stream.done and stream.result(0)["finish_reason"] == "length"
+        eng.stop()
+        assert eng._unread == [] and len(eng._free) == eng.slots
+        c = eng.metrics.stats()["counters"]
+        assert c["decode_steps"] == 4 and c["decode_steps_overlapped"] == 3
+    finally:
+        eng.stop()
+
+
+def test_every_step_but_the_first_after_a_drain_is_overlapped(lm):
+    # two slots always taken: four requests of one length, so both leave
+    # at once, the loop drains, and the next two start it again
+    eng = _engine(lm, slots=2)
+    telemetry.reset()
+    try:
+        prompts = [[(7 * i + j) % 60 for j in range(4)] for i in range(4)]
+        first = [eng.submit(p, max_new_tokens=10) for p in prompts[:2]]
+        for s in first:
+            next(iter(s.tokens(timeout=120)))
+        rest = [eng.submit(p, max_new_tokens=10) for p in prompts[2:]]
+        for p, s in zip(prompts, first + rest):
+            assert s.result(timeout=120)["tokens"] == \
+                _full_forward_greedy(lm, p, 10)
+        eng.stop()
+        c = eng.metrics.stats()["counters"]
+        drains = _drains()
+        assert drains >= 1 and c["decode_steps"] >= 18
+        assert c["decode_steps_overlapped"] == c["decode_steps"] - drains
+        assert c["tokens_generated"] == 4 * 9
+    finally:
+        eng.stop()
+        telemetry.reset()
+
+
+class _Unreadable:
+    """A program's tokens that fail when the host reads them."""
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("device fell over mid-step")
+
+
+@pytest.mark.parametrize("where", ["dispatch", "read"])
+def test_failure_with_a_step_in_flight_fails_riders_and_serves_on(lm, where):
+    # both riders have a decode step dispatched and unread when the next
+    # dispatch fails before its call (the injected fault: the rings live
+    # on), or when a step's tokens cannot be read (what the engine holds
+    # then descends from the failed program: rebuilt)
+    eng = _engine(lm, slots=2)
+    try:
+        riders = [eng.submit([5, 6, 7], max_new_tokens=40),
+                  eng.submit([8, 1], max_new_tokens=40)]
+        for r in riders:
+            next(iter(r.tokens(timeout=120)))
+        if where == "dispatch":
+            with faults.inject("generate.decode@1:permanent"):
+                for r in riders:
+                    with pytest.raises(faults.PermanentFault):
+                        r.result(timeout=120)
+        else:
+            real = eng._decode_prog
+
+            def unreadable(*args):
+                out = real[0](*args)
+                return (_Unreadable(),) + tuple(out[1:])
+
+            eng._decode_prog = (unreadable, real[1])
+            for r in riders:
+                with pytest.raises(RuntimeError, match="fell over"):
+                    r.result(timeout=120)
+            eng._decode_prog = real
+        c = eng.metrics.stats()["counters"]
+        assert c["errors"] == 2 and c["slot_allocs"] == c["slot_frees"] == 2
+        assert c["kv_ring_rebuilds"] == (0 if where == "dispatch" else 1)
+        assert eng._unread == []
+        assert all(not r.is_deleted() for r in eng._cache_flat)
+        assert not eng._last_tok.is_deleted()
+        got = eng.generate([3, 1, 4], max_new_tokens=6, timeout=120)
+        assert got["tokens"] == _full_forward_greedy(lm, [3, 1, 4], 6)
+        c = eng.metrics.stats()["counters"]
+        assert c["kv_ring_rebuilds"] == (0 if where == "dispatch" else 1)
+    finally:
+        eng.stop()
+
+
 # -- beam search: incremental vs legacy referee -----------------------------
 
 @pytest.mark.slow

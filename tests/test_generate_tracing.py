@@ -3,7 +3,9 @@
 * each iteration of ``GenerationEngine._loop`` with work is one ``generate``
   step whose phases (``admit``, ``stage``, ``dispatch``, ``readback``,
   ``emit``, ``release``) land in the flight-recorder ring and, inside a ``jax.profiler``
-  session, as ``mx:generate.*`` events in the trace's host plane;
+  session, as ``mx:generate.*`` events in the trace's host plane; the loop
+  keeps one decode step in flight, so a step's ``readback`` and ``emit`` are
+  of the decode step that the step before dispatched (``of_step``);
 * the counters that stand in for spans a token, a request or a thread would
   make too many of (``loop_offcpu_us``, ``queue_wait_us``,
   ``emit_to_wire_us``, ``stream_write_us``, ``stream_tokens_written``);
@@ -121,6 +123,10 @@ def test_each_step_is_an_envelope_with_its_phases_inside(engine):
         assert sum(c["dur_us"] for c in children) <= env["dur_us"] + 2
     assert with_decode == decode_steps
     assert admits == 3
+    # the last tokens are drained by a step that dispatches nothing
+    last = sorted(steps)[-1]
+    assert sorted(s["phase"] for s in steps[last]) == \
+        ["emit", "readback", "release", "step"]
     first_admit = next(s for spans in steps.values() for s in spans
                        if s["phase"] == "admit")
     assert first_admit["args"] == {"bucket": 8, "slot": 0, "prompt_len": 3}
@@ -133,10 +139,42 @@ def test_the_wait_on_an_empty_queue_is_outside_any_step(engine):
     engine.generate([4, 5], max_new_tokens=1, timeout=30)
     engine.stop()
     steps = generate_steps()
-    # one token: the prefill's; a step with an admission and no decode
+    # one token: the prefill's; a step with an admission and no decode,
+    # which reads and emits what the admission dispatched (the drain)
     assert len(steps) == 1
-    assert sorted(s["phase"] for s in next(iter(steps.values()))) == \
-        ["admit", "step"]
+    spans = sorted(next(iter(steps.values())), key=lambda s: s["ts_us"])
+    assert [s["phase"] for s in spans if s["phase"] != "step"] == \
+        ["admit", "readback", "emit", "release"]
+    assert spans[[s["phase"] for s in spans].index("emit")]["args"] == \
+        {"riders": 1}
+
+
+def test_readback_of_a_decode_step_carries_that_steps_id(engine):
+    """One decode step in flight: a loop step stages and dispatches decode
+    step n+1, then reads and emits decode step n, which the loop step
+    before dispatched; its ``readback`` says so."""
+    engine.generate([1, 2, 3], max_new_tokens=6, timeout=30)
+    engine.stop()
+    steps = generate_steps()
+    ids = sorted(steps)
+    by_phase = [{s["phase"]: s for s in steps[i]} for i in ids]
+    dispatched = [i for i, ph in zip(ids, by_phase) if "dispatch" in ph]
+    assert len(dispatched) == 5
+    # the first loop step admits, dispatches decode step 1 and reads the
+    # prefill's token: no decode step is read yet
+    assert by_phase[0]["readback"]["args"] == {"of_step": None}
+    assert by_phase[0]["emit"]["args"] == {"riders": 1}
+    # every later one reads the decode step of the loop step before it
+    for before, ph in zip(ids, by_phase[1:]):
+        assert ph["readback"]["args"] == {"of_step": before}
+        assert ph["emit"]["args"] == {"riders": 1}
+    # and the order inside a loop step that dispatches
+    for ph in by_phase[:5]:
+        order = [ph[p]["ts_us"] for p in PHASES]
+        assert order == sorted(order)
+    assert "stage" not in by_phase[5] and "dispatch" not in by_phase[5]
+    c = engine.metrics.stats()["counters"]
+    assert c["decode_steps"] == 5 and c["decode_steps_overlapped"] == 4
 
 
 def test_telemetry_off_records_nothing_and_the_counters_still_count(engine):
@@ -145,6 +183,7 @@ def test_telemetry_off_records_nothing_and_the_counters_still_count(engine):
     assert telemetry.step_span("generate") is telemetry.phase("stage")
     out = engine.generate([1, 2, 3], max_new_tokens=5, timeout=30)
     assert len(out["tokens"]) == 5
+    engine.stop()       # the loop counts a step's tokens after it emits them
     assert telemetry.flight_recorder() == []
     assert telemetry._SPANS.value == before
     c = engine.metrics.stats()["counters"]
@@ -160,11 +199,14 @@ def test_decode_step_ms_runs_from_stage_to_the_end_of_emit(engine):
     hist = engine.metrics.stats()["decode_step"]
     assert hist["count"] == 7
     spans = [s for ss in generate_steps().values() for s in ss]
-    inside = sum(s["dur_us"] for s in spans
-                 if s["phase"] in PHASES[:4]) / 1e3
+    staged = {s["step"] for s in spans if s["phase"] == "stage"}
+    assert len(staged) == 7         # the eighth loop step is the drain
+    inside = sum(s["dur_us"] for s in spans if s["step"] in staged
+                 and s["phase"] in PHASES[:4]) / 1e3
     envelopes = sum(s["dur_us"] for s in spans if s["phase"] == "step") / 1e3
-    # the histogram covers the read-back (it used to stop at the enqueue):
-    # no less than the four phases it spans, no more than the envelopes
+    # one sample a loop step that dispatches, from its stage to the end of
+    # its emit (of the decode step before): no less than the four phases it
+    # spans, no more than the envelopes
     assert inside <= hist["mean_ms"] * hist["count"] * 1.001
     assert hist["mean_ms"] * hist["count"] <= envelopes
 
@@ -243,8 +285,12 @@ def test_generate_phases_land_in_the_profilers_host_plane(engine, tmp_path):
     for name in names:
         ids = {int(dict(e.stats)["step"]) for e in events[name]}
         assert ids <= set(ring) and ids
-    assert len(events["mx:generate.emit"]) == 5
-    assert len(events["mx:generate.release"]) == 5
+    # five loop steps dispatch a decode step; a sixth drains the last one
+    assert len(events["mx:generate.stage"]) == 5
+    assert len(events["mx:generate.dispatch"]) == 5
+    assert len(events["mx:generate.readback"]) == 6
+    assert len(events["mx:generate.emit"]) == 6
+    assert len(events["mx:generate.release"]) == 6
     env = {int(dict(e.stats)["step"]): e for e in events["mx:generate.step"]}
     for e in events["mx:generate.readback"]:
         parent = env[int(dict(e.stats)["step"])]
@@ -343,7 +389,7 @@ def test_handler_threads_adding_their_sums_lose_no_update():
             for _ in range(200):    # 200 streams ending on this thread
                 m.add(emit_to_wire_us=7, stream_write_us=3,
                       stream_tokens_written=5)
-                m.record_decode_step(2, 1.0, 11)
+                m.record_decode_step(2, 2, 1.0, 11, True)
         threads = [threading.Thread(target=handler) for _ in range(32)]
         for t in threads:
             t.start()
@@ -356,8 +402,9 @@ def test_handler_threads_adding_their_sums_lose_no_update():
     n = 32 * 200
     assert (c["emit_to_wire_us"], c["stream_write_us"],
             c["stream_tokens_written"]) == (7 * n, 3 * n, 5 * n)
-    assert (c["decode_steps"], c["tokens_generated"],
-            c["loop_offcpu_us"]) == (n, 2 * n, 11 * n)
+    assert (c["decode_steps"], c["decode_steps_overlapped"],
+            c["tokens_generated"], c["loop_offcpu_us"]) == \
+        (n, n, 2 * n, 11 * n)
     assert m.stats()["decode_step"]["count"] == n
 
 
@@ -452,6 +499,11 @@ def test_the_phases_add_up_on_the_hand_trace():
     ("wire_write_us", {"stream_write_us": 700,
                        "stream_tokens_written": 10}, 70.0),
     ("queue_wait_us", {"queue_wait_us": 1200, "prefills": 4}, 300.0),
+    ("overlap_share.decode", {"decode_steps_overlapped": 9,
+                              "decode_steps": 10}, 0.9),
+    ("overlap_share.decode", {"decode_steps": 10}, None),   # the parent's
+    ("overlap_share.dsv32", {"decode_steps_overlapped": 10,
+                             "decode_steps": 10}, 1.0),
 ])
 def test_counter_metrics_read_their_counters(metric, counters, want):
     from chipbench import common
@@ -482,6 +534,19 @@ def test_new_metrics_are_entries_of_the_benchmark_except_queue_wait():
                                        "queue_wait_us.json"))
 
 
+def test_overlap_share_is_an_entry_a_serving_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    # appended by PR 29, after everything that was there
+    tail = bench["per_layer"][-2:]
+    assert [(m["name"], m["workloads"]) for m in tail] == [
+        ("overlap_share.decode", ["gpt1.decode_full"]),
+        ("overlap_share.dsv32", ["deepseek_v32.decode_long"])]
+    for m in tail:
+        assert (m["layer"], m["moves"], m["unit"], m["better"]) == (
+            "host loop, serving", "serve_tokens_per_s", "share", "higher")
+
+
 def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("XLA_FLAGS", None)
@@ -498,6 +563,8 @@ def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
     for name in ("loop_offcpu_us.decode", "emit_to_wire_us", "wire_write_us"):
         assert readings[name]["unit"] == "us"
         assert readings[name]["value"] >= 0
+    # the clients keep every slot taken: the loop never drains in the window
+    assert readings["overlap_share.decode"]["value"] > 0.9
     assert readings["wire_write_us"]["value"] <= \
         readings["emit_to_wire_us"]["value"]
     # the span metrics need a device plane: a CPU prints none of them, and
