@@ -10,9 +10,8 @@ across processes — the TVM ahead-of-time stance (arXiv:1802.04799).
 by ``StableHLO fingerprint x backend x jax/jaxlib/mxnet_tpu versions``:
 
 * ``index.json`` — the record list (key, file, bytes, sha256, version
-  metadata, LRU timestamps), rewritten atomically (tmp + ``os.replace``,
-  the ``util.write_json_records`` discipline) so a kill mid-write can never
-  destroy it;
+  metadata, LRU timestamps), rewritten atomically (tmp + ``os.replace``)
+  so a kill mid-write can never destroy it;
 * ``<key>.bin`` — one blob per program, also written atomically.
 
 Robustness contract (tested in ``tests/test_compile_cache.py``):

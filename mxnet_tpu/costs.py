@@ -42,10 +42,8 @@ hardware roof is this program running".
 
 Always-on by design (``MXNET_COSTS``, default on): capture happens at
 compile time and execution accounting is a dict lookup plus four float
-ops inside the telemetry-gated span block — the paired
-``cost_overhead_captured_base`` record in ``benchmark/BENCH_DETAILS.json``
-gates the on/off delta within the standing 2% bar.  Metric tables and
-the cost_report / perf_sentinel recipes: docs/OBSERVABILITY.md.
+ops inside the telemetry-gated span block.  Metric tables and the
+cost_report recipe: docs/OBSERVABILITY.md.
 """
 from __future__ import annotations
 

@@ -6,9 +6,8 @@ The reference needs a 6k-LoC dependency engine because each CUDA kernel is an
 independently-launched task whose read/write ordering must be tracked with
 per-variable versions.  On this stack XLA/PjRt order operations by data
 dependence, so what an *engine* still buys is *dispatch amortization*: an
-un-jitted eager op pays full JAX tracing on every call (measured ~8.4 s/step
-of host dispatch against ~80 ms device time at BERT-large parameter counts —
-``benchmark/dispatch_profile.py``).  Two tiers close that gap (the operator-
+un-jitted eager op pays full JAX tracing on every call, far more host time
+than the device spends on the op.  Two tiers close that gap (the operator-
 fusion lever of arXiv:2301.13062 / arXiv:1802.04799):
 
 - **per-op executable cache** (:func:`cached_call`): every eager

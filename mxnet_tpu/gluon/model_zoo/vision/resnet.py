@@ -147,81 +147,14 @@ class BottleneckV2(HybridBlock):
     hybrid_forward = None
 
 
-class SpaceToDepthStem(HybridBlock):
-    """Space-to-depth reformulation of the 7x7/s2 stem conv — the
-    published TPU MLPerf ResNet trick: pad the input to 232^2, group 2x2
-    pixel phases into channels ((B,3,224,224) -> (B,12,116,116)), and run
-    the stride-2 7x7 conv as a stride-1 VALID 4x4 conv whose kernel is
-    the zero-padded 8x8 kernel's phase rearrangement.  Mathematically
-    EXACT (see ``s2d_weight_from_7x7``/tests): the 3-channel stride-2
-    conv starves the MXU's contraction lanes (3*49=147 taps over a
-    strided read); the 12-channel dense form is the shape the conv
-    emitter tiles well.
-    """
-
-    def __init__(self, channels, **kwargs):
-        super().__init__(**kwargs)
-        self.conv = Conv2D(channels, 4, 1, 0, use_bias=False,
-                           in_channels=12)
-
-    def forward(self, x):
-        from .... import ndarray as F
-        B, C, H, W = x.shape
-        # the 2x2 phase grouping and the final crop are only exact for
-        # even sizes — odd sizes would silently compute a shifted (wrong)
-        # stem instead of erroring; a hard raise, not assert, so the
-        # check survives python -O
-        if H % 2 or W % 2:
-            raise MXNetError(
-                f"SpaceToDepthStem needs even H/W, got {H}x{W}")
-        # pad 3 top/left (the 7x7's pad) + 5 bottom/right (to the even
-        # 232 plus one extra row the zero kernel row never reads)
-        xp = F.pad(x, pad_width=(0, 0, 0, 0, 3, 5, 3, 5))
-        Hp, Wp = (H + 8) // 2, (W + 8) // 2
-        y = xp.reshape(B, C, Hp, 2, Wp, 2) \
-              .transpose((0, 1, 3, 5, 2, 4)) \
-              .reshape(B, C * 4, Hp, Wp)
-        out = self.conv(y)
-        return out[:, :, :H // 2, :W // 2]
-
-    hybrid_forward = None
-
-
-def s2d_weight_from_7x7(w7):
-    """(Cout, 3, 7, 7) stem weight -> the exactly-equivalent
-    (Cout, 12, 4, 4) SpaceToDepthStem weight (zero-pad to 8x8, split
-    each spatial dim into (tap, phase), fold phases into channels)."""
-    import numpy as onp
-    w7 = onp.asarray(w7)
-    co = w7.shape[0]
-    w8 = onp.zeros((co, 3, 8, 8), w7.dtype)
-    w8[:, :, :7, :7] = w7
-    return (w8.reshape(co, 3, 4, 2, 4, 2)
-              .transpose(0, 1, 3, 5, 2, 4)
-              .reshape(co, 12, 4, 4))
-
-
 class ResNetV1(HybridBlock):
-    """``fused=True`` routes the forward through the Pallas fused
-    conv+BN+ReLU block kernels (ops/conv_fused.py) — same parameters, same
-    math, BN-apply tensors never materialized.  Supported for bottleneck
-    nets; basic-block nets fall back to the layer path.
-    ``stem_s2d=True`` replaces the 7x7/s2 stem conv with the exact
-    space-to-depth reformulation (``SpaceToDepthStem``)."""
-
     def __init__(self, block, layers, channels, classes=1000, thumbnail=False,
-                 fused=False, stem_s2d=False, **kwargs):
+                 **kwargs):
         super().__init__(**kwargs)
-        self._fused = fused
         assert len(layers) == len(channels) - 1
         self.features = HybridSequential()
         if thumbnail:
             self.features.add(_conv3x3(channels[0], 1, 0))
-        elif stem_s2d:
-            self.features.add(SpaceToDepthStem(channels[0]))
-            self.features.add(BatchNorm())
-            self.features.add(Activation("relu"))
-            self.features.add(MaxPool2D(3, 2, 1))
         else:
             self.features.add(Conv2D(channels[0], 7, 2, 3, use_bias=False))
             self.features.add(BatchNorm())
@@ -244,14 +177,6 @@ class ResNetV1(HybridBlock):
         return layer
 
     def forward(self, x):
-        if self._fused:
-            from ....base import DeferredInitializationError
-            from ....ops import conv_fused
-            if conv_fused.fused_supported(self):
-                try:
-                    return conv_fused.fused_resnet_forward(self, x)
-                except DeferredInitializationError:
-                    pass  # first call: layer path below completes shapes
         x = self.features(x)
         return self.output(x)
 

@@ -21,8 +21,7 @@ interventions in the PR-13 autoscaler mold:
   ``SPMDTrainer(grad_accum=...)`` microbatch split (global batch and
   bitwise grad sums held fixed) or tighten ``remat='auto'``;
 - **sustained MFU regression -> flag (or abort)** against a baseline
-  band — the same relative-noise-band treatment ``perf_sentinel``
-  applies to committed records;
+  band, relative to the baseline's own noise;
 - **plateau -> early stop** with a final checkpoint.
 
 Every decision — including denied ones — lands in a lock-guarded

@@ -38,10 +38,9 @@ module answers the memory questions:
   resource-exhausted recovery lever (purge executable caches + jax
   caches + gc) behind ``faults.classify``'s ``resource`` class.
 
-Always-on by design (``MXNET_MEMORY``, default on): the committed
-``mem_overhead_always_on`` record in ``benchmark/BENCH_DETAILS.json``
-gates the paired on/off delta within 2%.  ``memory.enable(False)`` turns
-every census/sampling call into an attribute check.  Bytes are *global*
+Always-on by design (``MXNET_MEMORY``, default on).
+``memory.enable(False)`` turns every census/sampling call into an
+attribute check.  Bytes are *global*
 logical bytes (a sharded array counts its full global size; divide by
 the shard count for per-chip HBM).  Metric tables, the crash-report
 schema and the ``memory_report`` recipe: docs/OBSERVABILITY.md and

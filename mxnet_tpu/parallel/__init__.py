@@ -823,9 +823,9 @@ class SPMDTrainer:
             compiled = lowered.compile()
             t2 = _time.perf_counter()
         # both ledgers key the step program by its StableHLO fingerprint
-        # (the ProgramCache key the first step() warm-loads by), so
-        # bench.py can read the fused step's measured flops back out of
-        # the cost ledger instead of hand-rolled analytic MACs
+        # (the ProgramCache key the first step() warm-loads by), so a
+        # caller of precompile() can read the fused step's flops back
+        # out of the cost ledger by the key it returns
         key = None
         try:
             key = _compile.fingerprint_lowered(lowered)

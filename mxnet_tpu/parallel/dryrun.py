@@ -239,9 +239,7 @@ def _per_device_footprint(trainer):
 
 
 def zero_sweep(n_devices, steps=12, warmup=3, ledger_dir=None):
-    """The ZeRO-ladder memory/overlap referee behind the
-    ``parallel_zero*`` BENCH_DETAILS records
-    (``benchmark/dispatch_profile.py --zero sweep``).
+    """The ZeRO-ladder memory/overlap referee (docs/PARALLEL.md).
 
     Runs BERT-tiny data-parallel training at zero1, zero2 and zero3 on
     the same net/data/optimizer and returns per-device footprint
@@ -354,15 +352,13 @@ def zero_sweep(n_devices, steps=12, warmup=3, ledger_dir=None):
 def zero_sweep_guarded(n_devices=8, steps=12, ledger_dir=None,
                        timeout=None):
     """Run :func:`zero_sweep` in a subprocess on a FORCED ``n_devices``
-    virtual CPU mesh — the deterministic referee shape behind the
-    committed ``parallel_zero*`` records.
+    virtual CPU mesh — the deterministic referee shape.
 
-    The byte-shrink bars (zero2 >= 40%, zero3 >= 60% vs zero1) are
-    functions of the dp degree: at dp=8 the BERT-tiny ladder measures
-    ~41%/~82%, at dp=4 zero2 would land at ~33% and "fail" without any
-    code change.  Pinning the subprocess to the same virtual mesh shape
-    on every host makes the committed record comparable across reruns —
-    the sharding/scheduling referee does not need real accelerators, the
+    The byte shrinks against zero1 are functions of the dp degree: at
+    dp=8 the BERT-tiny ladder measures ~41%/~82%, at dp=4 zero2 would
+    land at ~33% without any code change.  Pinning the subprocess to the
+    same virtual mesh shape on every host makes the result comparable
+    across reruns — the sharding/scheduling referee does not need real accelerators, the
     same reasoning as :func:`bert_large_budget_guarded`.  Raises on a
     nonzero subprocess rc (a crashed sharded step is a real failure);
     returns the :func:`zero_sweep` result dict."""

@@ -22,8 +22,8 @@ supervised worker processes and ``Router`` load-balances across them
 with transparent retry, per-replica circuit breakers, hedged dispatch,
 fleet-level shedding and zero-drop rolling weight swaps; ``Autoscaler``
 (``autoscaler.py``) resizes the fleet off the federated gauges through
-the same zero-drop drain machinery — ``serve_bench.py --replicas N
---chaos`` and ``--chaos-net`` are the chaos acceptance proofs.
+the same zero-drop drain machinery (``tests/test_fleet.py`` holds the
+chaos proofs: nothing accepted is lost).
 
 Generative serving (``generate.py``): :class:`GenerationEngine` runs
 KV-cached incremental decode with continuous batching — one
@@ -31,11 +31,10 @@ shape-bucketed prefill program plus one fixed-shape decode program over
 the whole in-flight batch, requests joining and leaving at token
 boundaries — served through the same ``ModelServer``/``Router`` stack
 as a streaming ``/generate`` endpoint (docs/SERVING.md "Generative
-serving"; ``benchmark/generate_bench.py`` is the tokens/s + TTFT
-acceptance harness).
+serving").
 
-See ``docs/SERVING.md`` for architecture and knobs, and
-``benchmark/serve_bench.py`` for the latency-vs-throughput harness.
+See ``docs/SERVING.md`` for architecture and knobs; tokens/s and the gap
+between tokens are measured on the chip by ``chipbench/`` (PERF.md).
 """
 from .errors import (ServingError, QueueFullError,  # noqa: F401
                      DeadlineExceededError, EngineClosedError,

@@ -26,11 +26,10 @@ Scaling actions go strictly through the existing zero-drop machinery:
 Every decision — including the denied ones — lands in a bounded log
 surfaced through ``Router.status()`` → ``/statusz`` (``autoscaler``
 section), the crash report's ``fleet`` section, and the
-``fleet/scale_*`` metrics (docs/OBSERVABILITY.md).  The chaos-provable
-acceptance run is ``benchmark/serve_bench.py --chaos-net``: a storm
-with a slow replica, torn responses and a partition landing during a
-scale-down must lose zero idempotent requests and converge to the
-target size (docs/SERVING.md "Autoscaler lifecycle").
+``fleet/scale_*`` metrics (docs/OBSERVABILITY.md).  A scale-down that
+lands in a storm or races a rolling swap must lose zero idempotent
+requests and converge to the target size (``tests/test_fleet.py``;
+docs/SERVING.md "Autoscaler lifecycle").
 """
 from __future__ import annotations
 

@@ -86,8 +86,8 @@ class ServingClient:
             connect_timeout_s if connect_timeout_s is not None
             else min(self.timeout_s, 5.0))
         # ``pool``: None -> the process-wide shared keep-alive pool;
-        # False -> the legacy fresh-connection-per-request wire (the
-        # paired-overhead referee in serve_bench needs it); or a
+        # False -> a fresh connection per request (chipbench's warm-up
+        # client uses it so that no parked connection outlives it); or a
         # ConnectionPool instance of your own
         self._pool = _transport.shared_pool() if pool is None \
             else (pool or None)

@@ -387,7 +387,7 @@ class InferenceEngine:
                 raw_out = prog(*padded)
             if not isinstance(raw_out, (tuple, list)):
                 raw_out = (raw_out,)
-            # host readback is the sync point (asnumpy discipline, bench.py)
+            # host readback is the sync point (asnumpy discipline)
             outs = tuple(onp.asarray(o)[:n_valid] for o in raw_out)
             if prog_flops:
                 # per-execution MFU against the cost ledger: set on both

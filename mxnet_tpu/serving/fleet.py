@@ -49,10 +49,9 @@ fault point (in ``InferenceEngine``) and the router-side
 a replica mid-request-storm, and the wire-level ``net.connect`` (here)
 / ``net.request`` / ``net.response`` (``http.py``) points express the
 degraded-network kinds ``delay``/``reset``/``torn``/``blackhole``;
-``benchmark/serve_bench.py --replicas N --chaos`` and ``--chaos-net``
-are the committed acceptance proofs (zero lost idempotent requests
-across a crash / a slow+torn+partitioned storm, breaker trip+recover,
-autoscaler convergence, p99 recovery within SLO, zero-drop rollout).
+``tests/test_fleet.py`` holds the acceptance proofs (zero lost idempotent
+requests across a crash / slow, torn and partitioned wires, breaker
+trip+recover, autoscaler convergence, zero-drop rollout).
 Architecture, drain protocol and SLO knobs: docs/SERVING.md.
 """
 from __future__ import annotations
@@ -1857,9 +1856,7 @@ class Router:
             return {k: b.status(now) for k, b in self._breakers.items()}
 
     def set_resilience(self, breakers=None, hedging=None):
-        """Runtime toggle for the breaker/hedging machinery (the paired
-        overhead proof in ``serve_bench`` flips these per request
-        pair)."""
+        """Runtime toggle for the breaker/hedging machinery."""
         if breakers is not None:
             self.breakers_enabled = bool(breakers)
         if hedging is not None:

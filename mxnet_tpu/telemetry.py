@@ -972,8 +972,8 @@ def new_trace():
     The head-sample coin decides at mint time: a sampled-out request
     gets :data:`NULL_TRACE` — the same shared no-op constant as
     ``MXNET_TRACE_SAMPLE=0``, so the requests you are *not* looking at
-    pay nothing (the ``trace_overhead_sampling_off`` record in
-    benchmark/BENCH_DETAILS.json gates this).  A head-sample hit is
+    pay nothing (``tests/test_tracing.py`` holds the identity).  A
+    head-sample hit is
     traced at every hop and guaranteed a spool record."""
     rate = _sample_rate()
     if rate <= 0.0:

@@ -10,8 +10,7 @@ from __future__ import annotations
 import os
 
 __all__ = ["getenv", "setenv", "config", "register_env", "get_gpu_count",
-           "set_np", "reset_np", "is_np_array",
-           "write_json_records"]
+           "set_np", "reset_np", "is_np_array"]
 
 _ENV_REGISTRY: dict[str, tuple[type, object, str]] = {}
 
@@ -338,48 +337,6 @@ def setenv(name, value):
 def config():
     """The full effective configuration."""
     return {name: getenv(name) for name in sorted(_ENV_REGISTRY)}
-
-
-def write_json_records(path, records, append=True, keep=None):
-    """Persist a list of JSON records (the BENCH_DETAILS.json discipline,
-    shared by ``bench.py`` and ``benchmark/serve_bench.py``).
-
-    ``append=True`` merges with the record list already on disk;
-    ``append=False`` rewrites, carrying over any existing records matching
-    the optional ``keep`` predicate (bench.py preserves serve_bench.py's
-    ``serving_*`` records this way, so the two tools can be run in either
-    order).  An existing-but-unparseable file (a run killed mid-write) is
-    set aside as ``path + ".corrupt"`` rather than clobbered, and the
-    write itself goes through a tmp file + ``os.replace`` so a kill
-    mid-write can never destroy the previous records.  Best-effort by
-    design: record-keeping IO must never take down the measurement run.
-    """
-    import json
-
-    existing = []
-    try:
-        with open(path) as f:
-            loaded = json.load(f)
-        existing = loaded if isinstance(loaded, list) else [loaded]
-    except ValueError:
-        try:
-            os.replace(path, path + ".corrupt")
-        except OSError:
-            pass
-    except OSError:
-        pass
-    if append:
-        merged = existing + list(records)
-    else:
-        merged = ([r for r in existing if keep(r)] if keep else []) \
-            + list(records)
-    try:
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(merged, f, indent=1)
-        os.replace(tmp, path)
-    except OSError:
-        pass
 
 
 def get_gpu_count():

@@ -696,21 +696,3 @@ def test_prefetching_iter_num_prefetch_exposed():
     assert sum(1 for _ in it) == 5
     with pytest.raises(mx.MXNetError):
         io.PrefetchingIter(base, num_prefetch=0)
-
-
-def test_bench_writers_lint_repo_clean_and_catches_violation(tmp_path):
-    import importlib.util
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_writers",
-        os.path.join(repo, "tools", "check_bench_writers.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.check(repo) == []        # the repo invariant itself
-    bad = tmp_path / "bad_bench.py"
-    bad.write_text(
-        'import json\n'
-        'path = "BENCH_DETAILS.json"\n'
-        'json.dump([1], open("BENCH_DETAILS.json", "w"))\n')
-    vs = mod.check_file(str(bad))
-    assert any("write_json_records" in v for v in vs)

@@ -456,8 +456,7 @@ def test_int8_residency_drift_r50_eval_path():
 
 @pytest.mark.slow
 def test_int8_residency_drift_bert_ffn_eval_path():
-    """BERT-base FFN geometry (768 -> 3072, the committed serve_bench
-    config): top-1 drift vs fp32 within the 0.5% ceiling and the
+    """BERT-base FFN geometry (768 -> 3072): top-1 drift vs fp32 within the 0.5% ceiling and the
     inter-layer fold actually engaged."""
     from mxnet_tpu.serving import InferenceEngine
 

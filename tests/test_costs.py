@@ -3,8 +3,7 @@ sites (fresh compile / AOT / warm load, warm flagged + upgraded), MFU
 accounting on step_flush and serving execute spans, block-level
 attribution of captured segments (sum-vs-cost_analysis referee, VJP
 CSE correction, block scopes), the ledger-vs-analytic MFU referee on
-Dense/Conv, crash-report schema v4, tools/cost_report.py,
-tools/perf_sentinel.py and the check_bench_writers flop_source lint
+Dense/Conv, crash-report schema v4 and tools/cost_report.py
 (docs/OBSERVABILITY.md "Compute-cost observability")."""
 import importlib.util
 import json
@@ -265,7 +264,7 @@ def test_attribution_disabled_by_env(tmp_path, monkeypatch):
 # MFU referee: ledger flops vs analytic 2xMACs
 # ---------------------------------------------------------------------------
 def test_mfu_referee_dense_ledger_vs_analytic(tmp_path, monkeypatch):
-    """bench.py satellite referee: the fused SPMD step's cost_analysis()
+    """MFU referee: the fused SPMD step's cost_analysis()
     flops agree with the analytic 2xMACs convention within 10% on a
     dense stack (fwd + dgrad + wgrad = 3x forward)."""
     import jax
@@ -468,124 +467,8 @@ def test_trace_report_mfu_columns():
 
 
 # ---------------------------------------------------------------------------
-# perf sentinel
+# lint: metric names
 # ---------------------------------------------------------------------------
-def _rec(metric, value, unit, **extra):
-    return {"metric": metric, "value": value, "unit": unit,
-            "vs_baseline": None, "extra": extra}
-
-
-def test_perf_sentinel_pass_and_seeded_regression(capsys):
-    ps = _load_tool("perf_sentinel")
-    base = [_rec("resnet50_v1_train_throughput", 2400.0, "img/s/chip"),
-            _rec("fused_step_captured_base", 200.0, "ms_per_step"),
-            _rec("mem_overhead_always_on", 1.9, "pct"),
-            _rec("fleet_chaos_zero_drop", 0, "lost_requests")]
-    # unchanged tree: identical records pass
-    verdicts = ps.compare(base, base)
-    assert all(v["verdict"] == "pass" for v in verdicts)
-    assert ps.render(verdicts) == 0
-    # seeded slowdown: throughput -40% and step +60% both regress,
-    # direction-aware; the absolute-bar metric fails past its bar
-    fresh = [_rec("resnet50_v1_train_throughput", 1440.0, "img/s/chip"),
-             _rec("fused_step_captured_base", 320.0, "ms_per_step"),
-             _rec("mem_overhead_always_on", 2.6, "pct"),
-             _rec("fleet_chaos_zero_drop", 1, "lost_requests")]
-    verdicts = ps.compare(fresh, base)
-    by = {v["metric"]: v for v in verdicts}
-    assert by["resnet50_v1_train_throughput"]["verdict"] == "regress"
-    assert by["fused_step_captured_base"]["verdict"] == "regress"
-    assert by["mem_overhead_always_on"]["verdict"] == "regress"
-    assert by["fleet_chaos_zero_drop"]["verdict"] == "regress"
-    assert ps.render(verdicts) == 1
-    out = capsys.readouterr().out
-    lines = [json.loads(l) for l in out.strip().splitlines()]
-    assert any("sentinel_summary" in l and
-               l["sentinel_summary"]["verdict"] == "regress"
-               for l in lines)
-
-
-def test_perf_sentinel_noise_bands_and_edges():
-    ps = _load_tool("perf_sentinel")
-    base = [_rec("io_overlap_device_prefetch", 2.8, "x"),
-            _rec("some_new_metric", 1.0, "widgets"),
-            _rec("trace_coverage", 0.99, "fraction_of_wall")]
-    # within the documented 60% io band: pass; -70%: regress
-    fresh = [_rec("io_overlap_device_prefetch", 1.3, "x")]
-    v = ps.compare(fresh, base)[0]
-    assert v["verdict"] == "pass" and v["tol_pct"] == 60.0
-    v = ps.compare([_rec("io_overlap_device_prefetch", 0.7, "x")],
-                   base)[0]
-    assert v["verdict"] == "regress"
-    # unknown unit: explicit skip, never a guess
-    v = ps.compare([_rec("some_new_metric", 0.1, "widgets")], base)[0]
-    assert v["verdict"] == "skip"
-    # coverage keeps its absolute 0.90 gate even when the committed
-    # number is higher
-    v = ps.compare([_rec("trace_coverage", 0.91, "fraction_of_wall")],
-                   base)[0]
-    assert v["verdict"] == "pass"
-    v = ps.compare([_rec("trace_coverage", 0.85, "fraction_of_wall")],
-                   base)[0]
-    assert v["verdict"] == "regress"
-    # a per-record noise_pct declaration wins over the defaults
-    base2 = [_rec("fused_step_captured_base", 100.0, "ms_per_step")]
-    fresh2 = [_rec("fused_step_captured_base", 140.0, "ms_per_step",
-                   noise_pct=50.0)]
-    assert ps.compare(fresh2, base2)[0]["verdict"] == "pass"
-    # a required metric missing from the fresh run fails the gate
-    verdicts = ps.compare([], base,
-                          require=["trace_coverage"])
-    assert any(v["verdict"] == "missing" for v in verdicts)
-    assert ps.render(verdicts, out=open(os.devnull, "w")) == 1
-
-
-def test_perf_sentinel_committed_baseline_self_check():
-    """The committed trajectory judged against itself must pass — the
-    'unchanged tree' half of the acceptance criterion."""
-    ps = _load_tool("perf_sentinel")
-    with open(os.path.join(_REPO, "benchmark",
-                           "BENCH_DETAILS.json")) as f:
-        base = json.load(f)
-    verdicts = ps.compare(
-        base, base,
-        require=[r["metric"] for r in base
-                 if isinstance(r, dict) and r.get("metric")])
-    bad = [v for v in verdicts if v["verdict"] in ("regress", "missing")]
-    assert not bad, bad
-
-
-# ---------------------------------------------------------------------------
-# lint: flop_source discipline
-# ---------------------------------------------------------------------------
-def test_check_bench_writers_flop_source_lint(tmp_path):
-    cb = _load_tool("check_bench_writers")
-    bad = (
-        'PATH = "BENCH_DETAILS.json"\n'
-        'from mxnet_tpu.util import write_json_records\n'
-        'def emit(*a, **k): pass\n'
-        'emit("m", 1.0, "tok/s", None, "none", mfu=0.5)\n'
-    )
-    f = tmp_path / "badbench.py"
-    f.write_text(bad)
-    v = cb.check_file(str(f))
-    assert any("flop_source" in s for s in v)
-    good = bad.replace("mfu=0.5", 'mfu=0.5, flop_source="analytic"')
-    f.write_text(good)
-    assert not cb.check_file(str(f))
-    # record-dict shape: a "*_flops" key without flop_source is flagged
-    bad2 = (
-        'P = "BENCH_DETAILS.json"\n'
-        'from mxnet_tpu.util import write_json_records\n'
-        'r = {"metric": "x", "extra": {"step_flops": 1}}\n'
-    )
-    f.write_text(bad2)
-    assert any("flop_source" in s for s in _load_tool(
-        "check_bench_writers").check_file(str(f)))
-    # the repo's own bench writers are clean under the grown lint
-    assert cb.check() == []
-
-
 def test_check_metric_names_requires_costs_family():
     cm = _load_tool("check_metric_names")
     assert "costs" in cm._REQUIRED_SUBSYSTEMS

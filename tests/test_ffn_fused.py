@@ -142,3 +142,22 @@ def test_model_level_fused_matches_layer_path_eval():
     err = onp.abs(outs["1"] - outs["0"]).max()
     scale = onp.abs(outs["0"]).max()
     assert err <= 0.008 * max(scale, 1.0), (err, scale)
+
+
+def test_mixed_dtype_falls_back_to_the_layer_path():
+    """float32 parameters under bfloat16 activations: the kernel's dtype
+    gate sends the block down the layer path, and a training step through
+    it is finite instead of failing at the first call."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd, nd
+    from mxnet_tpu.models.bert import PositionwiseFFN
+
+    mx.random.seed(0)
+    ffn = PositionwiseFFN(units=256, hidden_size=1024, dropout=0.1)
+    ffn.initialize()
+    x = nd.array(onp.random.RandomState(0).randn(8, 128, 256)
+                 .astype("float32")).astype("bfloat16")
+    with autograd.record():
+        loss = ffn(x).astype("float32").sum()
+    loss.backward()
+    assert onp.isfinite(float(loss.asnumpy()))

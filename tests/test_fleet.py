@@ -222,6 +222,9 @@ def test_fleet_level_shedding_on_outstanding_cap():
         assert time.perf_counter() - t0 < 0.05
         assert f1.result(timeout=30) is not None
         assert f2.result(timeout=30) is not None
+        # the burst over, the fleet serves again: shedding left no
+        # outstanding count behind
+        assert router.submit(x).result(timeout=30) is not None
     assert _fleet_counter("shed") >= before + 1
     s1.stop()
 

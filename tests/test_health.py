@@ -680,12 +680,3 @@ def test_health_metrics_registered_and_snapshot():
     # prometheus exposition stays parseable with the new family
     text = telemetry.prometheus_text()
     assert "mxnet_health_steps_recorded" in text
-
-
-def test_sentinel_knows_health_bars():
-    ps = _load_tool("perf_sentinel")
-    assert ps.TOLERANCES["health_overhead_captured_base"]["max"] == 2.0
-    assert ps.TOLERANCES["run_ledger_contiguity_violations"]["max"] == 0
-    assert ps.TOLERANCES["health_anomaly_clean_false_positives"]["max"] \
-        == 0
-    assert ps.TOLERANCES["health_anomaly_seeded_flags"]["min"] == 2

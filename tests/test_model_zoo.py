@@ -11,6 +11,7 @@ from mxnet_tpu.gluon.model_zoo.vision import get_model
     pytest.param("densenet121", 64, marks=pytest.mark.slow),
     pytest.param("squeezenet1.1", 224, marks=pytest.mark.slow),
     ("vgg11_bn", 32),
+    ("resnet18_v1", 32),
 ])
 def test_zoo_forward(name, hw):
     mx.random.seed(0)
@@ -40,39 +41,51 @@ def test_inception_v3_structure():
     assert n_params > 100    # 94 convs + BNs
 
 
-def test_s2d_stem_exact():
-    """SpaceToDepthStem with the transformed weight reproduces the
-    7x7/s2 stem conv EXACTLY (same math, reordered)."""
-    import numpy as onp
-    from mxnet_tpu import nd
-    from mxnet_tpu.gluon import nn
-    from mxnet_tpu.gluon.model_zoo.vision.resnet import (
-        SpaceToDepthStem, s2d_weight_from_7x7)
+def test_resnet_v1_trains_and_eval_reads_running_stats():
+    """The one tier-1 run of ``ResNetV1``'s own forward under a trainer: a
+    few ``gluon.Trainer`` steps on a two-stage bottleneck net at 32 x 32
+    bring the loss down, move the BatchNorm running statistics, and an
+    eval-mode forward reads those statistics without changing them."""
+    from mxnet_tpu import autograd, gluon
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import (BottleneckV1,
+                                                         ResNetV1)
 
-    rng = onp.random.RandomState(0)
-    x = nd.array(rng.randn(2, 3, 224, 224).astype("float32"))
-
-    ref = nn.Conv2D(64, 7, 2, 3, use_bias=False, in_channels=3)
-    ref.initialize()
-    y_ref = ref(x).asnumpy()
-
-    s2d = SpaceToDepthStem(64)
-    s2d.initialize()
-    s2d.conv.weight.set_data(
-        nd.array(s2d_weight_from_7x7(ref.weight.data().asnumpy())))
-    y = s2d(x).asnumpy()
-    assert y.shape == y_ref.shape
-    onp.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_r50_s2d_builds_and_runs():
-    import numpy as onp
-    from mxnet_tpu import nd
-    from mxnet_tpu.gluon.model_zoo.vision import resnet50_v1
-    net = resnet50_v1(classes=10, stem_s2d=True)
+    mx.random.seed(0)
+    net = ResNetV1(BottleneckV1, [1, 1], [16, 32, 64], classes=4)
     net.initialize()
-    x = nd.array(onp.random.RandomState(0).randn(2, 3, 224, 224)
-                 .astype("float32"))
-    out = net(x)
-    assert out.shape == (2, 10)
+    rng = onp.random.RandomState(0)
+    x = nd.array(rng.randn(8, 3, 32, 32).astype("float32"))
+    y = nd.array(rng.randint(0, 4, (8,)).astype("float32"))
+    net(x)  # complete deferred init
+
+    def running():
+        return {k: v.data().asnumpy().copy()
+                for k, v in net._collect_params_with_prefix().items()
+                if "running" in k}
+
+    fresh = running()
+    lossfn = gluon.loss.SoftmaxCrossEntropyLoss()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05, "momentum": 0.9})
+    losses = []
+    for _ in range(6):
+        with autograd.record():
+            loss = lossfn(net(x), y).mean()
+        loss.backward()
+        trainer.step(1)
+        losses.append(float(loss.asnumpy()))
+    assert onp.isfinite(losses).all(), losses
+    assert losses[-1] < losses[0], losses
+
+    trained = running()
+    assert any(not onp.array_equal(trained[k], fresh[k]) for k in fresh)
+    out = net(x).asnumpy()
+    assert out.shape == (8, 4) and onp.isfinite(out).all()
+    onp.testing.assert_array_equal(net(x).asnumpy(), out)
+    for k, v in running().items():
+        onp.testing.assert_array_equal(v, trained[k])
+    name, stat = next((k, v) for k, v in
+                      net._collect_params_with_prefix().items()
+                      if k.endswith("running_mean"))
+    stat.set_data(nd.array(trained[name] + 1.0))
+    assert not onp.allclose(net(x).asnumpy(), out)

@@ -1,4 +1,4 @@
-"""opperf harness smoke (reference: benchmark/opperf, SURVEY.md §6)."""
+"""opperf harness smoke (reference: upstream's opperf package, SURVEY.md §6)."""
 import json
 import os
 import subprocess
@@ -14,7 +14,7 @@ def test_opperf_smoke(tmp_path):
     out = tmp_path / "r.json"
     env = dict(os.environ, PYTHONPATH=REPO)
     res = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "opperf.py"),
+        [sys.executable, os.path.join(REPO, "tools", "opperf.py"),
          "--cpu", "--ops", "relu,softmax,FullyConnected",
          "--json", str(out)],
         capture_output=True, text=True, timeout=420, env=env)

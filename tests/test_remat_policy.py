@@ -52,23 +52,25 @@ def test_policies_cheapest_first():
     assert sum(cands[-1][1]) == 6
 
 
-def test_search_measures_and_validates():
-    """Every candidate compiles, the measured temp/peak curve is
-    monotone from none to all, the chosen policy minimizes peak, and
-    the numeric validation proves the rewritten program bit-identical
-    to the unrewritten one."""
+def test_search_measures_and_validates(monkeypatch):
+    """Every candidate compiles, the chosen policy has the least measured
+    peak, and the numeric validation proves the rewritten program
+    bit-identical to the unrewritten one.  Which way the peak moves from
+    none to all is the backend's: XLA:CPU's allocator reports 'all' ABOVE
+    'none' at every size tried (4,793,740 against 4,689,292 bytes here,
+    121.7 against 119.5 MB at 16 x 256), so no direction is asserted on a
+    CPU and the search rightly rewrites nothing there."""
     net = _stack(layers=4, units=32, hidden=128)
     x = nd.array(onp.random.RandomState(0).randn(4, 64, 32)
                  .astype("float32"))
     rep = rp.auto_remat(net, x, validate=True)
     rows = {r["policy"]: r for r in rep["candidates"]}
     assert all(r["compiled"] for r in rep["candidates"])
-    assert rows["all"]["peak_bytes"] < rows["none"]["peak_bytes"]
-    assert rows["all"]["temp_bytes"] < rows["none"]["temp_bytes"]
-    assert rep["chosen"] == min(rows, key=lambda p: rows[p]["peak_bytes"])
+    assert all(r["peak_bytes"] and r["temp_bytes"]
+               for r in rep["candidates"])
+    assert rep["chosen"] == min(
+        rows, key=lambda p: (rows[p]["peak_bytes"], rows[p]["n_remat"]))
     assert rep["structural_ok"]
-    assert rep["numeric"]["ok"]
-    assert rep["numeric"]["bit_identical"]
     # the winner's flags are applied to the net
     blocks = rp.candidate_blocks(net)
     applied = [bool(getattr(b, "_remat", False)) for b in blocks]
@@ -76,6 +78,20 @@ def test_search_measures_and_validates():
     # every candidate landed in the ledger under its own entry
     kinds = [e for e in memory.ledger() if e["kind"] == "remat_policy"]
     assert len(kinds) >= len(rep["candidates"])
+    if rep["chosen"] != "none":
+        assert rep["numeric"]["ok"] and rep["numeric"]["bit_identical"]
+        return
+    # nothing was rewritten, so nothing was validated: steer the chooser
+    # (first fit in candidate order under a budget everything fits) to
+    # the full rewrite and hold THAT to the unrewritten program
+    monkeypatch.setattr(rp, "policies", lambda n: [
+        ("all", [True] * n), ("none", [False] * n)])
+    rep = rp.auto_remat(net, x, budget_bytes=1 << 62, validate=True)
+    assert rep["chosen"] == "all" and rep["fits_budget"]
+    assert rep["mask"] == [True] * len(blocks)
+    assert rep["structural_ok"]
+    assert rep["numeric"]["ok"]
+    assert rep["numeric"]["bit_identical"]
 
 
 @pytest.mark.slow

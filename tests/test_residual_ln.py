@@ -108,3 +108,20 @@ def test_encoder_layer_fused_matches_layer_path_eval():
     err = onp.abs(outs["1"] - outs["0"]).max()
     scale = onp.abs(outs["0"]).max()
     assert err <= 0.02 * max(scale, 1.0), (err, scale)
+
+
+def test_forward_finite_when_mean_dwarfs_std():
+    """Rows with |mean| >> std (1e4 against 1e-2): the one-pass variance
+    cancels to a rounding residue that may be negative; the kernel clamps
+    it, so the output stays finite like the two-pass reference's."""
+    B, L, d = 16, 512, 768
+    assert rl.use_residual_ln(B, L, d, "float32", 0.0)
+    rng = onp.random.RandomState(0)
+    x = jnp.asarray(1e4 + 1e-2 * rng.randn(B, L, d), jnp.float32)
+    inner = jnp.zeros((B, L, d), jnp.float32)
+    g = jnp.ones((d,), jnp.float32)
+    b = jnp.zeros((d,), jnp.float32)
+    assert onp.isfinite(onp.asarray(
+        rl.residual_ln(x, inner, g, b, 0.0, None))).all()
+    assert onp.isfinite(onp.asarray(
+        rl.residual_ln_ref(x, inner, g, b))).all()

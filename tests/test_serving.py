@@ -139,6 +139,9 @@ def test_deadline_shedding_before_dispatch():
             with pytest.raises(serving.DeadlineExceededError):
                 f.result(timeout=10)
         stats = batcher.stats()
+        # the storm over, every request of a clean wave is served
+        wave = [batcher.submit(x) for _ in range(3)]
+        assert all(f.result(timeout=10).shape == (4,) for f in wave)
     assert stats["counters"]["shed_deadline"] == 4
     # shed requests never reached the engine: only the live one dispatched
     assert stats["counters"]["batched_requests"] == 1

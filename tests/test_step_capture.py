@@ -356,7 +356,10 @@ def test_autograd_grad_function_captured():
 def test_dropout_captures_with_key_as_external():
     """Dropout threads its PRNG key as a raw positional arg — a committed
     concrete external the capture records; the VJP re-trace replays the
-    same mask, so grads match the eager run bitwise."""
+    same mask, so grads (mask x 2) match the eager run bitwise.  The scalar
+    ``y`` is a 256-term float32 sum that the fused segment and the per-op
+    program fold in different orders (29.879974 against 29.879988 on
+    XLA:CPU), so it is held to that sum's round-off, not to the bit."""
     from mxnet_tpu.ndarray import ops as F
 
     def run(mode):
@@ -376,8 +379,10 @@ def test_dropout_captures_with_key_as_external():
 
     (yc, gc), stats = run("LazyEngine")
     (ye, ge), _ = run("ThreadedEngine")
-    assert onp.array_equal(yc, ye)
     assert onp.array_equal(gc, ge)
+    assert set(onp.unique(gc)) == {0.0, 4.0}    # a mask, scaled 1/(1-p)
+    onp.testing.assert_allclose(
+        yc, ye, rtol=gc.size * onp.finfo(onp.float32).eps, atol=0)
     assert stats["tape_ops_recorded"] > 0   # dropout did capture
 
 

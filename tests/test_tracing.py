@@ -257,10 +257,22 @@ def test_rejected_request_leaves_inflight_registry(traced):
 
 # -- spool mechanics ---------------------------------------------------------
 
-def test_spool_jsonl_append_and_torn_tail_line_skipped(traced):
+def test_spool_jsonl_append_and_torn_tail_line_skipped(traced, monkeypatch):
+    def dropped():
+        return telemetry.snapshot()["counters"]["trace/spool_dropped"]
+
+    d0 = dropped()
     t = telemetry.new_trace()
     t.add_span("client_request", telemetry._wall_us(), 1000.0)
     assert "sampled" in telemetry.maybe_spool(t, 1.0, role="client")
+    assert dropped() == d0          # under the cap nothing is dropped
+    # past the cap a record is dropped and counted, never rotated in
+    monkeypatch.setattr(telemetry, "_SPOOL_CAP",
+                        telemetry._spool_accepted[0])
+    late = telemetry.new_trace()
+    late.add_span("client_request", telemetry._wall_us(), 1000.0)
+    telemetry.maybe_spool(late, 1.0, role="client")
+    assert dropped() == d0 + 1
     path = telemetry.flush_trace_spool()
     assert path and path.endswith(".jsonl")
     with open(path, "a") as f:

@@ -74,7 +74,7 @@ def marker_for(lines, lineno):
 def all_markers(repo_root):
     """Every donation-recovery marker in the repo (for staleness)."""
     out = []
-    for base in ("mxnet_tpu", "tools", "benchmark"):
+    for base in ("mxnet_tpu", "tools"):
         root = os.path.join(repo_root, base)
         if not os.path.isdir(root):
             continue

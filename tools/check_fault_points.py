@@ -30,7 +30,7 @@ stays disciplined; this checker enforces, over every literal
 
 Run directly (exit 1 on violations) or from the fast test in
 ``tests/test_faults.py`` — the same wiring as ``check_sync_free.py`` /
-``check_bench_writers.py``.
+``check_metric_names.py``.
 """
 from __future__ import annotations
 

@@ -15,8 +15,7 @@ Structured markers fence each shared body:
     ...shared code...
     # <<< KEEP-IN-SYNC(<name>)
 
-Rules enforced over every ``*.py`` under ``mxnet_tpu/``, ``tools/`` and
-``benchmark/``:
+Rules enforced over every ``*.py`` under ``mxnet_tpu/`` and ``tools/``:
 
 * every opened block is closed (same name, same file, no nesting);
 * every block name appears in **at least two files** (a block with one
@@ -38,7 +37,7 @@ import sys
 
 _OPEN_RE = re.compile(r"^\s*#\s*>>>\s*KEEP-IN-SYNC\(([^)]+)\)")
 _CLOSE_RE = re.compile(r"^\s*#\s*<<<\s*KEEP-IN-SYNC\(([^)]+)\)")
-_SCAN_DIRS = ("mxnet_tpu", "tools", "benchmark")
+_SCAN_DIRS = ("mxnet_tpu", "tools")
 
 
 def find_blocks(repo_root):

@@ -6,7 +6,7 @@ Answers "where do the FLOPs go, which block owns them, and is this
 program compute- or byte-bound" from the ``costs`` section
 ``mxnet_tpu.costs`` attaches to crash reports (schema v4,
 docs/RESILIENCE.md) — or from a full ``costs.report_payload()`` dump
-(what ``dispatch_profile --engine fused-step --trace`` writes).
+written out as JSON.
 Deliberately stdlib-only, like trace_report/memory_report: forensics on
 a dead job's report must not need a working jax install.
 

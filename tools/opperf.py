@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Per-operator micro-benchmark harness (reference: ``benchmark/opperf/`` —
-`run_benchmark_operators`, SURVEY.md §6).
+"""Per-operator micro-benchmark harness (reference: upstream's ``opperf``
+package — `run_benchmark_operators`, SURVEY.md §6).
 
 Measures each registered op up to four ways (``--modes``):
 
@@ -16,13 +16,13 @@ Measures each registered op up to four ways (``--modes``):
 * ``fused``  — marginal cost inside one compiled loop (``lax.scan``), i.e.
   the op's steady-state device cost inside a hybridized program
 
-``--record`` appends one summary record to ``benchmark/BENCH_DETAILS.json``
-through the atomic ``util.write_json_records`` writer.
+It compares dispatch paths op by op on whatever backend JAX finds; it is
+no record of the system's speed (that is ``chipbench/``, PERF.md).
 
 Usage:
-    python benchmark/opperf.py                     # default op set, all modes
-    python benchmark/opperf.py --ops dot,relu --modes eager,lazy --record
-    python benchmark/opperf.py --cpu               # force CPU
+    python tools/opperf.py                         # default op set, all modes
+    python tools/opperf.py --ops dot,relu --modes eager,lazy --json r.json
+    python tools/opperf.py --cpu                   # force CPU
 """
 import argparse
 import json
@@ -34,9 +34,6 @@ import numpy as onp
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
-
-_DETAILS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "BENCH_DETAILS.json")
 
 
 def default_configs():
@@ -191,16 +188,13 @@ def main():
     ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--no-fused", action="store_true",
                     help="skip the compiled-loop marginal measurement")
-    ap.add_argument("--record", action="store_true",
-                    help="append a summary record to BENCH_DETAILS.json "
-                         "(atomic util.write_json_records)")
     args = ap.parse_args()
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
-    from mxnet_tpu import nd, util
+    from mxnet_tpu import nd
 
     modes = [m.strip() for m in args.modes.split(",") if m.strip()]
     bad = [m for m in modes if m not in _ALL_MODES]
@@ -234,24 +228,6 @@ def main():
         with open(args.json, "w") as f:
             json.dump(results, f, indent=2)
         print(f"wrote {args.json}")
-    if args.record and results:
-        speedups = [r["eager_ms"] / r["lazy_ms"] for r in results
-                    if r.get("lazy_ms") and r.get("eager_ms")]
-        med = sorted(speedups)[len(speedups) // 2] if speedups else None
-        util.write_json_records(_DETAILS_PATH, [{
-            "metric": "opperf_lazy_dispatch_speedup",
-            "value": None if med is None else round(med, 2),
-            "unit": "x_vs_eager_unjitted_median",
-            "vs_baseline": None if med is None else round(med, 2),
-            "extra": {"platform": jax.devices()[0].platform,
-                      "modes": modes, "ops": results,
-                      "basis": "vs_eager_mode_same_host"},
-            "basis_note": "per-op dispatch wall time, eager un-jitted "
-                          "baseline vs lazy-bulked fused dispatch, "
-                          "same host/process",
-            "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-        }])
-        print(f"recorded opperf summary -> {_DETAILS_PATH}")
 
 
 if __name__ == "__main__":

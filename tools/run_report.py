@@ -296,8 +296,7 @@ def main(argv=None):
         if args.baseline:
             # one compact machine-parseable line: the full payload plus
             # the verdict fields hoisted to the top level, so a harness
-            # (benchmark/health_bench.py --autopilot-proof, CI gates)
-            # can json.loads a single stdout line and branch on
+            # (a CI gate) can json.loads a single stdout line and branch on
             # .verdict without digging into the comparison object
             c = out["comparison"]
             out["verdict"] = c.get("verdict")
