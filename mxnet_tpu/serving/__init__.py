@@ -30,7 +30,8 @@ KV-cached incremental decode with continuous batching — one
 shape-bucketed prefill program plus one fixed-shape decode program over
 the whole in-flight batch, requests joining and leaving at token
 boundaries — served through the same ``ModelServer``/``Router`` stack
-as a streaming ``/generate`` endpoint (docs/SERVING.md "Generative
+as a streaming ``/generate`` endpoint whose token lines one thread a
+server writes (``stream_writer.py``; docs/SERVING.md "Generative
 serving").
 
 See ``docs/SERVING.md`` for architecture and knobs; tokens/s and the gap

@@ -36,6 +36,12 @@ does not wait for it: the first token is read with whatever else is
 unread.  Greedy tokens do not depend on it; a request that ends on
 ``eos_id`` is found one step late and that step's token is thrown away.
 
+A request may name a ``sink`` (:meth:`GenerationEngine.submit`): its tokens
+then leave the loop a step at a time, one ``sink.take(batch)`` for every
+such stream of the step, and not through the stream's queue one by one.
+``ModelServer`` submits every streamed HTTP request so, with its one
+writer thread as the sink (``stream_writer.py``).
+
 Ring-buffer semantics: a slot's position ``p`` writes cache index
 ``p % max_len`` and attends over ``min(p+1, max_len)`` entries — past
 ``max_len`` the cache is a sliding window over the last ``max_len``
@@ -107,9 +113,10 @@ class GenerationMetrics:
             # thread): microsecond sums, read as ratios over the counts
             "loop_offcpu_us": 0,    # stage + emit wall less loop-thread CPU
             "queue_wait_us": 0,     # submit -> slot taken, over prefills
-            "emit_to_wire_us": 0,   # _emit -> flush returned, per token
-            "stream_write_us": 0,   # json.dumps + write + flush, per token
+            "emit_to_wire_us": 0,   # _emit -> send returned, per token
+            "stream_write_us": 0,   # formatting + send, per token
             "stream_tokens_written": 0,
+            "stream_writer_wakes": 0,   # batches of them the writer took
         }
         self._gauges = {
             "free_kv_slots": 0,
@@ -274,14 +281,17 @@ _telemetry.register_collector("generate", _gen_telemetry_collect, {
                                "us from submit to a slot taken, summed "
                                "over prefills"),
     "generate/emit_to_wire_us": ("counter",
-                                 "us from a token's emit to its flush "
+                                 "us from a token's emit to its send "
                                  "returning, summed over streamed tokens"),
     "generate/stream_write_us": ("counter",
-                                 "us inside json.dumps + write + flush, "
-                                 "summed over streamed tokens"),
+                                 "us inside formatting a token line and its "
+                                 "send, summed over streamed tokens"),
     "generate/stream_tokens_written": ("counter",
-                                       "token lines written by the HTTP "
-                                       "stream handlers"),
+                                       "token lines the server's stream "
+                                       "writer put on the sockets"),
+    "generate/stream_writer_wakes": ("counter",
+                                     "times the stream writer woke to a "
+                                     "batch of the loop's tokens"),
     "generate/free_kv_slots": ("gauge", "unallocated KV-cache slots"),
     "generate/active_streams": ("gauge", "requests in the decode batch"),
     "generate/queue_depth": ("gauge", "admitted requests awaiting a slot"),
@@ -307,30 +317,53 @@ class GenerationStream:
     iterate (:meth:`tokens`) for streaming, or call :meth:`result` to
     block for the completed dict ``{"tokens", "finish_reason",
     "ttft_ms", "tokens_per_s"}``.  A failed generation raises its error
-    from both paths."""
+    from both paths.
 
-    def __init__(self, trace=None):
+    A stream submitted with a ``sink`` (see :meth:`GenerationEngine.submit`)
+    has no reader in the process: its tokens and its end go to the sink
+    instead, and :meth:`tokens` yields nothing.  ``wire`` is the sink's own,
+    for what it keeps of this stream; the engine never reads it."""
+
+    def __init__(self, trace=None, sink=None):
         self.trace = trace if trace is not None else _telemetry.NULL_TRACE
         self._q: "queue.Queue" = queue.Queue()
         self._done = threading.Event()
         self._result = None
         self._exc = None
+        self._sink = sink
+        # the loop's way into the batch of its step: GenerationEngine._post,
+        # given at admission (a request that fails in the queue has none)
+        self._post = None
+        self.wire = None
 
     # engine-side ----------------------------------------------------------
+    def _put(self, token):
+        """A token on its way out, or None: the stream has ended.  Both
+        carry the stamp of this call: what a token waits between here and
+        the socket is summed by whoever writes it out.  To a sink the end
+        travels behind the stream's last token, in the same batch or a
+        later one, so it cannot overtake it."""
+        stamp = time.perf_counter_ns()
+        if self._sink is None:
+            self._q.put(_EOS_SENTINEL if token is None else (token, stamp))
+        elif self._post is None:
+            # never admitted, so no step's batch holds a token of it
+            self._sink.take([(self, token, stamp)])
+        else:
+            self._post(self, token, stamp)
+
     def _emit(self, token):
-        # the queue carries the emit stamp: what a token waits between here
-        # and the socket is summed by whoever writes it out
-        self._q.put((int(token), time.perf_counter_ns()))
+        self._put(int(token))
 
     def _complete(self, result):
         self._result = result
         self._done.set()
-        self._q.put(_EOS_SENTINEL)
+        self._put(None)
 
     def _fail(self, exc):
         self._exc = exc
         self._done.set()
-        self._q.put(_EOS_SENTINEL)
+        self._put(None)
 
     # client-side ----------------------------------------------------------
     @property
@@ -551,6 +584,8 @@ class GenerationEngine:
         self._by_slot: list = [None] * S            # slot -> _GenRequest
         self._free = list(range(S - 1, -1, -1))     # pop() -> lowest slot
         self._unread: list = []     # dispatched and not read, oldest first
+        # what this step's streams have for their sinks, a list a sink
+        self._outbox: dict = {}
         self._step_id = None        # the loop step open now (telemetry on)
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(max_queue)))
         self._closed = False
@@ -847,10 +882,22 @@ class GenerationEngine:
 
     # -- submission --------------------------------------------------------
     def submit(self, tokens, max_new_tokens=32, eos_id=None, trace=None,
-               probe=False):
+               probe=False, sink=None):
         """Queue one prompt; returns a :class:`GenerationStream`
         immediately.  ``max_new_tokens`` counts every emitted token
         (including the prefill's first and any EOS).
+
+        ``sink``: who puts this stream's tokens somewhere outside the
+        process, a socket say, and wants them a step at a time and not one
+        by one: anything with ``take(batch)``.  The loop calls it once a
+        step with a list of ``(stream, token, emit stamp)`` over every
+        stream of that sink, in the order emitted, the stamp in
+        ``time.perf_counter_ns()``; a stream's end is one more entry with
+        the token None, after which :meth:`GenerationStream.result` does not
+        wait.  ``take`` must neither block nor raise: it runs on the loop
+        thread (and, for a request that :meth:`stop` fails in the queue, on
+        the stopping one).  Without a sink the stream keeps its queue for
+        :meth:`GenerationStream.tokens`.
 
         ``probe``: show what the serving programs computed for this
         request, to hold a deployment against a reference.  Its prefill
@@ -869,7 +916,7 @@ class GenerationEngine:
             raise ServingError("empty prompt")
         self._bucket_for(prompt.size)      # reject oversized prompts NOW
         stream = GenerationStream(
-            trace if trace is not None else _telemetry.new_trace())
+            trace if trace is not None else _telemetry.new_trace(), sink)
         req = _GenRequest(prompt, max(1, int(max_new_tokens)),
                           None if eos_id is None else int(eos_id), stream,
                           probe)
@@ -896,6 +943,7 @@ class GenerationEngine:
         while True:
             if self._aborted:
                 self._fail_riders(EngineClosedError("engine aborted"))
+                self._hand_over()
                 return
             first = None
             if not self._unread and len(self._free) == self._slots \
@@ -916,6 +964,8 @@ class GenerationEngine:
                     self._admit(first)
                 self._admit_ready()
                 self._decode_once()
+                # what a failure left for the sinks, here or at an admission
+                self._hand_over()
 
     def _admit_ready(self):
         while self._free:
@@ -995,7 +1045,23 @@ class GenerationEngine:
                 self._release(r)
                 self._fail(r, exc)
 
+    def _post(self, stream, token, stamp):
+        """A stream's token (or None, its end) into the batch its sink
+        takes when the step has been read: the loop thread's own list."""
+        batch = self._outbox.get(stream._sink)
+        if batch is None:
+            batch = self._outbox[stream._sink] = []
+        batch.append((stream, token, stamp))
+
+    def _hand_over(self):
+        """Every sink takes its batch of this step: one call, one wake."""
+        if self._outbox:
+            batches, self._outbox = self._outbox, {}
+            for sink, batch in batches.items():
+                sink.take(batch)
+
     def _admit(self, req):
+        req.stream._post = self._post
         slot = self._free.pop()
         wait_us = (time.perf_counter() - req.t_submit) * 1e6
         self._metrics.add(slot_allocs=1, prefills=1,
@@ -1170,9 +1236,10 @@ class GenerationEngine:
 
     def _emit_read(self, older, read):
         """Hand what was read to its streams, oldest first: a prefill's
-        first token, a decode step's token a rider.  Returns the counts of
-        first tokens, of decode tokens emitted and of decode tokens thrown
-        away (their request had ended on ``eos_id`` a step before)."""
+        first token, a decode step's token a rider; then the step's batch
+        to each sink.  Returns the counts of first tokens, of decode tokens
+        emitted and of decode tokens thrown away (their request had ended
+        on ``eos_id`` a step before)."""
         import jax
         S = self._slots
         firsts = emitted = discarded = 0
@@ -1197,6 +1264,7 @@ class GenerationEngine:
                 r.stream._emit(t)
                 self._finish_if_done(r, t)
                 emitted += 1
+        self._hand_over()
         return firsts, emitted, discarded
 
     # -- completion --------------------------------------------------------

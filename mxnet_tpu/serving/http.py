@@ -34,6 +34,7 @@ import numpy as onp
 from .batcher import DynamicBatcher
 from .errors import (DeadlineExceededError, EngineClosedError,
                      QueueFullError)
+from .stream_writer import StreamWriter
 
 __all__ = ["ModelServer", "encode_array", "decode_array"]
 
@@ -365,9 +366,13 @@ class _Handler(BaseHTTPRequestHandler):
             return
 
         t0 = time.perf_counter()
+        # a streamed request's tokens go to the server's one writer, a step
+        # at a time; any other's stay on the stream's own queue
+        writer = self.server.stream_writer
         try:
             stream = gen.submit(tokens, max_new_tokens=max_new,
-                                eos_id=eos_id, trace=trace)
+                                eos_id=eos_id, trace=trace,
+                                sink=writer if streaming else None)
         except QueueFullError as e:
             trace.mark("shed")
             self._try_reply(429, {"error": "queue_full", "detail": str(e)})
@@ -425,51 +430,59 @@ class _Handler(BaseHTTPRequestHandler):
             self.close_connection = True
             spool()
             return
-        # what a token waits between the engine's emit and the socket, and
-        # what writing it costs: summed here, added to the engine's
-        # counters once when the stream ends (no shared lock per token)
-        i = wire_ns = write_ns = 0
         try:
-            try:
-                self.send_response(200)
-                self.send_header("Content-Type", "application/x-ndjson")
-                self.send_header("Connection", "close")
-                self.end_headers()
-                for tok, t_emit in stream.stamped_tokens(
-                        timeout=_DEFAULT_RESULT_TIMEOUT_S):
-                    t_write = time.perf_counter_ns()
-                    self.wfile.write(json.dumps(
-                        {"token": int(tok), "index": i}).encode() + b"\n")
-                    self.wfile.flush()
-                    t_flushed = time.perf_counter_ns()
-                    wire_ns += t_flushed - t_emit
-                    write_ns += t_flushed - t_write
-                    i += 1
-                final = final_payload(
-                    stream.result(timeout=_DEFAULT_RESULT_TIMEOUT_S))
-            except (BrokenPipeError, ConnectionResetError):
-                # client hung up mid-stream; the engine finishes on its own
-                self.close_connection = True
-                spool()
-                return
-            except Exception as e:           # noqa: BLE001
-                # generation died AFTER the 200 + some tokens went out: the
-                # only honest wire move on an unframed stream is a typed
-                # error line (the client raises GenerationStreamBroken)
-                final = {"error": "stream_broken", "detail": str(e),
-                         "trace_id": trace.trace_id if trace else None}
-            try:
-                self.wfile.write(json.dumps(final).encode() + b"\n")
-                self.wfile.flush()
-            except (BrokenPipeError, ConnectionResetError):
-                pass
+            self.send_response(200)
+            self.send_header("Content-Type", "application/x-ndjson")
+            self.send_header("Connection", "close")
+            self.end_headers()
+            # the token lines are the writer's; this thread sleeps until
+            # the stream's last one is out (or the socket comes back to it
+            # for another reason) and writes the final line
+            self._await_wire(writer, writer.attach(stream, self.connection))
+            final = final_payload(
+                stream.result(timeout=_DEFAULT_RESULT_TIMEOUT_S))
+        except (BrokenPipeError, ConnectionResetError):
+            # client hung up mid-stream; the engine finishes on its own
             self.close_connection = True
             spool()
-        finally:
-            if i:
-                gen.metrics.add(emit_to_wire_us=wire_ns // 1000,
-                                stream_write_us=write_ns // 1000,
-                                stream_tokens_written=i)
+            return
+        except Exception as e:           # noqa: BLE001
+            # generation died AFTER the 200 + some tokens went out: the
+            # only honest wire move on an unframed stream is a typed
+            # error line (the client raises GenerationStreamBroken)
+            final = {"error": "stream_broken", "detail": str(e),
+                     "trace_id": trace.trace_id if trace else None}
+        try:
+            self.wfile.write(json.dumps(final).encode() + b"\n")
+            self.wfile.flush()
+        except OSError:
+            pass        # hung up, or not reading, at the very end
+        self.close_connection = True
+        spool()
+
+    def _await_wire(self, writer, wire):
+        """Sleep until the writer has put out the last token line of
+        ``wire``'s stream and let go of its socket: one wake a request.
+        Raises what else ended the wait: ``BrokenPipeError`` (the client
+        hung up), ``TimeoutError`` (the engine has had nothing for the
+        stream for ``_DEFAULT_RESULT_TIMEOUT_S``; the socket is taken
+        back) or ``EngineClosedError`` (the writer stopped or died).  The
+        socket is this thread's again, and blocking, in every case but a
+        writer that does not give it back, which reads as a client gone:
+        nobody may write on it then."""
+        wait_s = _DEFAULT_RESULT_TIMEOUT_S
+        while not wire.released.wait(wait_s):
+            idle_s = (time.perf_counter_ns() - wire.last_ns) / 1e9
+            wait_s = _DEFAULT_RESULT_TIMEOUT_S - idle_s
+            if wait_s <= 0 and not writer.detach(wire):
+                raise BrokenPipeError("the stream writer holds the socket")
+        self.connection.settimeout(self.timeout)
+        if wire.outcome == "gone":
+            raise BrokenPipeError("client hung up mid-stream")
+        if wire.outcome == "detached":
+            raise TimeoutError("no token within timeout")
+        if wire.outcome == "closed":
+            raise EngineClosedError("the server's stream writer stopped")
 
 
 class _FleetHTTPServer(ThreadingHTTPServer):
@@ -560,6 +573,10 @@ class ModelServer:
         self._httpd.block_on_close = False
         self._httpd.batcher = batcher
         self._httpd.generator = generator
+        # one thread writes the token lines of every streamed /generate
+        self._writer = StreamWriter(generator.metrics) \
+            if generator is not None else None
+        self._httpd.stream_writer = self._writer
         self._httpd.inflight = 0
         self._httpd.inflight_cv = threading.Condition()
         self._thread = None
@@ -584,6 +601,8 @@ class ModelServer:
             raise EngineClosedError(
                 "ModelServer stopped; construct a new one to serve again")
         self.batcher.start()
+        if self._writer is not None:
+            self._writer.start()
         if self._thread is None:
             self._thread = threading.Thread(
                 target=self._httpd.serve_forever,
@@ -622,6 +641,9 @@ class ModelServer:
                 self._httpd.inflight_cv.wait(remaining)
         if self.generator is not None:
             self.generator.stop()
+            # behind the engine: what it emitted while draining is written
+            # out before the writer lets go of the sockets and is joined
+            self._writer.close()
         self.batcher.stop()
         # in-flight work is done (or failed by batcher.stop above) —
         # what's left are idle keep-alive peers; sever them so no

@@ -32,7 +32,7 @@ def test_run_py_rehearses_the_cell(cell, trace):
                 "experts_touched.dsv32", "expert_load_max.dsv32",
                 "batch_occupancy.dsv32", "compile_s.dsv32",
                 "loop_offcpu_us.dsv32", "emit_to_wire_us.dsv32",
-                "wire_write_us.dsv32"} <= names
+                "wire_write_us.dsv32", "writer_batch_tokens.dsv32"} <= names
     elif cell.startswith("deepseek"):
         assert names == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
     else:

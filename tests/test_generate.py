@@ -16,6 +16,10 @@ Covers the ``mxnet_tpu.serving.generate`` subsystem end to end (all CPU):
   endpoint (streaming + non-streaming), and the router's
   prefill-only-re-route / typed-mid-stream-break policy.
 """
+import json
+import socket
+import struct
+import threading
 import time
 import urllib.request
 
@@ -756,6 +760,287 @@ def test_router_generate_rejects_bad_midstream_policy(lm):
         with serving.Router([srv.url]) as router:
             with pytest.raises(ValueError, match="midstream"):
                 router.generate([1, 2], midstream="retry")
+
+
+# -- one writer for every token stream (serving/stream_writer.py) -----------
+
+def _open_stream(srv, prompt, max_new):
+    """A streaming /generate sent on a raw socket: the reply is read (or
+    not) by the test, byte for byte."""
+    sock = socket.create_connection((srv.host, srv.port), timeout=120)
+    body = json.dumps({"tokens": prompt, "max_new_tokens": max_new,
+                       "stream": True}).encode()
+    sock.sendall(b"POST /generate HTTP/1.1\r\nHost: test\r\n"
+                 b"Content-Type: application/json\r\n"
+                 b"Content-Length: %d\r\n\r\n" % len(body) + body)
+    return sock
+
+
+def _read_to_close(sock):
+    """The body of a close-delimited reply, split into its lines."""
+    data = b""
+    while True:
+        chunk = sock.recv(65536)
+        if not chunk:
+            break
+        data += chunk
+    sock.close()
+    head, _, body = data.partition(b"\r\n\r\n")
+    assert head.startswith(b"HTTP/1.1 200"), head
+    assert b"Connection: close" in head
+    assert body.endswith(b"\n")
+    return body[:-1].split(b"\n")
+
+
+def _token_lines(tokens):
+    """What the per-token handler loop wrote for these tokens."""
+    return [json.dumps({"token": int(t), "index": i}).encode()
+            for i, t in enumerate(tokens)]
+
+
+def _in_threads(fn, args_list, timeout=180):
+    """``fn(*args)`` for every args at once, a thread each; the results
+    (or the exception raised) in order."""
+    out = [None] * len(args_list)
+
+    def run(k, args):
+        try:
+            out[k] = fn(*args)
+        except Exception as e:          # noqa: BLE001 — the test reads it
+            out[k] = e
+    threads = [threading.Thread(target=run, args=(k, a))
+               for k, a in enumerate(args_list)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout)
+    assert not any(t.is_alive() for t in threads)
+    return out
+
+
+def _drain_stream(it):
+    """A client's streamed tokens and what ended them: the final record,
+    or the exception."""
+    seen = []
+    try:
+        while True:
+            seen.append(next(it))
+    except StopIteration as stop:
+        return seen, stop.value
+    except Exception as e:              # noqa: BLE001 — the test reads it
+        return seen, e
+
+
+def test_sixteen_streams_read_the_bytes_the_handler_loop_wrote(lm):
+    # four slots, sixteen clients: streams join and leave the batch while
+    # one thread writes them all
+    cases = [([1 + k, 7, 3 + (k % 5)][:1 + k % 3], 3 + (5 * k) % 11)
+             for k in range(16)]
+    with _serving_stack(lm) as srv:
+        client = serving.ServingClient(srv.url)
+        plain = [client.generate(p, max_new_tokens=n)["tokens"]
+                 for p, n in cases]
+        got = _in_threads(
+            lambda p, n: _read_to_close(_open_stream(srv, p, n)), cases)
+    for (prompt, n), ref, lines in zip(cases, plain, got):
+        assert not isinstance(lines, Exception), lines
+        assert len(ref) == n
+        # indices 0..n-1 in order, then the final line, and nothing else
+        assert lines[:-1] == _token_lines(ref)
+        final = json.loads(lines[-1])
+        assert final["done"] is True and final["tokens"] == ref
+        assert final["finish_reason"] == "length"
+
+
+def test_a_client_that_reads_nothing_delays_nobody(lm):
+    ref = _full_forward_greedy(lm, [5, 9], 40)
+    with _serving_stack(lm) as srv:
+        stalled = _open_stream(srv, [5, 9], 40)      # sent, never read
+        try:
+            client = serving.ServingClient(srv.url, timeout_s=60)
+            others = _in_threads(
+                lambda k: _drain_stream(client.generate_stream(
+                    [2 + k, 4], max_new_tokens=20)),
+                [(k,) for k in range(8)])
+            for seen, final in others:
+                assert len(seen) == 20 and final["tokens"] == seen
+            # its generation went on without it: every line is there
+            lines = _read_to_close(stalled)
+        finally:
+            stalled.close()
+    assert lines[:-1] == _token_lines(ref)
+    assert json.loads(lines[-1])["tokens"] == ref
+
+
+def test_a_socket_that_takes_no_bytes_keeps_its_remainder_in_order():
+    # more than a socket's buffers hold: the writer keeps what is left for
+    # that stream and goes on with the other one
+    from mxnet_tpu.serving.generate import GenerationMetrics, \
+        GenerationStream
+    from mxnet_tpu.serving.stream_writer import StreamWriter
+    metrics = GenerationMetrics()
+    writer = StreamWriter(metrics).start()
+    slow_srv, slow_cli = socket.socketpair()
+    fast_srv, fast_cli = socket.socketpair()
+    # a socket pair holds a few hundred small sends, whatever their bytes
+    n, per = 6000, 300
+    try:
+        slow, fast = GenerationStream(sink=writer), \
+            GenerationStream(sink=writer)
+        w_slow = writer.attach(slow, slow_srv)
+        w_fast = writer.attach(fast, fast_srv)
+        now = time.perf_counter_ns()
+        for k in range(0, n, per):
+            writer.take([(slow, t, now) for t in range(k, k + per)]
+                        + [(fast, k // per, now)])
+        writer.take([(slow, None, now), (fast, None, now)])
+        assert w_fast.released.wait(60) and w_fast.outcome == "done"
+        assert not w_slow.released.is_set()     # most of it is still owed
+        fast_cli.settimeout(60)
+        want = b"".join(ln + b"\n" for ln in _token_lines(range(n // per)))
+        got = b""
+        while len(got) < len(want):
+            got += fast_cli.recv(65536)
+        assert got == want
+        slow_cli.settimeout(60)
+        want = b"".join(ln + b"\n" for ln in _token_lines(range(n)))
+        got = b""
+        while len(got) < len(want):
+            got += slow_cli.recv(1 << 20)
+        assert got == want
+        assert w_slow.released.wait(60) and w_slow.outcome == "done"
+        c = metrics.stats()["counters"]
+        assert c["stream_tokens_written"] == n + n // per
+        assert c["stream_writer_wakes"] <= n // per + 1
+    finally:
+        writer.close()
+        for s in (slow_srv, slow_cli, fast_srv, fast_cli):
+            s.close()
+    assert not any(t.name == "mxnet-tpu-stream-writer"
+                   for t in threading.enumerate())
+
+
+def test_a_failure_mid_stream_ends_every_attached_stream_typed(lm):
+    cases = [([3 + k, 1, 4], 12) for k in range(3)]
+    refs = [_full_forward_greedy(lm, p, n) for p, n in cases]
+    with _serving_stack(lm) as srv:
+        client = serving.ServingClient(srv.url, timeout_s=60)
+        with faults.inject("generate.decode@4:permanent"):
+            got = _in_threads(
+                lambda p, n: _drain_stream(
+                    client.generate_stream(p, max_new_tokens=n)), cases)
+        broken = 0
+        for (seen, end), ref in zip(got, refs):
+            if isinstance(end, serving.GenerationStreamBroken):
+                # the typed line came after the tokens already sent
+                broken += 1
+                assert end.tokens == seen and 0 < len(seen) < 12
+            else:       # admitted after the failed step
+                assert end["tokens"] == seen and len(seen) == 12
+            assert seen == ref[:len(seen)]
+        assert broken >= 1
+        assert client.generate([3, 1, 4], max_new_tokens=3)["tokens"] \
+            == refs[0][:3]
+
+
+def test_a_client_that_hangs_up_costs_one_stream(lm):
+    with _serving_stack(lm) as srv:
+        gen = srv.generator
+        sock = _open_stream(srv, [6, 2], 50)
+        data = b""
+        while b'"index": 0}' not in data:
+            data += sock.recv(4096)
+        # a reset, not a polite close: the next send to it fails
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                        struct.pack("ii", 1, 0))
+        sock.close()
+        client = serving.ServingClient(srv.url, timeout_s=60)
+        seen, final = _drain_stream(
+            client.generate_stream([6, 2], max_new_tokens=8))
+        assert final["tokens"] == seen == _full_forward_greedy(
+            lm, [6, 2], 8)
+        # the engine finished the abandoned generation on its own
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline and \
+                gen.metrics.stats()["counters"]["completed"] < 2:
+            time.sleep(0.01)
+        c = gen.metrics.stats()["counters"]
+        assert c["completed"] == 2 and c["errors"] == 0
+        assert 8 < c["stream_tokens_written"] < 58
+
+
+def test_the_writer_counts_what_the_clients_read(lm):
+    cases = [([4 + k, 8], 6 + k) for k in range(4)]
+    with _serving_stack(lm) as srv:
+        before = srv.generator.metrics.stats()["counters"]
+        client = serving.ServingClient(srv.url, timeout_s=60)
+        got = _in_threads(
+            lambda p, n: _drain_stream(
+                client.generate_stream(p, max_new_tokens=n)), cases)
+        # a final line is written only once the counters hold its stream
+        after = srv.generator.metrics.stats()["counters"]
+    d = {k: after[k] - before[k] for k in after}
+    assert all(isinstance(end, dict) for _seen, end in got)
+    assert d["stream_tokens_written"] == sum(len(seen) for seen, _ in got) \
+        == sum(n for _p, n in cases)
+    # at most one wake a program read: a batch a step, never one a token
+    assert 0 < d["stream_writer_wakes"] <= d["prefills"] + d["decode_steps"]
+    assert d["emit_to_wire_us"] >= d["stream_write_us"] > 0
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_stop_joins_the_writer_and_a_dead_writer_fails_typed(lm):
+    def writers():
+        return [t for t in threading.enumerate()
+                if t.name == "mxnet-tpu-stream-writer"]
+
+    srv = _serving_stack(lm)
+    assert not writers()            # none before start()
+    srv.start()
+    try:
+        assert len(writers()) == 1
+        client = serving.ServingClient(srv.url, timeout_s=60)
+        seen, final = _drain_stream(
+            client.generate_stream([1, 2], max_new_tokens=4))
+        assert final["tokens"] == seen
+    finally:
+        srv.stop()
+    assert not writers()            # joined, not left to die with the process
+
+    # a writer that dies takes no stream down in silence
+    srv = _serving_stack(lm).start()
+    try:
+        client = serving.ServingClient(srv.url, timeout_s=60)
+        writer = srv._writer
+        calls = []
+
+        def dies_at_the_third(stream, token, t_emit, _item=writer._item):
+            calls.append(token)
+            if len(calls) == 3:
+                raise RuntimeError("injected: the writer dies")
+            _item(stream, token, t_emit)
+        writer._item = dies_at_the_third
+        t0 = time.monotonic()
+        seen, end = _drain_stream(
+            client.generate_stream([1, 2], max_new_tokens=30))
+        assert isinstance(end, serving.GenerationStreamBroken)
+        assert "stream writer" in str(end) and len(seen) == 2
+        deadline = time.monotonic() + 30
+        while writers() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not writers()
+        # a later stream is refused typed, at once; the rest serves on
+        seen, end = _drain_stream(
+            client.generate_stream([1, 2], max_new_tokens=4))
+        assert isinstance(end, serving.GenerationStreamBroken) and not seen
+        assert time.monotonic() - t0 < 30
+        assert len(client.generate([1, 2], max_new_tokens=4)["tokens"]) == 4
+    finally:
+        srv.stop()
+    # a server that never started has no thread to leave behind
+    _serving_stack(lm).stop()
+    assert not writers()
 
 
 # -- fleet chaos: mid-generation replica death ------------------------------

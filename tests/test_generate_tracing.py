@@ -348,7 +348,8 @@ def test_a_streamed_request_moves_the_wire_and_queue_counters(lm):
                     final = stop.value
                     break
             assert len(toks) == 6 and final["tokens"] == toks
-            # the handler adds its sums once, when the stream has ended
+            # the writer adds its sums once a wake, before the handler
+            # wakes to write the final line
             import time
             deadline = time.monotonic() + 10
             while time.monotonic() < deadline and gen.metrics.stats()[
@@ -504,6 +505,12 @@ def test_the_phases_add_up_on_the_hand_trace():
     ("overlap_share.decode", {"decode_steps": 10}, None),   # the parent's
     ("overlap_share.dsv32", {"decode_steps_overlapped": 10,
                              "decode_steps": 10}, 1.0),
+    ("writer_batch_tokens.decode", {"stream_tokens_written": 1270,
+                                    "stream_writer_wakes": 10}, 127.0),
+    # the parent counts the token lines and has no writer to count wakes
+    ("writer_batch_tokens.decode", {"stream_tokens_written": 1270}, None),
+    ("writer_batch_tokens.dsv32", {"stream_tokens_written": 640,
+                                   "stream_writer_wakes": 10}, 64.0),
 ])
 def test_counter_metrics_read_their_counters(metric, counters, want):
     from chipbench import common
@@ -534,17 +541,24 @@ def test_new_metrics_are_entries_of_the_benchmark_except_queue_wait():
                                        "queue_wait_us.json"))
 
 
-def test_overlap_share_is_an_entry_a_serving_cell():
+@pytest.mark.parametrize("stem, layer, unit", [
+    ("overlap_share", "host loop, serving", "share"),           # PR 29
+    ("writer_batch_tokens", "entry point, wire", "tokens"),     # PR 32
+])
+def test_a_pair_of_counter_metrics_is_an_entry_a_serving_cell(stem, layer,
+                                                              unit):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # appended by PR 29, after everything that was there
-    tail = bench["per_layer"][-2:]
-    assert [(m["name"], m["workloads"]) for m in tail] == [
-        ("overlap_share.decode", ["gpt1.decode_full"]),
-        ("overlap_share.dsv32", ["deepseek_v32.decode_long"])]
-    for m in tail:
+    names = [m["name"] for m in bench["per_layer"]]
+    # appended as a pair, after everything that was there
+    at = names.index(stem + ".decode")
+    pair = bench["per_layer"][at:at + 2]
+    assert [(m["name"], m["workloads"]) for m in pair] == [
+        (stem + ".decode", ["gpt1.decode_full"]),
+        (stem + ".dsv32", ["deepseek_v32.decode_long"])]
+    for m in pair:
         assert (m["layer"], m["moves"], m["unit"], m["better"]) == (
-            "host loop, serving", "serve_tokens_per_s", "share", "higher")
+            layer, "serve_tokens_per_s", unit, "higher")
 
 
 def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
@@ -565,6 +579,8 @@ def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
         assert readings[name]["value"] >= 0
     # the clients keep every slot taken: the loop never drains in the window
     assert readings["overlap_share.decode"]["value"] > 0.9
+    # one wake takes a step's tokens or, behind on a CPU's steps, several
+    assert readings["writer_batch_tokens.decode"]["value"] >= 1
     assert readings["wire_write_us"]["value"] <= \
         readings["emit_to_wire_us"]["value"]
     # the span metrics need a device plane: a CPU prints none of them, and
