@@ -19,3 +19,4 @@ from .yolo import (  # noqa: F401
     yolo3_darknet53_voc, yolo3_darknet53_coco, yolo3_tiny,
 )
 from .deepseek import DeepSeekV32LM, tiny_v32  # noqa: F401
+from .lfm2 import LFM2MoeLM, tiny_lfm2  # noqa: F401

@@ -30,7 +30,6 @@ orthogonal and changes no product; FP8 is a storage choice).
 """
 from __future__ import annotations
 
-import functools
 import math
 import types
 
@@ -40,10 +39,11 @@ from ..gluon.block import HybridBlock
 from ..gluon import nn
 from ..gluon.parameter import Parameter
 from .. import initializer as init
-from .. import random as _random
 from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
+from .parts import (FanInNormal as _FanInNormal, matmul as _mm,
+                    rms_norm as _rms, rope as _rope, sub_weights as _sub)
 
 __all__ = ["DeepSeekV32LM", "V32_PUBLISHED", "tiny_v32", "run_full", "decode",
            "yarn_inv_freq", "softmax_scale", "STEP_COUNTERS"]
@@ -131,13 +131,6 @@ def _jnp():
     return jnp
 
 
-def _rms(x, g, eps):
-    jnp = _jnp()
-    x32 = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 / jnp.sqrt(ms + eps) * g.astype(jnp.float32)).astype(x.dtype)
-
-
 def _layernorm(x, g, b, eps):
     jnp = _jnp()
     x32 = x.astype(jnp.float32)
@@ -145,30 +138,6 @@ def _layernorm(x, g, b, eps):
     var = jnp.square(x32 - mu).mean(-1, keepdims=True)
     return ((x32 - mu) / jnp.sqrt(var + eps) * g.astype(jnp.float32)
             + b.astype(jnp.float32)).astype(x.dtype)
-
-
-def _mm(x, w):
-    """x @ w, accumulated in float32, in x's type."""
-    jnp = _jnp()
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def _rope(x, cos, sin, interleaved):
-    """Rotate the last axis of ``x`` by the angles behind ``cos`` / ``sin``
-    ([..., dim / 2], broadcast against x): pairs are (2i, 2i + 1) if
-    ``interleaved`` else (i, i + dim / 2)."""
-    jnp = _jnp()
-    x32 = x.astype(jnp.float32)
-    if interleaved:
-        a, b = x32[..., 0::2], x32[..., 1::2]
-    else:
-        a, b = jnp.split(x32, 2, axis=-1)
-    ra, rb = a * cos - b * sin, a * sin + b * cos
-    if interleaved:
-        out = jnp.stack([ra, rb], axis=-1).reshape(x.shape)
-    else:
-        out = jnp.concatenate([ra, rb], axis=-1)
-    return out.astype(x.dtype)
 
 
 def _ring_row(c, latent):
@@ -182,10 +151,6 @@ def _ring_row(c, latent):
     pad = c.latent_stride - latent.shape[-1]
     return latent if pad == 0 else jnp.pad(
         latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)])
-
-
-def _sub(w, prefix):
-    return {k[len(prefix):]: v for k, v in w.items() if k.startswith(prefix)}
 
 
 def _attn_inputs(c, w, x, pos):
@@ -430,30 +395,6 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
 # ---------------------------------------------------------------------------
 # the model
 # ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=None)
-def _normal_maker(shape, dtype, sigma):
-    import jax
-    import jax.numpy as jnp
-    return jax.jit(lambda key: (jax.random.normal(key, shape, jnp.float32)
-                                * sigma).astype(dtype))
-
-
-class _FanInNormal(init.Initializer):
-    """Normal of standard deviation ``sigma``, or ``fan_in ** -0.5`` of a
-    matrix stored [..., in, out], so that every product keeps its input's
-    scale.  Made in one jitted program a shape: no float32 copy of a
-    bfloat16 stack of experts."""
-
-    def __init__(self, sigma=None):
-        super().__init__(sigma=sigma)
-        self.sigma = sigma
-
-    def _init_weight(self, name, shape, dtype):
-        sigma = self.sigma or shape[-2] ** -0.5
-        return _normal_maker(tuple(shape), str(onp.dtype(dtype)),
-                             float(sigma))(_random.next_key())
-
-
 class _V32Block(HybridBlock):
     def __init__(self, c, index, dtype, grad_req):
         super().__init__()

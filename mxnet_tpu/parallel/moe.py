@@ -218,13 +218,15 @@ class MoE(HybridBlock):
 # ---------------------------------------------------------------------------
 # dropless routing over the experts held here (DeepSeek-V3 style)
 # ---------------------------------------------------------------------------
-def noaux_route(scores, bias, k, n_group=1, topk_group=1, route_scale=1.0):
+def noaux_route(scores, bias, k, n_group=1, topk_group=1, route_scale=1.0,
+                norm_eps=0.0):
     """Group-limited top-k of ``scores`` [T, E] (sigmoid, float32) by
     ``scores + bias``: a group's score is the sum of its two largest
     biased scores, the best ``topk_group`` of ``n_group`` groups stay, and
     the ``k`` largest biased scores among their experts are chosen.  Gates
     are the *unbiased* scores of the chosen, normalised to sum to
-    ``route_scale``.  Returns ``(idx [T, k] int32, gates [T, k])``."""
+    ``route_scale`` (over ``sum + norm_eps``, where a publisher guards the
+    division so).  Returns ``(idx [T, k] int32, gates [T, k])``."""
     import jax
     import jax.numpy as jnp
 
@@ -239,7 +241,10 @@ def noaux_route(scores, bias, k, n_group=1, topk_group=1, route_scale=1.0):
         biased = jnp.where(kept[:, :, None], g, -jnp.inf).reshape(T, E)
     _, idx = jax.lax.top_k(biased, k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    gates = route_scale * chosen / chosen.sum(-1, keepdims=True)
+    total = chosen.sum(-1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    gates = route_scale * chosen / total
     return idx.astype(jnp.int32), gates
 
 
@@ -302,7 +307,7 @@ def held_load(idx, first, count, weight=None):
 
 
 def dropless_moe(x2d, w, k, first, n_group=1, topk_group=1, route_scale=1.0,
-                 with_shared=True):
+                 with_shared=True, norm_eps=0.0):
     """One expert layer on raw tokens [T, d] from its raw weights ``w``
     (``gate_weight`` [d, E], ``select_bias`` [E], ``held_w1/w3/w2``,
     optionally ``shared_w1/w3/w2``).  The router's product and its sigmoid
@@ -313,7 +318,7 @@ def dropless_moe(x2d, w, k, first, n_group=1, topk_group=1, route_scale=1.0,
     scores = jax.nn.sigmoid(jnp.dot(x2d.astype(f32),
                                     w["gate_weight"].astype(f32)))
     idx, gates = noaux_route(scores, w["select_bias"].astype(f32), k,
-                             n_group, topk_group, route_scale)
+                             n_group, topk_group, route_scale, norm_eps)
     y = dropless_experts(x2d, idx, gates, w["held_w1"], w["held_w3"],
                          w["held_w2"], first)
     if with_shared and "shared_w1" in w:
@@ -337,8 +342,8 @@ class DroplessMoE(HybridBlock):
     def __init__(self, units, hidden_size, num_experts, k, held=None,
                  n_group=1, topk_group=1, route_scale=1.0, shared_experts=1,
                  dtype="float32", weight_initializer=None,
-                 bias_initializer=None, grad_req="write", prefix=None,
-                 params=None):
+                 bias_initializer=None, grad_req="write", norm_eps=0.0,
+                 prefix=None, params=None):
         super().__init__(prefix, params)
         first, count = held if held is not None else (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
@@ -346,7 +351,7 @@ class DroplessMoE(HybridBlock):
         self._k = k
         self._first, self._count = int(first), int(count)
         self._n_group, self._topk_group = n_group, topk_group
-        self._route_scale = route_scale
+        self._route_scale, self._norm_eps = route_scale, norm_eps
         winit = weight_initializer or init.Xavier()
         sh = shared_experts * hidden_size
 
@@ -376,7 +381,8 @@ class DroplessMoE(HybridBlock):
         y, idx, _gates, scores = dropless_moe(
             x.reshape(-1, x.shape[-1]), w, k=self._k, first=self._first,
             n_group=self._n_group, topk_group=self._topk_group,
-            route_scale=self._route_scale, with_shared=with_shared)
+            route_scale=self._route_scale, with_shared=with_shared,
+            norm_eps=self._norm_eps)
         return y.astype(x.dtype).reshape(x.shape), idx, scores
 
     def forward(self, x):

@@ -49,6 +49,16 @@ tokens (softmax is order-invariant, so ring order never matters).
 Prefill pads its K/V scatter to the bucket length; the padded rows are
 provably dead — decode overwrites index ``j`` at position ``j`` before
 the attention mask ever reaches it.
+
+What a layer keeps need not be such a ring.  ``cache_spec(max_len)`` names,
+for each layer, any number of ``(kind, trailing shape, dtype)``; the engine
+allocates each as ``(slots,) + shape``, donates them all and writes what
+``prefill`` returns at ``(slot, 0, ...)``.  A **state** — a shape with no
+position axis, a short convolution's last rows say — is written whole by
+every step, so nothing of it is dead: ``prefill`` owes it *as of the valid
+length*, not of the padded bucket's end, and ``decode_step`` leaves the
+state and the rings of a slot with ``active == 0`` as they were
+(docs/SERVING.md, ``models/lfm2.py``).
 """
 from __future__ import annotations
 
@@ -558,9 +568,13 @@ class GenerationEngine:
         kv_bytes = sum(by_kind.values())
         budget = int(getenv("MXNET_KV_BUDGET_BYTES"))
         if budget > 0 and kv_bytes > budget:
+            # layers need not be alike: every kind with its count and bytes
+            kinds = [kind for kind, _shape, _dtype in self._ring_specs]
+            held = ", ".join(f"{kinds.count(kind)} x {kind} = {n} bytes"
+                             for kind, n in by_kind.items())
             raise ServingError(
                 f"KV cache needs {kv_bytes} bytes ({S} slots x {len(spec)} "
-                f"layers of {spec[0]}) > MXNET_KV_BUDGET_BYTES={budget} — "
+                f"layers: {held}) > MXNET_KV_BUDGET_BYTES={budget} — "
                 "shrink MXNET_KV_SLOTS / MXNET_KV_MAX_LEN or raise the "
                 "budget")
         self._cache_flat, self._last_tok = self._zero_rings(), \
@@ -714,8 +728,11 @@ class GenerationEngine:
                 last, first[None], (slot,))]
             rows = [unwrap(r) for layer in res[1] for r in layer]
             for ring, new in zip(cache_flat, rows):
-                # padded rows beyond vl are dead: decode overwrites index
-                # j at position j before the mask reaches it
+                # what the model keeps, written whole at the slot.  Of a
+                # ring indexed by position the padded rows beyond vl are
+                # dead: decode overwrites index j at position j before
+                # the mask reaches it.  A state has no dead part: the
+                # model owes it as of vl, not of the bucket's end
                 out.append(jax.lax.dynamic_update_slice(
                     ring, new.astype(ring.dtype),
                     (slot,) + (0,) * (ring.ndim - 1)))

@@ -1,4 +1,4 @@
-"""The benchmark cells PR 28 adds, rehearsed end to end through
+"""The benchmark cells PR 28 and PR 33 add, rehearsed end to end through
 ``chipbench/run.py --rehearse`` on the CPU (tiny sizes, every value null);
 ``bert_base.pretrain_dp4``'s files wait in the tree for a ``benchmark`` PR
 (PERF.md section 7.1) and are rehearsed with it."""
@@ -14,7 +14,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.parametrize("cell,trace", [("deepseek_v32.decode_long", 1),
                                         ("deepseek_v32.decode_long", 0),
-                                        ("bert_base.pretrain_dp4", 1)])
+                                        ("bert_base.pretrain_dp4", 1),
+                                        ("lfm2_24b.decode_rollout", 1),
+                                        ("lfm2_24b.decode_rollout", 0)])
 def test_run_py_rehearses_the_cell(cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
@@ -33,7 +35,13 @@ def test_run_py_rehearses_the_cell(cell, trace):
                 "batch_occupancy.dsv32", "compile_s.dsv32",
                 "loop_offcpu_us.dsv32", "emit_to_wire_us.dsv32",
                 "wire_write_us.dsv32", "writer_batch_tokens.dsv32"} <= names
-    elif cell.startswith("deepseek"):
+    elif cell.startswith("lfm2") and trace:
+        assert {"experts_touched.lfm2", "expert_load_max.lfm2",
+                "kv_context_mean.lfm2", "batch_occupancy.lfm2",
+                "compile_s.lfm2", "overlap_share.lfm2",
+                "loop_offcpu_us.lfm2", "emit_to_wire_us.lfm2",
+                "wire_write_us.lfm2", "writer_batch_tokens.lfm2"} <= names
+    elif cell.startswith(("deepseek", "lfm2")):
         assert names == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
     else:
         assert "compile_s" in names and result["device"]["count"] == 4

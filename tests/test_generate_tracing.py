@@ -561,6 +561,49 @@ def test_a_pair_of_counter_metrics_is_an_entry_a_serving_cell(stem, layer,
             layer, "serve_tokens_per_s", unit, "higher")
 
 
+def _lfm2_metric_files():
+    return sorted(f[:-5] for f in os.listdir(os.path.join(
+        REPO, "chipbench", "metrics")) if f.endswith(".lfm2.json"))
+
+
+@pytest.mark.parametrize("name", _lfm2_metric_files())
+def test_an_lfm2_metric_file_is_an_entry_of_its_cell(name):
+    """PR 33's set: every ``*.lfm2.json`` has its ``BENCHMARK.json`` entry,
+    which says what the file says and lists the one cell; a namesake of
+    the serving set reads as its ``gpt1`` / ``dsv32`` file does."""
+    from chipbench import common
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    spec = common.load("metrics", name)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == ["lfm2_24b.decode_rollout"]
+    assert spec["jobs"] == ["serve_lfm2"]
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert entry[key] == spec[key], key
+    # appended behind everything that was there
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index(name) > names.index("writer_batch_tokens.dsv32")
+    twin = name[:-len("lfm2")] + "dsv32"
+    if twin in common.names("metrics"):
+        other = common.load("metrics", twin)
+        assert {k: v for k, v in spec.items() if k != "jobs"} \
+            == {k: v for k, v in other.items() if k != "jobs"}
+    else:
+        assert name == "kv_context_mean.lfm2"
+
+
+def test_the_lfm2_set_has_the_serving_namesakes_and_the_four_of_its_own():
+    stems = {n[:-len(".lfm2")] for n in _lfm2_metric_files()}
+    assert {"experts_touched", "expert_load_max", "kv_context_mean",
+            "decode_step_roofline"} <= stems
+    assert len(stems) == 23
+    from chipbench import common
+    served = {n[:-len(".dsv32")] for n in common.names("metrics")
+              if n.endswith(".dsv32")}
+    # what DeepSeek's cell reads of its own mechanisms has no namesake here
+    assert served - stems == {"index_selected_share", "routed_held_share"}
+
+
 def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("XLA_FLAGS", None)

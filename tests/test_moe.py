@@ -240,3 +240,51 @@ def test_moe_expert_parallel_zero2_step():
     assert onp.isfinite(l) and l < l0
     sh = net[1].expert_w1._nd._data.sharding
     assert "expert" in sh.spec
+
+
+@pytest.mark.parametrize("holders", [[(0, 16), (16, 16), (32, 16), (48, 16)],
+                                     [(0, 64)], [(0, 40), (40, 24)]])
+def test_lfm2_routing_shares_add_up_to_the_whole_layer(holders):
+    """LFM2's selection (one group, no shared expert, top-4 of 64 by score
+    + bias, gates over ``sum + 1e-6``) through the layer DeepSeek shares:
+    the parts the holders give add up to ``held=(0, 64)`` and to the plain
+    reference's whole layer (``chipbench/reference/lfm2.py``)."""
+    import os
+    import sys
+    import jax.numpy as jnp
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench.reference import lfm2 as ref
+    mx.random.seed(9)
+    d, f, E, k = 16, 8, 64, 4
+    args = dict(route_scale=1, shared_experts=0, norm_eps=1e-6)
+    whole = moe.DroplessMoE(d, f, E, k, held=(0, E), **args)
+    whole.initialize()
+    whole.select_bias.set_data(nd.array(
+        0.05 * onp.random.RandomState(1).randn(E).astype("float32")))
+    w = {n: p.data()._data for n, p in whole._reg_params.items()}
+    x = jnp.asarray(onp.random.RandomState(3).randn(50, d), jnp.float32)
+    want, idx, scores = whole.apply(x)
+    total = 0.0
+    for first, count in holders:
+        part = moe.DroplessMoE(d, f, E, k, held=(first, count), **args)
+        part.initialize()
+        for name, p in part._reg_params.items():
+            p.set_data(w[name][first:first + count]
+                       if name.startswith("held_") else w[name])
+        y, idx_r, _ = part.apply(x)
+        assert (onp.asarray(idx_r) == onp.asarray(idx)).all()
+        total = total + y
+    assert onp.abs(onp.asarray(total - want)).max() < 1e-5
+    cfg = {"num_experts_per_tok": k, "routed_scaling_factor": 1}
+    y_ref, s_ref, idx_ref = ref.feed_forward(
+        cfg, {"ffn." + n: v for n, v in w.items()}, x)
+    assert onp.abs(onp.asarray(y_ref - want)).max() < 1e-5
+    assert onp.abs(onp.asarray(s_ref - scores)).max() < 1e-6
+    assert (onp.sort(onp.asarray(idx_ref), -1)
+            == onp.sort(onp.asarray(idx), -1)).all()
+    # the gates' guard: they sum to just under the scale
+    chosen = onp.take_along_axis(onp.asarray(scores), onp.asarray(idx), -1)
+    _i, gates = moe.noaux_route(scores, w["select_bias"], k, norm_eps=1e-6)
+    assert onp.allclose(onp.asarray(gates).sum(-1),
+                        chosen.sum(-1) / (chosen.sum(-1) + 1e-6), atol=1e-6)
