@@ -42,6 +42,7 @@ from .. import initializer as init
 from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
+from ..ops import latent_ring_attention as _lra
 from .parts import (FanInNormal as _FanInNormal, matmul as _mm,
                     rms_norm as _rms, rope as _rope, sub_weights as _sub)
 
@@ -91,6 +92,10 @@ STEP_COUNTERS = (
                         "layers and steps"),
     ("expert_load_max", "largest load of a held expert in a step (over "
                         "the layers), summed over steps"),
+    ("latent_rows_read", "rows of the latent ring the attention read, "
+                         "summed over slots and layers: whole blocks of "
+                         "valid positions where the kernel ran, the "
+                         "selected rows where the gather did"),
 )
 
 
@@ -209,6 +214,32 @@ def topk_mask(scores, valid, k):
     return valid & (masked >= kth)
 
 
+def selection_mask(chosen, keep, ring_len):
+    """``top_k``'s indices ``chosen`` [S, K] (distinct a slot) as a mask
+    [S, ring_len], true at ``chosen[s, k]`` where ``keep[s, k]``: the
+    selection itself, ties and all (on a TPU ``top_k`` does not break ties
+    by position, so no threshold on the scores gives it).  Where the ring
+    is whole lanes the mask is the product of two one-hot matrices,
+    ``position // 128`` [S, ring_len / 128, K] and ``position % 128``
+    [S, K, 128]: exact, a position being chosen at most once, and one
+    fusion on the matrix unit where a scatter of S x K single elements is
+    a loop over them (0.25 ms against 0.71 a layer at 64 x 2,048 into
+    6,144 on a v5e: PERF.md, PR 34)."""
+    jnp = _jnp()
+    S = chosen.shape[0]
+    if ring_len % LATENT_ALIGN:
+        return jnp.zeros((S, ring_len), bool).at[
+            jnp.arange(S)[:, None], chosen].set(keep)
+    high = jnp.where(keep, chosen // LATENT_ALIGN, -1)[:, None, :]
+    low = (chosen % LATENT_ALIGN)[:, :, None]
+    high = (high == jnp.arange(ring_len // LATENT_ALIGN)[None, :, None])
+    low = (low == jnp.arange(LATENT_ALIGN)[None, None, :])
+    hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
+                      low.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return (hits > 0).reshape(S, ring_len)
+
+
 def _attn_full(c, w, x, pos, index_topk, want_mask):
     """Attention over a whole sequence in the expanded form, in blocks of
     queries so that neither the heads' scores nor the indexer's are ever
@@ -312,9 +343,22 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     The new rows land at ``pos % M`` of the active slots (one scatter a
     ring); the indexer scores the slot's valid positions, ``top_k`` keeps
     ``index_topk`` of them, and attention runs in the absorbed form over
-    a gather of the selected latent rows alone (masked dense attention
-    over the whole ring gives the same result and took 2.5 times as long
-    on a v5e at 64 slots x 6,144: PERF.md, PR 28).  Returns ``(logits [S, V] float32, rings,
+    the selected latent rows alone, in one of two forms that share no
+    line and are chosen by what the code sees, with no switch:
+
+    * on one TPU, for a ring a block divides,
+      :func:`mxnet_tpu.ops.latent_ring_attention.latent_ring_attention`
+      reads the donated ring where it lies, the selection as a mask: it
+      skips the blocks past a slot's valid positions and writes neither
+      rows, scores nor probabilities to memory;
+    * on a CPU, under a mesh, or where the compiler refuses the kernel, a
+      gather of the selected rows and plain einsums: the kernel's tested
+      reference.
+
+    At 64 slots x 6,144 on a v5e masked dense attention over the whole
+    ring took 2.5 times as long as the gather form (PERF.md, PR 28); the
+    kernel took the step from 41.5 ms in the gather form to 27 (PERF.md,
+    PR 31 and 34).  Returns ``(logits [S, V] float32, rings,
     counts [len(STEP_COUNTERS)] int32)``, and with ``want_selections`` a
     fourth: :func:`run_full`'s selections for this one position a slot
     (masks and index scores [S, M] over the ring)."""
@@ -351,24 +395,33 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
         scores = index_scores(qi, wi, ring_i.astype(x.dtype))[:, 0]  # [S, M]
         K = min(index_topk, M)
         vals, chosen = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), K)
-        rows = jnp.take_along_axis(ring_l, chosen[:, :, None],
-                                   axis=1).astype(x.dtype)
         keep = vals > -jnp.inf
+        block = _lra.kernel_block(S, H, kvr, c.qk_rope_head_dim, M,
+                                  ring_l.shape[2], x.dtype, ring_l.dtype)
+        if want_selections or block is not None:
+            mask = selection_mask(chosen, keep, M)
         if want_selections:
-            sel["positions"].append(jnp.zeros((S, M), bool).at[
-                slots[:, None], chosen].set(keep))
+            sel["positions"].append(mask)
         sel["index_scores"].append(scores)
         wkb = lw["wkv_b"].reshape(kvr, H, n + dv)
         q_abs = jnp.einsum("shn,chn->shc", q_nope[:, 0], wkb[..., :n],
                            preferred_element_type=f32).astype(x.dtype)
-        s = jnp.einsum("shc,skc->shk", q_abs, rows[..., :kvr],
-                       preferred_element_type=f32) \
-            + jnp.einsum("shr,skr->shk", q_rope[:, 0], rows[..., kvr:row],
-                         preferred_element_type=f32)
-        s = jnp.where(keep[:, None], s * scale, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        o = jnp.einsum("shk,skc->shc", p, rows[..., :kvr],
-                       preferred_element_type=f32).astype(x.dtype)
+        if block is not None:
+            o = _lra.latent_ring_attention(q_abs, q_rope[:, 0], ring_l, mask,
+                                           n_valid, scale, block=block)
+            rows_read = _lra.rows_visited(n_valid, block)
+        else:
+            rows = jnp.take_along_axis(ring_l, chosen[:, :, None],
+                                       axis=1).astype(x.dtype)
+            s = jnp.einsum("shc,skc->shk", q_abs, rows[..., :kvr],
+                           preferred_element_type=f32) \
+                + jnp.einsum("shr,skr->shk", q_rope[:, 0],
+                             rows[..., kvr:row], preferred_element_type=f32)
+            s = jnp.where(keep[:, None], s * scale, -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            o = jnp.einsum("shk,skc->shc", p, rows[..., :kvr],
+                           preferred_element_type=f32).astype(x.dtype)
+            rows_read = jnp.minimum(n_valid, K)
         o = jnp.einsum("shc,chv->shv", o, wkb[..., n:],
                        preferred_element_type=f32).astype(x.dtype)
         x = x + _mm(o.reshape(S, 1, H * dv), lw["wo"])
@@ -379,9 +432,9 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
         if idx is not None:
             sel["experts"].append(idx)
             sel["router_scores"].append(router_scores)
-        seen = jnp.stack([(act * n_valid).sum(),
-                          (act * jnp.minimum(n_valid, K)).sum()])
-        counts = counts.at[:2].add(seen.astype(jnp.int32))
+        seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
+                                 rows_read])).sum(axis=1).astype(jnp.int32)
+        counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2])
         if load is not None:
             counts = counts.at[2:5].add(load[:3])
             counts = counts.at[5].max(load[3])

@@ -213,6 +213,9 @@ def test_prefill_then_decode_is_the_full_forward(held):
         layers, moe_layers, K = 3, 2, net.config.index_topk
         assert counts["index_valid_positions"] == 2 * layers * (P + j + 1)
         assert counts["index_selected_positions"] == 2 * layers * K
+        # on a CPU attention gathers the selected rows and reads no other
+        assert counts["latent_rows_read"] == 2 * layers * K
+        assert len(counts) == len(deepseek.STEP_COUNTERS)
         assert counts["routed_pairs"] == 2 * moe_layers * 4
         assert (counts["routed_pairs_held"] == counts["routed_pairs"]) \
             == (held == (0, 16))

@@ -601,7 +601,8 @@ def test_the_lfm2_set_has_the_serving_namesakes_and_the_four_of_its_own():
     served = {n[:-len(".dsv32")] for n in common.names("metrics")
               if n.endswith(".dsv32")}
     # what DeepSeek's cell reads of its own mechanisms has no namesake here
-    assert served - stems == {"index_selected_share", "routed_held_share"}
+    assert served - stems == {"index_selected_share", "routed_held_share",
+                              "latent_rows_read"}
 
 
 def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():
