@@ -43,8 +43,10 @@ from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from ..ops import latent_ring_attention as _lra
-from .parts import (FanInNormal as _FanInNormal, matmul as _mm,
-                    rms_norm as _rms, rope as _rope, sub_weights as _sub)
+from .parts import (LANES, FanInNormal as _FanInNormal, index_scores,
+                    layer_norm as _layernorm, matmul as _mm,
+                    rms_norm as _rms, rope as _rope, selection_mask,
+                    sub_weights as _sub, topk_mask)
 
 __all__ = ["DeepSeekV32LM", "V32_PUBLISHED", "tiny_v32", "run_full", "decode",
            "yarn_inv_freq", "softmax_scale", "STEP_COUNTERS"]
@@ -72,7 +74,7 @@ QUERY_BLOCK = 512
 
 # a latent row is stored at the next multiple of the chip's lane width
 # (576 numbers at 640, see _ring_row)
-LATENT_ALIGN = 128
+LATENT_ALIGN = LANES
 
 # the noaux_tc selection bias of a model built from a seed: drawn at this
 # scale so that it is exercised (a trained checkpoint carries its own)
@@ -136,15 +138,6 @@ def _jnp():
     return jnp
 
 
-def _layernorm(x, g, b, eps):
-    jnp = _jnp()
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = jnp.square(x32 - mu).mean(-1, keepdims=True)
-    return ((x32 - mu) / jnp.sqrt(var + eps) * g.astype(jnp.float32)
-            + b.astype(jnp.float32)).astype(x.dtype)
-
-
 def _ring_row(c, latent):
     """A latent row as the ring stores it: padded with zeros to
     ``c.latent_stride`` numbers (:data:`LATENT_ALIGN`).  At 576 numbers a row the chip lays a
@@ -189,55 +182,6 @@ def _attn_inputs(c, w, x, pos):
     wi = jnp.dot(x, w["idx_w"], preferred_element_type=f32) \
         * (Hi ** -0.5 * Di ** -0.5)
     return q_nope, q_rope, latent, qi, ki, wi
-
-
-def index_scores(qi, wi, ki):
-    """``I`` [B, Q, K] float32 from q^I [B,Q,Hi,Di], w [B,Q,Hi] and k^I
-    [B,K,Di].  The weighted sum over heads is elementwise: a float32
-    product through the matrix unit would round ``w`` and the ReLUs."""
-    import jax
-    jnp = _jnp()
-    s = jnp.einsum("bqhd,bkd->bqhk", qi, ki,
-                   preferred_element_type=jnp.float32)
-    return (jax.nn.relu(s) * wi[..., None]).sum(axis=2)
-
-
-def topk_mask(scores, valid, k):
-    """The ``k`` largest of ``scores`` [..., N] among ``valid``, as a
-    mask (all of ``valid`` where it has no more than ``k``)."""
-    import jax
-    jnp = _jnp()
-    if k >= scores.shape[-1]:
-        return valid
-    masked = jnp.where(valid, scores, -jnp.inf)
-    kth = jax.lax.top_k(masked, k)[0][..., -1:]
-    return valid & (masked >= kth)
-
-
-def selection_mask(chosen, keep, ring_len):
-    """``top_k``'s indices ``chosen`` [S, K] (distinct a slot) as a mask
-    [S, ring_len], true at ``chosen[s, k]`` where ``keep[s, k]``: the
-    selection itself, ties and all (on a TPU ``top_k`` does not break ties
-    by position, so no threshold on the scores gives it).  Where the ring
-    is whole lanes the mask is the product of two one-hot matrices,
-    ``position // 128`` [S, ring_len / 128, K] and ``position % 128``
-    [S, K, 128]: exact, a position being chosen at most once, and one
-    fusion on the matrix unit where a scatter of S x K single elements is
-    a loop over them (0.25 ms against 0.71 a layer at 64 x 2,048 into
-    6,144 on a v5e: PERF.md, PR 34)."""
-    jnp = _jnp()
-    S = chosen.shape[0]
-    if ring_len % LATENT_ALIGN:
-        return jnp.zeros((S, ring_len), bool).at[
-            jnp.arange(S)[:, None], chosen].set(keep)
-    high = jnp.where(keep, chosen // LATENT_ALIGN, -1)[:, None, :]
-    low = (chosen % LATENT_ALIGN)[:, :, None]
-    high = (high == jnp.arange(ring_len // LATENT_ALIGN)[None, :, None])
-    low = (low == jnp.arange(LATENT_ALIGN)[None, None, :])
-    hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
-                      low.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
-    return (hits > 0).reshape(S, ring_len)
 
 
 def _attn_full(c, w, x, pos, index_topk, want_mask):

@@ -41,8 +41,8 @@ from .. import initializer as init
 from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
-from .parts import (FanInNormal, matmul as _mm, rms_norm as _rms,
-                    rope as _rope, sub_weights as _sub)
+from .parts import (DrawnBias as _DrawnBias, FanInNormal, matmul as _mm,
+                    rms_norm as _rms, rope as _rope, sub_weights as _sub)
 
 __all__ = ["LFM2MoeLM", "LFM2_PUBLISHED", "tiny_lfm2", "run_full", "decode",
            "STEP_COUNTERS"]
@@ -330,14 +330,6 @@ def decode(c, w, tok, caches, pos, active=None, want_selections=False):
 # ---------------------------------------------------------------------------
 # the model
 # ---------------------------------------------------------------------------
-class _DrawnBias(FanInNormal):
-    """:class:`FanInNormal` for a parameter whose name ends in ``bias``,
-    which the base class reads as a zero."""
-
-    def init_array(self, name, shape, dtype):
-        return self._init_weight(name, shape, dtype)
-
-
 class _LFM2Block(HybridBlock):
     def __init__(self, c, index, dtype, grad_req):
         super().__init__()

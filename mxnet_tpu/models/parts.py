@@ -1,8 +1,10 @@
 """What the decoder families written as pure functions of a dict of raw
-weights share (``deepseek.py``, ``lfm2.py``): the norm, the product, the
-rotation, the weights of one layer, and the initializer of a served model
-built from a seed.  Weights are stored [in, out]; norms and rotations are
-float32 inside whatever the activations are."""
+weights share (``deepseek.py``, ``lfm2.py``, ``keye.py``): the norms, the
+product, the rotation and its sectioned angles, the learned selection of
+positions (the indexer's scores, the top-k and its mask), the weights of
+one layer, and the initializer of a served model built from a seed.
+Weights are stored [in, out]; norms, rotations and index scores are float32
+inside whatever the activations are."""
 from __future__ import annotations
 
 import functools
@@ -12,7 +14,13 @@ import numpy as onp
 from .. import initializer as init
 from .. import random as _random
 
-__all__ = ["rms_norm", "matmul", "rope", "sub_weights", "FanInNormal"]
+__all__ = ["rms_norm", "layer_norm", "matmul", "rope", "sectioned_angles",
+           "index_scores", "topk_mask", "selection_mask", "sub_weights",
+           "FanInNormal", "DrawnBias", "LANES"]
+
+# the chip's lane width: a ring whose row is a multiple of it lies with the
+# rows contiguous, and :func:`selection_mask` splits a position by it
+LANES = 128
 
 
 def rms_norm(x, g, eps):
@@ -20,6 +28,15 @@ def rms_norm(x, g, eps):
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return (x32 / jnp.sqrt(ms + eps) * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, g, b, eps):
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    mu = x32.mean(-1, keepdims=True)
+    var = jnp.square(x32 - mu).mean(-1, keepdims=True)
+    return ((x32 - mu) / jnp.sqrt(var + eps) * g.astype(jnp.float32)
+            + b.astype(jnp.float32)).astype(x.dtype)
 
 
 def matmul(x, w):
@@ -44,6 +61,73 @@ def rope(x, cos, sin, interleaved):
     else:
         out = jnp.concatenate([ra, rb], axis=-1)
     return out.astype(x.dtype)
+
+
+def sectioned_angles(pos3, dim, theta, sections):
+    """Rotary angles [..., dim / 2] float32 of three-axis positions
+    ``pos3`` [3, ...] (temporal, height, width): frequency ``i`` of the
+    ``dim / 2`` (``theta ** (-2 i / dim)``) turns by the axis whose section
+    holds it, the sections laid out in chunks (``sections`` = how many
+    frequencies each axis takes, in order).  With the three axes equal
+    these are plain rotary angles."""
+    import jax.numpy as jnp
+    if sum(sections) != dim // 2:
+        raise ValueError(f"sections {sections} must add up to {dim // 2}")
+    inv = 1.0 / float(theta) ** (
+        onp.arange(0, dim, 2, dtype=onp.float64) / dim)
+    # the axis that owns each frequency: its position, a frequency
+    axis = onp.repeat(onp.arange(len(sections)), sections)     # [dim / 2]
+    p = jnp.moveaxis(pos3.astype(jnp.float32), 0, -1)[..., axis]
+    return p * jnp.asarray(inv.astype(onp.float32))
+
+
+def index_scores(qi, wi, ki):
+    """``I`` [B, Q, K] float32 from q^I [B,Q,Hi,Di], w [B,Q,Hi] and k^I
+    [B,K,Di].  The weighted sum over heads is elementwise: a float32
+    product through the matrix unit would round ``w`` and the ReLUs."""
+    import jax
+    import jax.numpy as jnp
+    s = jnp.einsum("bqhd,bkd->bqhk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * wi[..., None]).sum(axis=2)
+
+
+def topk_mask(scores, valid, k):
+    """The ``k`` largest of ``scores`` [..., N] among ``valid``, as a
+    mask (all of ``valid`` where it has no more than ``k``)."""
+    import jax
+    import jax.numpy as jnp
+    if k >= scores.shape[-1]:
+        return valid
+    masked = jnp.where(valid, scores, -jnp.inf)
+    kth = jax.lax.top_k(masked, k)[0][..., -1:]
+    return valid & (masked >= kth)
+
+
+def selection_mask(chosen, keep, ring_len):
+    """``top_k``'s indices ``chosen`` [S, K] (distinct a slot) as a mask
+    [S, ring_len], true at ``chosen[s, k]`` where ``keep[s, k]``: the
+    selection itself, ties and all (on a TPU ``top_k`` does not break ties
+    by position, so no threshold on the scores gives it).  Where the ring
+    is whole lanes the mask is the product of two one-hot matrices,
+    ``position // 128`` [S, ring_len / 128, K] and ``position % 128``
+    [S, K, 128]: exact, a position being chosen at most once, and one
+    fusion on the matrix unit where a scatter of S x K single elements is
+    a loop over them (0.25 ms against 0.71 a layer at 64 x 2,048 into
+    6,144 on a v5e: PERF.md, PR 34)."""
+    import jax.numpy as jnp
+    S = chosen.shape[0]
+    if ring_len % LANES:
+        return jnp.zeros((S, ring_len), bool).at[
+            jnp.arange(S)[:, None], chosen].set(keep)
+    high = jnp.where(keep, chosen // LANES, -1)[:, None, :]
+    low = (chosen % LANES)[:, :, None]
+    high = (high == jnp.arange(ring_len // LANES)[None, :, None])
+    low = (low == jnp.arange(LANES)[None, None, :])
+    hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
+                      low.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return (hits > 0).reshape(S, ring_len)
 
 
 def sub_weights(w, prefix):
@@ -72,3 +156,11 @@ class FanInNormal(init.Initializer):
         sigma = self.sigma or shape[-2] ** -0.5
         return _normal_maker(tuple(shape), str(onp.dtype(dtype)),
                              float(sigma))(_random.next_key())
+
+
+class DrawnBias(FanInNormal):
+    """:class:`FanInNormal` for a parameter whose name ends in ``bias``,
+    which the base class reads as a zero."""
+
+    def init_array(self, name, shape, dtype):
+        return self._init_weight(name, shape, dtype)

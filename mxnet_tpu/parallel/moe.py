@@ -15,10 +15,11 @@ Pieces:
 - :class:`MoE` — Gluon ``HybridBlock`` position-wise FFN MoE layer; expert
   weights are stacked ``(E, ...)`` so one regex rule shards them.
 - :func:`noaux_route` / :func:`dropless_experts` / :class:`DroplessMoE` —
-  the other kind of layer: sigmoid scores with a selection bias,
-  group-limited top-k, no capacity and so no dropped pair, one grouped
-  product a projection (``jax.lax.ragged_dot``) over the experts this chip
-  *holds* of ``num_experts``, and a shared expert.
+  the other kind of layer: sigmoid scores with a selection bias (or a
+  softmax over all experts and none), group-limited top-k, no capacity
+  and so no dropped pair, one grouped product a projection
+  (``jax.lax.ragged_dot``) over the experts this chip *holds* of
+  ``num_experts``, and a shared expert.
 - :func:`moe_sharding_rules` — ``shard_params`` rules for the EP axis.
 - :func:`aux_loss_scope` — collects router aux losses during a forward so
   the training loss can add them (pure-function-friendly: the collected
@@ -307,18 +308,28 @@ def held_load(idx, first, count, weight=None):
 
 
 def dropless_moe(x2d, w, k, first, n_group=1, topk_group=1, route_scale=1.0,
-                 with_shared=True, norm_eps=0.0):
+                 with_shared=True, norm_eps=0.0, scoring="sigmoid"):
     """One expert layer on raw tokens [T, d] from its raw weights ``w``
-    (``gate_weight`` [d, E], ``select_bias`` [E], ``held_w1/w3/w2``,
-    optionally ``shared_w1/w3/w2``).  The router's product and its sigmoid
-    are float32.  Returns ``(y [T, d] float32, idx, gates, scores)``."""
+    (``gate_weight`` [d, E], optionally ``select_bias`` [E] (none, if
+    absent), ``held_w1/w3/w2``, optionally ``shared_w1/w3/w2``).  The
+    router's product and its ``scoring`` are float32: ``"sigmoid"`` of
+    each logit, or ``"softmax"`` over all ``E`` (the gates are then the
+    chosen probabilities renormalised).  Returns ``(y [T, d] float32, idx,
+    gates, scores)``."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(x2d.astype(f32),
-                                    w["gate_weight"].astype(f32)))
-    idx, gates = noaux_route(scores, w["select_bias"].astype(f32), k,
-                             n_group, topk_group, route_scale, norm_eps)
+    logits = jnp.dot(x2d.astype(f32), w["gate_weight"].astype(f32))
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"scoring {scoring!r} is neither sigmoid nor softmax")
+    bias = w["select_bias"].astype(f32) if "select_bias" in w \
+        else jnp.zeros((scores.shape[-1],), f32)
+    idx, gates = noaux_route(scores, bias, k, n_group, topk_group,
+                             route_scale, norm_eps)
     y = dropless_experts(x2d, idx, gates, w["held_w1"], w["held_w3"],
                          w["held_w2"], first)
     if with_shared and "shared_w1" in w:
@@ -336,14 +347,17 @@ class DroplessMoE(HybridBlock):
     expert, which every holder computes alike.  On one chip it runs
     without its exchange: what the absent experts would add is left out.
     The parts of all the holders, the shared expert counted once, add up
-    to the whole layer (``tests/test_deepseek.py``).  Weights are stored
-    [in, out]; ``select_bias`` is the ``noaux_tc`` selection bias."""
+    to the whole layer (``tests/test_moe.py``).  Weights are stored
+    [in, out]; ``select_bias`` is the ``noaux_tc`` selection bias, left
+    out with ``select_bias=False``; ``scoring`` is the router's
+    (:func:`dropless_moe`)."""
 
     def __init__(self, units, hidden_size, num_experts, k, held=None,
                  n_group=1, topk_group=1, route_scale=1.0, shared_experts=1,
                  dtype="float32", weight_initializer=None,
                  bias_initializer=None, grad_req="write", norm_eps=0.0,
-                 prefix=None, params=None):
+                 scoring="sigmoid", select_bias=True, prefix=None,
+                 params=None):
         super().__init__(prefix, params)
         first, count = held if held is not None else (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
@@ -352,6 +366,7 @@ class DroplessMoE(HybridBlock):
         self._first, self._count = int(first), int(count)
         self._n_group, self._topk_group = n_group, topk_group
         self._route_scale, self._norm_eps = route_scale, norm_eps
+        self._scoring = scoring
         winit = weight_initializer or init.Xavier()
         sh = shared_experts * hidden_size
 
@@ -359,9 +374,10 @@ class DroplessMoE(HybridBlock):
             setattr(self, name, Parameter(name, shape=shape, dtype=dtype,
                                           init=winit, grad_req=grad_req))
         mat("gate_weight", units, num_experts)
-        self.select_bias = Parameter(
-            "select_bias", shape=(num_experts,), dtype="float32",
-            init=bias_initializer or init.Zero(), grad_req=grad_req)
+        if select_bias:
+            self.select_bias = Parameter(
+                "select_bias", shape=(num_experts,), dtype="float32",
+                init=bias_initializer or init.Zero(), grad_req=grad_req)
         mat("held_w1", count, units, hidden_size)
         mat("held_w3", count, units, hidden_size)
         mat("held_w2", count, hidden_size, units)
@@ -382,7 +398,7 @@ class DroplessMoE(HybridBlock):
             x.reshape(-1, x.shape[-1]), w, k=self._k, first=self._first,
             n_group=self._n_group, topk_group=self._topk_group,
             route_scale=self._route_scale, with_shared=with_shared,
-            norm_eps=self._norm_eps)
+            norm_eps=self._norm_eps, scoring=self._scoring)
         return y.astype(x.dtype).reshape(x.shape), idx, scores
 
     def forward(self, x):

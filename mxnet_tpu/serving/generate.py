@@ -719,8 +719,11 @@ class GenerationEngine:
                     return model.prefill(NDArray(tok), NDArray(vl), **kw)
 
             res, _aux = _run_with_params(ps, raws, call)
-            lraw = unwrap(res[0])                       # (1, Lb, V)
-            row = jnp.take(lraw[0], vl[0] - 1, axis=0)
+            # (1, Lb, V), or (1, 1, V): the row at vl - 1 alone, from a
+            # model whose vocabulary times the bucket is gigabytes
+            lraw = unwrap(res[0])
+            row = lraw[0, 0] if lraw.shape[1] == 1 \
+                else jnp.take(lraw[0], vl[0] - 1, axis=0)
             first = jnp.argmax(row).astype(jnp.int32)
             # the host reads `first`; the slot's next decode step reads it
             # from the vector, without waiting for the host

@@ -1,4 +1,4 @@
-"""The benchmark cells PR 28 and PR 33 add, rehearsed end to end through
+"""The benchmark cells PR 28, PR 33 and PR 35 add, rehearsed end to end through
 ``chipbench/run.py --rehearse`` on the CPU (tiny sizes, every value null);
 ``bert_base.pretrain_dp4``'s files wait in the tree for a ``benchmark`` PR
 (PERF.md section 7.1) and are rehearsed with it."""
@@ -16,7 +16,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
                                         ("deepseek_v32.decode_long", 0),
                                         ("bert_base.pretrain_dp4", 1),
                                         ("lfm2_24b.decode_rollout", 1),
-                                        ("lfm2_24b.decode_rollout", 0)])
+                                        ("lfm2_24b.decode_rollout", 0),
+                                        ("keye_vl2.decode_doc", 1),
+                                        ("keye_vl2.decode_doc", 0)])
 def test_run_py_rehearses_the_cell(cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
@@ -41,7 +43,14 @@ def test_run_py_rehearses_the_cell(cell, trace):
                 "compile_s.lfm2", "overlap_share.lfm2",
                 "loop_offcpu_us.lfm2", "emit_to_wire_us.lfm2",
                 "wire_write_us.lfm2", "writer_batch_tokens.lfm2"} <= names
-    elif cell.startswith(("deepseek", "lfm2")):
+    elif cell.startswith("keye") and trace:
+        assert {"index_selected_share.keye", "kv_rows_read.keye",
+                "kv_context_mean.keye", "experts_touched.keye",
+                "expert_load_max.keye", "batch_occupancy.keye",
+                "compile_s.keye", "overlap_share.keye",
+                "loop_offcpu_us.keye", "emit_to_wire_us.keye",
+                "wire_write_us.keye", "writer_batch_tokens.keye"} <= names
+    elif cell.startswith(("deepseek", "lfm2", "keye")):
         assert names == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
     else:
         assert "compile_s" in names and result["device"]["count"] == 4

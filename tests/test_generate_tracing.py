@@ -561,48 +561,74 @@ def test_a_pair_of_counter_metrics_is_an_entry_a_serving_cell(stem, layer,
             layer, "serve_tokens_per_s", unit, "higher")
 
 
-def _lfm2_metric_files():
+# the sets of metric files a model_config PR copies for its job kind
+# (PERF.md section 7.7): suffix -> (job, cell, the entry they all follow,
+# what the set has of its own beside the 19 serving namesakes, what
+# DeepSeek's set has that it lacks)
+METRIC_SETS = {
+    "lfm2": ("serve_lfm2", "lfm2_24b.decode_rollout",         # PR 33
+             "writer_batch_tokens.dsv32",
+             {"experts_touched", "expert_load_max", "kv_context_mean",
+              "decode_step_roofline"},
+             {"index_selected_share", "routed_held_share",
+              "latent_rows_read"}),
+    "keye": ("serve_keye", "keye_vl2.decode_doc",             # PR 35
+             "decode_step_roofline.lfm2",
+             {"experts_touched", "expert_load_max", "kv_context_mean",
+              "decode_step_roofline", "index_selected_share",
+              "kv_rows_read"},
+             {"routed_held_share", "latent_rows_read"}),
+}
+
+
+def _set_metric_files(suffix):
     return sorted(f[:-5] for f in os.listdir(os.path.join(
-        REPO, "chipbench", "metrics")) if f.endswith(".lfm2.json"))
+        REPO, "chipbench", "metrics")) if f.endswith(f".{suffix}.json"))
 
 
-@pytest.mark.parametrize("name", _lfm2_metric_files())
-def test_an_lfm2_metric_file_is_an_entry_of_its_cell(name):
-    """PR 33's set: every ``*.lfm2.json`` has its ``BENCHMARK.json`` entry,
-    which says what the file says and lists the one cell; a namesake of
-    the serving set reads as its ``gpt1`` / ``dsv32`` file does."""
+@pytest.mark.parametrize("name", [
+    name for suffix in METRIC_SETS for name in _set_metric_files(suffix)])
+def test_a_sets_metric_file_is_an_entry_of_its_cell(name):
+    """Every ``*.lfm2.json`` and ``*.keye.json`` has its ``BENCHMARK.json``
+    entry, which says what the file says and lists the one cell; a
+    namesake of the serving set reads as its ``dsv32`` / ``lfm2`` file
+    does."""
     from chipbench import common
+    suffix = name.rsplit(".", 1)[1]
+    job, cell, after, _own, _lacks = METRIC_SETS[suffix]
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     spec = common.load("metrics", name)
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["lfm2_24b.decode_rollout"]
-    assert spec["jobs"] == ["serve_lfm2"]
+    assert entry["workloads"] == [cell]
+    assert spec["jobs"] == [job]
     for key in ("layer", "unit", "better", "source", "moves"):
         assert entry[key] == spec[key], key
     # appended behind everything that was there
     names = [m["name"] for m in bench["per_layer"]]
-    assert names.index(name) > names.index("writer_batch_tokens.dsv32")
-    twin = name[:-len("lfm2")] + "dsv32"
-    if twin in common.names("metrics"):
-        other = common.load("metrics", twin)
+    assert names.index(name) > names.index(after)
+    stem = name[:-len(suffix)]
+    twins = [stem + other for other in ("dsv32", "lfm2")
+             if other != suffix and stem + other in common.names("metrics")]
+    if twins:
+        other = common.load("metrics", twins[0])
         assert {k: v for k, v in spec.items() if k != "jobs"} \
             == {k: v for k, v in other.items() if k != "jobs"}
     else:
-        assert name == "kv_context_mean.lfm2"
+        assert name in ("kv_context_mean.lfm2", "kv_rows_read.keye")
 
 
-def test_the_lfm2_set_has_the_serving_namesakes_and_the_four_of_its_own():
-    stems = {n[:-len(".lfm2")] for n in _lfm2_metric_files()}
-    assert {"experts_touched", "expert_load_max", "kv_context_mean",
-            "decode_step_roofline"} <= stems
-    assert len(stems) == 23
+@pytest.mark.parametrize("suffix", sorted(METRIC_SETS))
+def test_a_set_has_the_serving_namesakes_and_those_of_its_own(suffix):
+    _job, _cell, _after, own, lacks = METRIC_SETS[suffix]
+    stems = {n[:-len("." + suffix)] for n in _set_metric_files(suffix)}
+    assert own <= stems
+    assert len(stems) == 19 + len(own)
     from chipbench import common
     served = {n[:-len(".dsv32")] for n in common.names("metrics")
               if n.endswith(".dsv32")}
-    # what DeepSeek's cell reads of its own mechanisms has no namesake here
-    assert served - stems == {"index_selected_share", "routed_held_share",
-                              "latent_rows_read"}
+    # what DeepSeek's cell reads of its own mechanisms and this set does not
+    assert served - stems == lacks
 
 
 def test_rehearsal_of_the_serving_cell_reads_the_counter_metrics():

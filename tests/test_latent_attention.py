@@ -11,7 +11,7 @@ import numpy as onp
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu.models import deepseek, tiny_v32
+from mxnet_tpu.models import deepseek, parts, tiny_v32
 from mxnet_tpu.ops import latent_ring_attention as lra
 
 COUNTERS = [name for name, _help in deepseek.STEP_COUNTERS]
@@ -124,7 +124,7 @@ def test_selection_mask_is_top_ks_selection_ties_and_all(ring_len):
     valid = jnp.arange(ring_len)[None] < n_valid[:, None]
     vals, chosen = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), K)
     keep = vals > -jnp.inf
-    got = onp.asarray(deepseek.selection_mask(chosen, keep, ring_len))
+    got = onp.asarray(parts.selection_mask(chosen, keep, ring_len))
     want = onp.zeros((slots, ring_len), bool)
     for s in range(slots):
         for k in range(K):

@@ -288,3 +288,57 @@ def test_lfm2_routing_shares_add_up_to_the_whole_layer(holders):
     _i, gates = moe.noaux_route(scores, w["select_bias"], k, norm_eps=1e-6)
     assert onp.allclose(onp.asarray(gates).sum(-1),
                         chosen.sum(-1) / (chosen.sum(-1) + 1e-6), atol=1e-6)
+
+
+@pytest.mark.parametrize("holders", [[(0, 32), (32, 32), (64, 32), (96, 32)],
+                                     [(0, 128)]])
+def test_keye_routing_shares_add_up_to_the_whole_layer(holders):
+    """Keye's selection (a float32 softmax over all 128, the 8 largest
+    probabilities, gates renormalised over the chosen, no bias, no shared
+    expert) through the layer DeepSeek and LFM2 share, ``scoring="softmax"``:
+    the parts the holders give add up to ``held=(0, 128)`` and to the plain
+    reference's whole layer (``chipbench/reference/keye.py``)."""
+    import os
+    import sys
+    import jax.numpy as jnp
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench.reference import keye as ref
+    mx.random.seed(9)
+    d, f, E, k = 16, 8, 128, 8
+    args = dict(shared_experts=0, scoring="softmax", select_bias=False)
+    whole = moe.DroplessMoE(d, f, E, k, held=(0, E), **args)
+    whole.initialize()
+    assert "select_bias" not in whole._reg_params
+    w = {n: p.data()._data for n, p in whole._reg_params.items()}
+    x = jnp.asarray(onp.random.RandomState(3).randn(50, d), jnp.float32)
+    want, idx, scores = whole.apply(x)
+    assert onp.allclose(onp.asarray(scores).sum(-1), 1.0, atol=1e-6)
+    total = 0.0
+    for first, count in holders:
+        part = moe.DroplessMoE(d, f, E, k, held=(first, count), **args)
+        part.initialize()
+        for name, p in part._reg_params.items():
+            p.set_data(w[name][first:first + count]
+                       if name.startswith("held_") else w[name])
+        y, idx_r, _ = part.apply(x)
+        assert (onp.asarray(idx_r) == onp.asarray(idx)).all()
+        total = total + y
+    assert onp.abs(onp.asarray(total - want)).max() < 1e-5
+    dims = ref.dims_of({
+        "sa_config": {"indexer_num_kv_heads": 1, "indexer_num_heads": 1,
+                      "indexer_head_dim": 1, "topk": 1},
+        "rope_scaling": {"mrope_section": [1]}, "head_dim": 1,
+        "num_attention_heads": 1, "num_key_value_heads": 1, "rope_theta": 1,
+        "rms_norm_eps": 1e-6, "num_experts": E, "num_experts_per_tok": k})
+    y_ref, s_ref, idx_ref = ref.feed_forward(
+        dims, {"ffn." + n: v for n, v in w.items()}, x)
+    assert onp.abs(onp.asarray(y_ref - want)).max() < 1e-5
+    assert onp.abs(onp.asarray(s_ref - scores)).max() < 1e-6
+    assert (onp.sort(onp.asarray(idx_ref), -1)
+            == onp.sort(onp.asarray(idx), -1)).all()
+    # the gates are the chosen probabilities renormalised
+    _i, gates = moe.noaux_route(scores, jnp.zeros((E,)), k)
+    assert onp.allclose(onp.asarray(gates).sum(-1), 1.0, atol=1e-6)
+    with pytest.raises(ValueError, match="neither sigmoid nor softmax"):
+        moe.dropless_moe(x, w, k=k, first=0, scoring="tanh")
