@@ -98,6 +98,10 @@ STEP_COUNTERS = (
                          "summed over slots and layers: whole blocks of "
                          "valid positions where the kernel ran, the "
                          "selected rows where the gather did"),
+    ("expert_rows_computed", "rows one grouped product over the held "
+                             "experts multiplied (row tiles visited x tile "
+                             "rows), summed over the expert layers: over "
+                             "the held pairs, the product's redundancy"),
 )
 
 
@@ -241,7 +245,8 @@ def _ffn(c, w, i, x, weight=None):
         x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
         n_group=c.n_group, topk_group=c.topk_group,
         route_scale=c.routed_scaling_factor)
-    load = _moe.held_load(idx, first, count, weight)
+    load = _jnp().append(_moe.held_load(idx, first, count, weight),
+                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
     return y.astype(x.dtype).reshape(x.shape), idx, scores, load
 
 
@@ -381,7 +386,7 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
         counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2])
         if load is not None:
             counts = counts.at[2:5].add(load[:3])
-            counts = counts.at[5].max(load[3])
+            counts = counts.at[5].max(load[3]).at[7].add(load[4])
     logits = jnp.dot(_rms(x[:, 0], w["norm"], c.rms_norm_eps), w["head"],
                      preferred_element_type=f32)
     if want_selections:

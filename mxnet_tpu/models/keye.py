@@ -95,6 +95,10 @@ STEP_COUNTERS = (
                         "layers and steps"),
     ("expert_load_max", "largest load of a held expert in a step (over "
                         "the layers), summed over steps"),
+    ("expert_rows_computed", "rows one grouped product over the held "
+                             "experts multiplied (row tiles visited x tile "
+                             "rows), summed over the expert layers: over "
+                             "the held pairs, the product's redundancy"),
 )
 
 
@@ -195,7 +199,8 @@ def _ffn(c, w, x, weight=None):
     y, idx, _gates, scores = _moe.dropless_moe(
         x.reshape(-1, x.shape[-1]), _sub(w, "ffn."),
         k=c.num_experts_per_tok, first=first, scoring="softmax")
-    load = _moe.held_load(idx, first, count, weight)
+    load = _jnp().append(_moe.held_load(idx, first, count, weight),
+                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
     return y.astype(x.dtype).reshape(x.shape), idx, scores, load
 
 
@@ -358,7 +363,7 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
                                  rows_read, n_valid])).sum(axis=1)
         counts = counts.at[:4].add(seen.astype(jnp.int32))
         counts = counts.at[4].add(load[0]).at[5].add(load[2])
-        counts = counts.at[6].max(load[3])
+        counts = counts.at[6].max(load[3]).at[7].add(load[4])
     logits = head(c, w, x)
     if want_selections:
         return logits, new, counts, sel

@@ -83,6 +83,10 @@ STEP_COUNTERS = (
                         "the layers), summed over steps"),
     ("attn_valid_positions", "cached positions the attention layers read, "
                              "summed over slots and layers"),
+    ("expert_rows_computed", "rows one grouped product over the held "
+                             "experts multiplied (row tiles visited x tile "
+                             "rows), summed over the expert layers: over "
+                             "the held pairs, the product's redundancy"),
 )
 
 
@@ -187,7 +191,8 @@ def _ffn(c, w, i, x, weight=None):
     y, idx, _gates, scores = _moe.dropless_moe(
         x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
         route_scale=c.routed_scaling_factor, norm_eps=GATE_NORM_EPS)
-    load = _moe.held_load(idx, first, count, weight)
+    load = _jnp().append(_moe.held_load(idx, first, count, weight),
+                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
     return y.astype(x.dtype).reshape(x.shape), idx, scores, load
 
 
@@ -320,7 +325,7 @@ def decode(c, w, tok, caches, pos, active=None, want_selections=False):
             sel["router_scores"].append(scores)
             counts = counts.at[0].add(load[0])
             counts = counts.at[1].add(load[2])
-            counts = counts.at[2].max(load[3])
+            counts = counts.at[2].max(load[3]).at[4].add(load[4])
     logits = _head(c, w, x)
     if want_selections:
         return logits, new, counts, sel

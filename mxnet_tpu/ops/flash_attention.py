@@ -18,6 +18,8 @@ Layout: (B, H, L, D).  ``flash_attention_nd`` is the NDArray-facing op.
 from __future__ import annotations
 
 import functools
+import json
+import os
 
 from ..base import MXNetError
 
@@ -94,14 +96,75 @@ def kernel_dispatch_allowed():
 _KERNEL_PROBES = {}     # (kernel, signature) -> None | refusal message
 
 
+class _ProbeMemo:
+    """The probes that compiled, kept beside the persistent compile cache
+    (``kernel_probes.json`` in its root) so that a restart on the same
+    toolchain and chip does not trace, lower and load them again: a probe
+    is a second or two of a warm engine's start, the kernel's first import
+    included.  Refusals are not kept: they are probed, and warned of, in
+    every process.  Off where the compile cache is off."""
+
+    def __init__(self):
+        self._path = self._stamp = self._known = None
+
+    def _load(self):
+        from .. import compile as _compile
+        if not _compile.persistent_cache_enabled():
+            return False
+        path = os.path.join(_compile.cache_root(), "kernel_probes.json")
+        if path != self._path:
+            import jax
+            dev = jax.devices()[0]
+            self._stamp = repr((sorted(_compile.version_stamp().items()),
+                                dev.device_kind,
+                                dev.client.platform_version))
+            self._path, self._known = path, set()
+            try:
+                with open(path) as f:
+                    self._known = set(json.load(f).get(self._stamp, ()))
+            except (OSError, ValueError):
+                pass
+        return True
+
+    def has(self, key):
+        return self._load() and repr(key) in self._known
+
+    def add(self, key):
+        if not self._load():
+            return
+        self._known.add(repr(key))
+        try:                    # best effort, as the cache itself is
+            try:
+                with open(self._path) as f:
+                    every = json.load(f)
+            except (OSError, ValueError):
+                every = {}
+            every[self._stamp] = sorted(
+                self._known | set(every.get(self._stamp, ())))
+            tmp = f"{self._path}.{os.getpid()}"
+            with open(tmp, "w") as f:
+                json.dump(every, f)
+            os.replace(tmp, self._path)
+        except OSError:
+            pass
+
+
+_PROBE_MEMO = _ProbeMemo()
+
+
 def probe_compile(kernel, signature, compile_fn):
     """True when ``compile_fn()`` (a ``jit(...).lower(...).compile()``
-    of one kernel variant) succeeds; memoized per (kernel, signature)."""
+    of one kernel variant) succeeds; memoized per (kernel, signature) in
+    the process, and a success beside the compile cache across them."""
     key = (kernel, signature)
     if key not in _KERNEL_PROBES:
+        if _PROBE_MEMO.has(key):
+            _KERNEL_PROBES[key] = None
+            return True
         try:
             compile_fn()
             _KERNEL_PROBES[key] = None
+            _PROBE_MEMO.add(key)
         except Exception as e:      # noqa: BLE001 — Mosaic, XLA and jax
             # lowering each raise their own types; all mean "refused"
             msg = f"{type(e).__name__}: {e}"

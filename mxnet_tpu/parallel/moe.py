@@ -27,16 +27,19 @@ Pieces:
 """
 from __future__ import annotations
 
+import functools
 import threading
 
 from ..ndarray.ndarray import NDArray, apply_op, unwrap
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
 from .. import initializer as init
+from ..ops import grouped_product as _gp
 
 __all__ = ["MoE", "moe_dispatch", "moe_sharding_rules", "aux_loss_scope",
            "collected_aux_loss", "DroplessMoE", "noaux_route",
-           "dropless_experts", "dropless_moe", "held_load", "swiglu"]
+           "dropless_experts", "dropless_moe", "held_load", "rows_computed",
+           "swiglu"]
 
 _moe_tls = threading.local()
 
@@ -260,35 +263,95 @@ def swiglu(x, w1, w3, w2):
     return jnp.dot(h.astype(x.dtype), w2, preferred_element_type=f32)
 
 
+def _held_pairs(idx, first, count):
+    """``(key, sizes)`` of routed pairs ``idx`` [T, k]: a pair's held
+    expert (``count`` where it is not held here), and the pairs a held
+    expert got [count]."""
+    import jax.numpy as jnp
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    return key, jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+
+
 def dropless_experts(x2d, idx, gates, w1, w3, w2, first):
     """What the experts ``first .. first + count`` (the stacks' leading
     axis) add for tokens ``x2d`` [T, d] routed by ``idx`` / ``gates``
     [T, k]: every pair whose expert is held is computed, whatever the
     load.  Pairs are sorted by held expert (the others last), and each
-    projection is one ``ragged_dot`` over the stack.  float32 [T, d]."""
+    projection is one grouped product over the stack.  float32 [T, d].
+
+    What runs where.  On a TPU, outside a mesh, the product is
+    :func:`mxnet_tpu.ops.grouped_product.grouped_product` (megablox
+    ``gmm``) at the row tile
+    :func:`~mxnet_tpu.ops.grouped_product.row_tile` gives for the pairs
+    and the held experts, the weights in whole rows of an expert's
+    matrix.  Elsewhere (a CPU, a mesh, an ONNX export, a compiler's
+    refusal) it is ``jax.lax.ragged_dot``, the kernel's tested reference.
+    On a TPU that is XLA's own grouped matmul at a row tile of its own
+    choosing, the largest power of two up to 512 that divides the pairs:
+    a decode step's 512 pairs were one tile that each of 64 experts
+    multiplied whole, 1.26 ms a call on a v5e in
+    ``lfm2_24b.decode_rollout`` (ledger, PR 35) where the kernel takes
+    0.56 (my chip runs, PR 36)."""
+    T, k = idx.shape
+    count, d, hidden = w1.shape
+    tm = _gp.kernel_tile(T * k, count, d, hidden, w1.dtype)
+    return _experts_program()(x2d, idx, gates, w1, w3, w2, first=first, tm=tm)
+
+
+def _experts(x2d, idx, gates, w1, w3, w2, first, tm):
+    """:func:`dropless_experts` at the row tile ``tm`` (``ragged_dot``
+    where None)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     T, k = idx.shape
     count = w1.shape[0]
-    local = idx.reshape(-1) - first
-    held = (local >= 0) & (local < count)
-    key = jnp.where(held, local, count)
+    key, sizes = _held_pairs(idx, first, count)
     order = jnp.argsort(key)                          # stable
     token = (jnp.arange(T * k, dtype=jnp.int32) // k)[order]
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+
+    def product(a, w):
+        if tm is None:
+            return jax.lax.ragged_dot(a, w, sizes,
+                                      preferred_element_type=f32)
+        return _gp.grouped_product(a, w, sizes, tm)
     xs = x2d[token]
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,
-                                       preferred_element_type=f32)) \
-        * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
-    y = jax.lax.ragged_dot(h.astype(x2d.dtype), w2, sizes,
-                           preferred_element_type=f32)
+    h = jax.nn.silu(product(xs, w1)) * product(xs, w3)
+    y = product(h.astype(x2d.dtype), w2)
     # rows past the held pairs belong to no group: whatever is there is
     # not a result
-    g = jnp.where(held, gates.reshape(-1), 0.0)[order].astype(f32)
+    g = jnp.where(key < count, gates.reshape(-1), 0.0)[order].astype(f32)
     live = jnp.arange(T * k) < sizes.sum()
     y = jnp.where(live[:, None], y, 0.0) * g[:, None]
     return jnp.zeros((T, x2d.shape[-1]), f32).at[token].add(y)
+
+
+@functools.lru_cache(maxsize=None)
+def _experts_program():
+    """:func:`_experts` as one jitted function: a model's expert layers
+    are alike, so a program that holds eight of them traces and lowers
+    the layer (three kernels and what prepares their groups) once and
+    calls it eight times.  XLA inlines the calls: the compiled program is
+    the one the unrolled trace gave, an engine's start is seconds
+    shorter (my chip runs, PR 36)."""
+    import jax
+    return jax.jit(_experts, static_argnames=("first", "tm"))
+
+
+def rows_computed(idx, first, stack):
+    """Rows one grouped product of :func:`dropless_experts` multiplies for
+    pairs ``idx`` [T, k] over ``stack`` [count, d, hidden] (row tiles
+    visited x tile rows, int32), at the kernel's row tile where it runs
+    and elsewhere at the one XLA's ``ragged_dot`` takes on a TPU.  Over
+    the held pairs it is the product's redundancy."""
+    import jax.numpy as jnp
+    T, k = idx.shape
+    count, d, hidden = stack.shape
+    _key, sizes = _held_pairs(idx, first, count)
+    tm = _gp.kernel_tile(T * k, count, d, hidden, stack.dtype) \
+        or _gp.xla_row_tile(T * k)
+    return _gp.rows_visited(sizes, tm).astype(jnp.int32)
 
 
 def held_load(idx, first, count, weight=None):

@@ -34,11 +34,13 @@ def test_run_py_rehearses_the_cell(cell, trace):
     if cell.startswith("deepseek") and trace:
         assert {"routed_held_share.dsv32", "index_selected_share.dsv32",
                 "experts_touched.dsv32", "expert_load_max.dsv32",
+                "expert_rows_computed.dsv32",
                 "batch_occupancy.dsv32", "compile_s.dsv32",
                 "loop_offcpu_us.dsv32", "emit_to_wire_us.dsv32",
                 "wire_write_us.dsv32", "writer_batch_tokens.dsv32"} <= names
     elif cell.startswith("lfm2") and trace:
         assert {"experts_touched.lfm2", "expert_load_max.lfm2",
+                "expert_rows_computed.lfm2",
                 "kv_context_mean.lfm2", "batch_occupancy.lfm2",
                 "compile_s.lfm2", "overlap_share.lfm2",
                 "loop_offcpu_us.lfm2", "emit_to_wire_us.lfm2",
@@ -46,6 +48,7 @@ def test_run_py_rehearses_the_cell(cell, trace):
     elif cell.startswith("keye") and trace:
         assert {"index_selected_share.keye", "kv_rows_read.keye",
                 "kv_context_mean.keye", "experts_touched.keye",
+                "expert_rows_computed.keye",
                 "expert_load_max.keye", "batch_occupancy.keye",
                 "compile_s.keye", "overlap_share.keye",
                 "loop_offcpu_us.keye", "emit_to_wire_us.keye",

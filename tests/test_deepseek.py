@@ -219,6 +219,9 @@ def test_prefill_then_decode_is_the_full_forward(held):
         assert counts["routed_pairs"] == 2 * moe_layers * 4
         assert (counts["routed_pairs_held"] == counts["routed_pairs"]) \
             == (held == (0, 16))
+        # on a CPU the product is ragged_dot: 8 pairs a layer are one row
+        # tile of XLA's, which every held expert with a pair would visit
+        assert counts["expert_rows_computed"] == 8 * counts["experts_touched"]
     # the step's selections, on request, are the full forward's last row
     _lg, sel = net.forward(nd.array(toks), want_selections=True)
     raw = [tuple(r._data for r in layer) for layer in caches]

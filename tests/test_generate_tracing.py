@@ -569,14 +569,14 @@ METRIC_SETS = {
     "lfm2": ("serve_lfm2", "lfm2_24b.decode_rollout",         # PR 33
              "writer_batch_tokens.dsv32",
              {"experts_touched", "expert_load_max", "kv_context_mean",
-              "decode_step_roofline"},
+              "decode_step_roofline", "expert_rows_computed"},      # PR 36
              {"index_selected_share", "routed_held_share",
               "latent_rows_read"}),
     "keye": ("serve_keye", "keye_vl2.decode_doc",             # PR 35
              "decode_step_roofline.lfm2",
              {"experts_touched", "expert_load_max", "kv_context_mean",
               "decode_step_roofline", "index_selected_share",
-              "kv_rows_read"},
+              "kv_rows_read", "expert_rows_computed"},              # PR 36
              {"routed_held_share", "latent_rows_read"}),
 }
 

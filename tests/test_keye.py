@@ -205,6 +205,10 @@ def test_a_decode_steps_selection_is_top_ks_own(ring_len):
         assert counts["kv_rows_read"] == 3 * 2 * ring_len
         assert counts["routed_pairs"] == 3 * 2 * 4
         assert 1 <= counts["expert_load_max"] <= 2
+        assert len(counts) == len(keye.STEP_COUNTERS)
+        # on a CPU the product is ragged_dot: 8 pairs a layer are one row
+        # tile of XLA's, which every expert with a pair would visit
+        assert counts["expert_rows_computed"] == 8 * counts["experts_touched"]
 
 
 def test_a_slot_that_sits_out_a_step_keeps_its_three_rings():

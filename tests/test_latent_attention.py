@@ -208,9 +208,10 @@ def test_decode_with_the_kernel_is_decode_in_the_xla_form(monkeypatch,
                          jnp.where(jnp.isfinite(b), b, 0)) < 2e-5
     got_n, want_n = (dict(zip(COUNTERS, onp.asarray(c))) for c in
                      (got[2], want[2]))
-    assert len(got[2]) == len(want[2]) == len(deepseek.STEP_COUNTERS) == 7
-    for name in COUNTERS[:-1]:
-        assert got_n[name] == want_n[name]
+    assert len(got[2]) == len(want[2]) == len(deepseek.STEP_COUNTERS) == 8
+    for name in COUNTERS:
+        if name != "latent_rows_read":
+            assert got_n[name] == want_n[name]
     layers, K = 3, net.config.index_topk
     # n_valid 4, 18 and 32 or 41: the gather reads min(n_valid, K) rows a
     # slot, the kernel the whole blocks of 8 that hold the valid rows
@@ -291,3 +292,40 @@ def test_the_kernel_compiles_for_a_v5e_at_the_benchmarks_widths(one_chip):
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < ring_bytes // 8
     assert stats.output_size_in_bytes == slots * heads * 512 * 2
+
+
+# a decode step of the three MoE cells: (pairs, held experts, d, hidden)
+@pytest.mark.parametrize("pairs,count,d,hidden", [
+    (512, 64, 2048, 1536), (512, 16, 7168, 2048), (320, 128, 2048, 768)],
+    ids=["lfm2", "deepseek_v32", "keye"])
+def test_the_grouped_product_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, pairs, count, d, hidden):
+    """Both orientations of an expert layer's products at the tiles the
+    rules give: Mosaic takes the kernel inside the default VMEM, the stack
+    goes in as it lies and the program has no temporary the size of it.
+    (Here and not in ``test_grouped_product.py``: one file describes the
+    chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops import grouped_product as gp
+    tm = gp.row_tile(pairs, count)
+
+    def shape(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for k, n in ((d, hidden), (hidden, d)):
+            compiled = jax.jit(functools.partial(
+                gp.grouped_product, tm=tm)).lower(
+                shape(pairs, k), shape(count, k, n),
+                shape(count, dt=jnp.int32)).compile()
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text
+            assert "ragged_dot_tiling" not in text     # not XLA's own
+            assert f"bf16[{count},{k},{n}]" in text
+            stats = compiled.memory_analysis()
+            assert stats.temp_size_in_bytes < count * k * n * 2 // 8
+            assert stats.output_size_in_bytes == pairs * n * 4
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()

@@ -131,6 +131,10 @@ def test_prefill_hands_the_state_over_at_the_valid_length(valid):
         assert counts["attn_valid_positions"] == 2 * int(pos.sum())
         assert 1 <= counts["expert_load_max"] <= 2
         assert 4 <= counts["experts_touched"] <= 2 * 4 * 4
+        assert len(counts) == len(lfm2.STEP_COUNTERS)
+        # on a CPU the product is ragged_dot: 8 pairs a layer are one row
+        # tile of XLA's, which every expert with a pair would visit
+        assert counts["expert_rows_computed"] == 8 * counts["experts_touched"]
 
 
 def test_a_slot_that_sits_out_a_step_keeps_its_state_and_rings():
