@@ -111,6 +111,7 @@ def enable_persistent_cache():
             # never reached where JAX_COMPILATION_CACHE_DIR placed the
             # cache before the process started: jax read it at import
             jax.config.update("jax_compilation_cache_dir", d)
+        _key_jax_cache_by_parts()
         jax.config.update("jax_enable_compilation_cache", True)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -123,6 +124,22 @@ def enable_persistent_cache():
         _state["enabled"] = True
         _state["dir"] = d
         return d
+
+
+def _key_jax_cache_by_parts():
+    """jax keys its cache by the program with debug locations stripped,
+    as :func:`fingerprint_lowered` does, and ``telemetry.part``'s names
+    live there: an executable compiled before a scope existed or moved
+    would be a warm hit and name a device trace's operations by the old
+    layout.  ``cache_key.custom_hook`` is jax's own place for an addition
+    to its key; ``telemetry.PARTS_VERSION`` goes there (and, through
+    :func:`version_stamp`, into the ProgramCache's).  Not
+    ``jax_compilation_cache_include_metadata_in_key``, which would
+    recompile for every edited line.  ``tests/test_parts.py`` holds a jax
+    upgrade to it: a bumped version must miss the cache."""
+    from jax._src import cache_key as _ck
+    from .. import telemetry
+    _ck.custom_hook = lambda: f"mx.parts={telemetry.PARTS_VERSION}"
 
 
 def _reset_jax_cache_latch():

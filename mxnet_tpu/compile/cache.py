@@ -42,12 +42,16 @@ _INDEX_FORMAT = 1
 
 
 def version_stamp():
-    """The toolchain identity a compiled artifact is only valid for."""
+    """The toolchain identity a compiled artifact is only valid for, and
+    the layout of the parts its operations are named by: the key is taken
+    with debug locations stripped, and a scope's name lives there
+    (``telemetry.PARTS_VERSION``)."""
     import jax
     import jaxlib
     from .. import __version__ as mx_version
+    from .. import telemetry
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
-            "mxnet_tpu": mx_version}
+            "mxnet_tpu": mx_version, "parts": telemetry.PARTS_VERSION}
 
 
 def _set_aside(path):

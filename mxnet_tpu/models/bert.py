@@ -16,6 +16,7 @@ from ..gluon.block import HybridBlock
 from ..gluon import nn
 from ..gluon.parameter import Parameter
 from .. import initializer as init
+from ..telemetry import part
 
 __all__ = ["BERTModel", "BERTEncoder", "TransformerEncoderLayer",
            "MultiHeadAttention", "PositionwiseFFN", "bert_base", "bert_large",
@@ -61,7 +62,8 @@ class MultiHeadAttention(HybridBlock):
         B, L, C = x.shape
         H = self._heads
         D = C // H
-        qkv = self.qkv(x)                      # (B, L, 3C)
+        with part("project"):
+            qkv = self.qkv(x)                  # (B, L, 3C)
         from .. import autograd as _ag
         drop = self._attn_drop if _ag.is_training() else 0.0
         if self._use_flash and mask is None and use_packed_attention(
@@ -70,39 +72,43 @@ class MultiHeadAttention(HybridBlock):
                 dtype=str(qkv.dtype), has_dropout=drop > 0):
             # packed path: q/k/v stay in the projection's (B*L, H*D)
             # layout — no head/seq transposes in the whole program
-            qkv2 = qkv.reshape(B * L, 3 * C)
-            out2 = flash_attention_packed_nd(
-                qkv2[:, :C], qkv2[:, C:2 * C], qkv2[:, 2 * C:], B, H,
-                causal=self._causal, valid_length=valid_length,
-                dropout=drop)
-            return self.out_proj(out2.reshape(B, L, C))
-        qkv = qkv.reshape(B, L, 3, H, D)
-        q = qkv[:, :, 0].transpose((0, 2, 1, 3))   # (B, H, L, D)
-        k = qkv[:, :, 1].transpose((0, 2, 1, 3))
-        v = qkv[:, :, 2].transpose((0, 2, 1, 3))
-        if self._use_flash and mask is None:
-            # length masks ride the fused kernel (O(L) memory) instead of a
-            # materialized (B, L, L) additive mask
-            out = flash_attention_nd(q, k, v, causal=self._causal,
-                                     valid_length=valid_length,
-                                     dropout=drop)
-        else:
-            if mask is None and valid_length is not None:
-                mask = length_mask(F, L, valid_length)
-            scores = F.batch_dot(q.reshape(B * H, L, D),
-                                 k.reshape(B * H, L, D), transpose_b=True) \
-                / math.sqrt(D)
-            if mask is not None:
-                # mask: (B, L) 1=valid
-                m = mask.reshape(B, 1, 1, L)
-                scores = scores.reshape(B, H, L, L) + (1 - m) * -1e30
-                scores = scores.reshape(B * H, L, L)
-            att = F.softmax(scores, axis=-1)
-            att = self.dropout(att)
-            out = F.batch_dot(att, v.reshape(B * H, L, D))
-            out = out.reshape(B, H, L, D)
-        out = out.transpose((0, 2, 1, 3)).reshape(B, L, C)
-        return self.out_proj(out)
+            with part("attend"):
+                qkv2 = qkv.reshape(B * L, 3 * C)
+                out2 = flash_attention_packed_nd(
+                    qkv2[:, :C], qkv2[:, C:2 * C], qkv2[:, 2 * C:], B, H,
+                    causal=self._causal, valid_length=valid_length,
+                    dropout=drop)
+            with part("project"):
+                return self.out_proj(out2.reshape(B, L, C))
+        with part("attend"):
+            qkv = qkv.reshape(B, L, 3, H, D)
+            q = qkv[:, :, 0].transpose((0, 2, 1, 3))   # (B, H, L, D)
+            k = qkv[:, :, 1].transpose((0, 2, 1, 3))
+            v = qkv[:, :, 2].transpose((0, 2, 1, 3))
+            if self._use_flash and mask is None:
+                # length masks ride the fused kernel (O(L) memory) instead
+                # of a materialized (B, L, L) additive mask
+                out = flash_attention_nd(q, k, v, causal=self._causal,
+                                         valid_length=valid_length,
+                                         dropout=drop)
+            else:
+                if mask is None and valid_length is not None:
+                    mask = length_mask(F, L, valid_length)
+                scores = F.batch_dot(q.reshape(B * H, L, D),
+                                     k.reshape(B * H, L, D),
+                                     transpose_b=True) / math.sqrt(D)
+                if mask is not None:
+                    # mask: (B, L) 1=valid
+                    m = mask.reshape(B, 1, 1, L)
+                    scores = scores.reshape(B, H, L, L) + (1 - m) * -1e30
+                    scores = scores.reshape(B * H, L, L)
+                att = F.softmax(scores, axis=-1)
+                att = self.dropout(att)
+                out = F.batch_dot(att, v.reshape(B * H, L, D))
+                out = out.reshape(B, H, L, D)
+            out = out.transpose((0, 2, 1, 3)).reshape(B, L, C)
+        with part("project"):
+            return self.out_proj(out)
 
     # -- incremental decode (docs/SERVING.md "Generative serving") ---------
     def prefill(self, x, valid_length=None):
@@ -121,21 +127,24 @@ class MultiHeadAttention(HybridBlock):
         B, L, C = x.shape
         H = self._heads
         D = C // H
-        qkv = unwrap(self.qkv(x)).reshape(B, L, 3, H, D)
-        q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))   # (B, H, L, D)
-        k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
-        v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(D)
-        mask = jnp.tril(jnp.ones((L, L), bool))[None, None]
-        if valid_length is not None:
-            vl = unwrap(valid_length).astype(jnp.int32)
-            mask = mask & (jnp.arange(L)[None, None, None, :]
-                           < vl[:, None, None, None])
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-        att = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bhqk,bhkd->bhqd", att, v)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, L, C)
-        return self.out_proj(NDArray(out)), NDArray(k), NDArray(v)
+        with part("project"):
+            qkv = unwrap(self.qkv(x)).reshape(B, L, 3, H, D)
+            q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))   # (B, H, L, D)
+            k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
+            v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
+        with part("attend"):
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(D)
+            mask = jnp.tril(jnp.ones((L, L), bool))[None, None]
+            if valid_length is not None:
+                vl = unwrap(valid_length).astype(jnp.int32)
+                mask = mask & (jnp.arange(L)[None, None, None, :]
+                               < vl[:, None, None, None])
+            scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+            att = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+            out = jnp.einsum("bhqk,bhkd->bhqd", att, v)
+            out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, L, C)
+        with part("project"):
+            return self.out_proj(NDArray(out)), NDArray(k), NDArray(v)
 
     def decode_step(self, x, k_cache, v_cache, position, active=None):
         """One token per sequence against a ring-buffer KV cache.
@@ -158,26 +167,30 @@ class MultiHeadAttention(HybridBlock):
         B, _, C = x.shape
         H = self._heads
         D = C // H
-        qkv = unwrap(self.qkv(x)).reshape(B, 3, H, D)
-        q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # (B, H, D)
+        with part("project"):
+            qkv = unwrap(self.qkv(x)).reshape(B, 3, H, D)
+            q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B, H, D)
         kc = unwrap(k_cache)
         vc = unwrap(v_cache)
         pos = unwrap(position).astype(jnp.int32)
         M = kc.shape[2]
-        write = jax.nn.one_hot(pos % M, M, dtype=kc.dtype)     # (B, M)
-        if active is not None:
-            write = write * unwrap(active).astype(kc.dtype)[:, None]
-        w = write[:, None, :, None]
-        kc = kc * (1 - w) + k_new[:, :, None, :].astype(kc.dtype) * w
-        vc = vc * (1 - w) + v_new[:, :, None, :].astype(vc.dtype) * w
-        n_valid = jnp.minimum(pos + 1, M)                      # (B,)
-        mask = jnp.arange(M)[None, :] < n_valid[:, None]       # (B, M)
-        scores = jnp.einsum("bhd,bhmd->bhm", q, kc) / math.sqrt(D)
-        scores = jnp.where(mask[:, None, :], scores.astype(jnp.float32),
-                           -1e30)
-        att = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
-        out = jnp.einsum("bhm,bhmd->bhd", att, vc).reshape(B, 1, C)
-        return self.out_proj(NDArray(out)), NDArray(kc), NDArray(vc)
+        with part("ring_write"):
+            write = jax.nn.one_hot(pos % M, M, dtype=kc.dtype)     # (B, M)
+            if active is not None:
+                write = write * unwrap(active).astype(kc.dtype)[:, None]
+            w = write[:, None, :, None]
+            kc = kc * (1 - w) + k_new[:, :, None, :].astype(kc.dtype) * w
+            vc = vc * (1 - w) + v_new[:, :, None, :].astype(vc.dtype) * w
+        with part("attend"):
+            n_valid = jnp.minimum(pos + 1, M)                      # (B,)
+            mask = jnp.arange(M)[None, :] < n_valid[:, None]       # (B, M)
+            scores = jnp.einsum("bhd,bhmd->bhm", q, kc) / math.sqrt(D)
+            scores = jnp.where(mask[:, None, :], scores.astype(jnp.float32),
+                               -1e30)
+            att = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
+            out = jnp.einsum("bhm,bhmd->bhd", att, vc).reshape(B, 1, C)
+        with part("project"):
+            return self.out_proj(NDArray(out)), NDArray(kc), NDArray(vc)
 
     hybrid_forward = None
 
@@ -283,30 +296,39 @@ class TransformerEncoderLayer(HybridBlock):
     def _res_ln(self, ln, x, inner, rate):
         return apply_residual_ln(ln, x, inner, rate, self.dropout)
 
-    def forward(self, x, mask=None, valid_length=None):
-        x = self._res_ln(self.ln1, x,
-                         self.attention(x, mask, valid_length), self._rate)
+    # A device trace files each half of the layer, its residual add and
+    # its post-norm with it, under its part: ``mx.attention`` (the
+    # attention block names ``project`` / ``attend`` / ``ring_write``
+    # inside it) and ``mx.ffn``.
+    def _ffn_part(self, x):
         # the FFN applies its own output dropout (in-kernel on the fused
         # path), so the second glue runs with rate 0
-        x = self._res_ln(self.ln2, x, self.ffn(x), 0.0)
-        return x
+        with part("ffn"):
+            return self._res_ln(self.ln2, x, self.ffn(x), 0.0)
+
+    def forward(self, x, mask=None, valid_length=None):
+        with part("attention"):
+            x = self._res_ln(self.ln1, x,
+                             self.attention(x, mask, valid_length),
+                             self._rate)
+        return self._ffn_part(x)
 
     # -- incremental decode ------------------------------------------------
     def prefill(self, x, valid_length=None):
         """Prompt pass: returns ``(out, k, v)`` — the attention K/V of
         this layer for the caller's cache (docs/SERVING.md)."""
-        att, k, v = self.attention.prefill(x, valid_length)
-        x = self._res_ln(self.ln1, x, att, self._rate)
-        x = self._res_ln(self.ln2, x, self.ffn(x), 0.0)
-        return x, k, v
+        with part("attention"):
+            att, k, v = self.attention.prefill(x, valid_length)
+            x = self._res_ln(self.ln1, x, att, self._rate)
+        return self._ffn_part(x), k, v
 
     def decode_step(self, x, k_cache, v_cache, position, active=None):
         """One cached decode hop; returns ``(out, k_cache', v_cache')``."""
-        att, kc, vc = self.attention.decode_step(x, k_cache, v_cache,
-                                                 position, active=active)
-        x = self._res_ln(self.ln1, x, att, self._rate)
-        x = self._res_ln(self.ln2, x, self.ffn(x), 0.0)
-        return x, kc, vc
+        with part("attention"):
+            att, kc, vc = self.attention.decode_step(
+                x, k_cache, v_cache, position, active=active)
+            x = self._res_ln(self.ln1, x, att, self._rate)
+        return self._ffn_part(x), kc, vc
 
     hybrid_forward = None
 
@@ -343,7 +365,8 @@ class BERTEncoder(HybridBlock):
     def forward(self, x, mask=None, valid_length=None):
         # position add + LN happen in BERTModel (HF/gluon-nlp embedding
         # order); the encoder owns dropout + the layer stack
-        x = self.dropout(x)
+        with part("embed"):
+            x = self.dropout(x)
         for layer in self.layers._children.values():
             x = layer(x, mask, valid_length)
         return x
@@ -407,20 +430,27 @@ class BERTModel(HybridBlock):
     def forward(self, inputs, token_types=None, valid_length=None,
                 masked_positions=None):
         from .. import ndarray as F
-        seq = self.word_embed(inputs)
-        if token_types is not None:
-            seq = seq + self.token_type_embed(token_types)
-        # BERT order (HF + gluon-nlp): word + token_type + position, THEN
-        # the embedding LayerNorm — required for pretrained-weight
-        # compatibility (tools/convert_weights.py)
-        L = seq.shape[1]
-        seq = seq + self.encoder.position_weight.data()[:L] \
-            .reshape(1, L, self._units)
-        seq = self.embed_ln(seq)
+        with part("embed"):
+            seq = self.word_embed(inputs)
+            if token_types is not None:
+                seq = seq + self.token_type_embed(token_types)
+            # BERT order (HF + gluon-nlp): word + token_type + position,
+            # THEN the embedding LayerNorm — required for pretrained-weight
+            # compatibility (tools/convert_weights.py)
+            L = seq.shape[1]
+            seq = seq + self.encoder.position_weight.data()[:L] \
+                .reshape(1, L, self._units)
+            seq = self.embed_ln(seq)
         # length masking rides the fused attention kernels directly (no
         # materialized (B, L) -> (B, L, L) additive mask; reference builds
         # one in gluon-nlp BERTModel._encode_sequence)
         out = self.encoder(seq, None, valid_length)
+        with part("head"):
+            return self._heads(F, out, masked_positions)
+
+    def _heads(self, F, out, masked_positions):
+        """The pooler, the NSP classifier and the weight-tied MLM decoder
+        on the encoder's output: ``mx.head`` in a device trace."""
         results = [out]
         if self.pooler is not None:
             pooled = self.pooler(out[:, 0])
@@ -465,15 +495,16 @@ class BERTPretrainingLoss(HybridBlock):
                 nsp_labels):
         from .. import ndarray as F
         B, M, V = mlm_logits.shape
-        # fused CE: fp32 math internally, no (B*M, V) log-softmax ever
-        # materialized — pass the logits in their storage dtype (bf16)
-        per_tok = F.softmax_ce_loss(mlm_logits.reshape(B * M, V),
-                                    mlm_labels.reshape(-1),
-                                    mlm_weights.reshape(-1))
-        denom = F.sum(mlm_weights) + 1e-6
-        mlm = F.sum(per_tok) / denom
-        nsp = F.mean(self.nsp_loss(nsp_logits, nsp_labels))
-        return mlm + nsp
+        with part("loss"):
+            # fused CE: fp32 math internally, no (B*M, V) log-softmax ever
+            # materialized — pass the logits in their storage dtype (bf16)
+            per_tok = F.softmax_ce_loss(mlm_logits.reshape(B * M, V),
+                                        mlm_labels.reshape(-1),
+                                        mlm_weights.reshape(-1))
+            denom = F.sum(mlm_weights) + 1e-6
+            mlm = F.sum(per_tok) / denom
+            nsp = F.mean(self.nsp_loss(nsp_logits, nsp_labels))
+            return mlm + nsp
 
     hybrid_forward = None
 

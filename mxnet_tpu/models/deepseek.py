@@ -44,7 +44,7 @@ from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from ..ops import latent_ring_attention as _lra
 from .parts import (LANES, FanInNormal as _FanInNormal, index_scores,
-                    layer_norm as _layernorm, matmul as _mm,
+                    layer_norm as _layernorm, matmul as _mm, part,
                     rms_norm as _rms, rope as _rope, selection_mask,
                     sub_weights as _sub, topk_mask)
 
@@ -155,54 +155,60 @@ def _ring_row(c, latent):
         latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)])
 
 
-def _attn_inputs(c, w, x, pos):
-    """Everything attention derives from the normed input ``x`` [B, L, d]
-    at positions ``pos`` [B, L]: ``(q_nope [B,L,H,n], q_rope [B,L,H,r],
-    latent row [B,L,kv+r], q^I [B,L,Hi,Di], k^I [B,L,Di], w [B,L,Hi]
-    float32)``."""
+def _attn_inputs(c, w, h, pos):
+    """Everything attention derives from the stream ``h`` [B, L, d] (its
+    pre-norm is here) at positions ``pos`` [B, L]: ``(q_nope [B,L,H,n],
+    q_rope [B,L,H,r], latent row [B,L,kv+r], q^I [B,L,Hi,Di], k^I
+    [B,L,Di], w [B,L,Hi] float32)``: the first three are attention's
+    projections, the last three the indexer's."""
     jnp = _jnp()
     f32 = jnp.float32
-    B, L, _ = x.shape
+    B, L, _ = h.shape
     H, n, r = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
     kvr, Hi, Di = c.kv_lora_rank, c.index_n_heads, c.index_head_dim
     eps = c.rms_norm_eps
-    ang = pos.astype(f32)[..., None] * jnp.asarray(yarn_inv_freq(c))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)                   # [B, L, r/2]
-    hcos, hsin = cos[:, :, None], sin[:, :, None]
-    c_q = _rms(_mm(x, w["wq_a"]), w["q_norm"], eps)
-    q = _mm(c_q, w["wq_b"]).reshape(B, L, H, n + r)
-    q_nope, q_rope = q[..., :n], _rope(q[..., n:], hcos, hsin, True)
-    kv = _mm(x, w["wkv_a"])
-    latent = jnp.concatenate(
-        [_rms(kv[..., :kvr], w["kv_norm"], eps),
-         _rope(kv[..., kvr:], cos, sin, True)], axis=-1)
-    qi = _mm(c_q, w["idx_wq_b"]).reshape(B, L, Hi, Di)
-    qi = jnp.concatenate([_rope(qi[..., :r], hcos, hsin, False),
-                          qi[..., r:]], axis=-1)
-    ki = _layernorm(_mm(x, w["idx_wk"]), w["idx_knorm_w"], w["idx_knorm_b"],
-                    eps)
-    ki = jnp.concatenate([_rope(ki[..., :r], cos, sin, False), ki[..., r:]],
-                         axis=-1)
-    wi = jnp.dot(x, w["idx_w"], preferred_element_type=f32) \
-        * (Hi ** -0.5 * Di ** -0.5)
+    with part("attention"), part("project"):
+        x = _rms(h, w["attn_norm"], eps)
+        ang = pos.astype(f32)[..., None] * jnp.asarray(yarn_inv_freq(c))
+        cos, sin = jnp.cos(ang), jnp.sin(ang)               # [B, L, r/2]
+        hcos, hsin = cos[:, :, None], sin[:, :, None]
+        c_q = _rms(_mm(x, w["wq_a"]), w["q_norm"], eps)
+        q = _mm(c_q, w["wq_b"]).reshape(B, L, H, n + r)
+        q_nope, q_rope = q[..., :n], _rope(q[..., n:], hcos, hsin, True)
+        kv = _mm(x, w["wkv_a"])
+        latent = jnp.concatenate(
+            [_rms(kv[..., :kvr], w["kv_norm"], eps),
+             _rope(kv[..., kvr:], cos, sin, True)], axis=-1)
+    with part("indexer"), part("project"):
+        qi = _mm(c_q, w["idx_wq_b"]).reshape(B, L, Hi, Di)
+        qi = jnp.concatenate([_rope(qi[..., :r], hcos, hsin, False),
+                              qi[..., r:]], axis=-1)
+        ki = _layernorm(_mm(x, w["idx_wk"]), w["idx_knorm_w"],
+                        w["idx_knorm_b"], eps)
+        ki = jnp.concatenate([_rope(ki[..., :r], cos, sin, False),
+                              ki[..., r:]], axis=-1)
+        wi = jnp.dot(x, w["idx_w"], preferred_element_type=f32) \
+            * (Hi ** -0.5 * Di ** -0.5)
     return q_nope, q_rope, latent, qi, ki, wi
 
 
-def _attn_full(c, w, x, pos, index_topk, want_mask):
+def _attn_full(c, w, h, pos, index_topk, want_mask):
     """Attention over a whole sequence in the expanded form, in blocks of
     queries so that neither the heads' scores nor the indexer's are ever
-    whole.  Returns ``(out [B,L,d], latent rows, k^I, mask or None, index
-    scores or None)``: the last two on request, the scores only where the
-    sequence is longer than ``index_topk`` (below it nothing is scored)."""
+    whole.  Returns ``(the stream ``h`` [B,L,d] with attention's output
+    added, latent rows, k^I, mask or None, index scores or None)``: the
+    last two on request, the scores only where the sequence is longer
+    than ``index_topk`` (below it nothing is scored)."""
     import jax
     jnp = _jnp()
     f32 = jnp.float32
-    B, L, _ = x.shape
+    B, L, _ = h.shape
     H, n, dv = c.num_attention_heads, c.qk_nope_head_dim, c.v_head_dim
     kvr = c.kv_lora_rank
-    q_nope, q_rope, latent, qi, ki, wi = _attn_inputs(c, w, x, pos)
-    kvb = _mm(latent[..., :kvr], w["wkv_b"]).reshape(B, L, H, n + dv)
-    k_nope, v, k_rope = kvb[..., :n], kvb[..., n:], latent[..., kvr:]
+    q_nope, q_rope, latent, qi, ki, wi = _attn_inputs(c, w, h, pos)
+    with part("attention"), part("project"):
+        kvb = _mm(latent[..., :kvr], w["wkv_b"]).reshape(B, L, H, n + dv)
+        k_nope, v, k_rope = kvb[..., :n], kvb[..., n:], latent[..., kvr:]
     scale = softmax_scale(c)
     bq = math.gcd(L, QUERY_BLOCK)
     sparse = L > index_topk
@@ -210,44 +216,58 @@ def _attn_full(c, w, x, pos, index_topk, want_mask):
     def block(i):
         def rows(a):
             return jax.lax.dynamic_slice_in_dim(a, i * bq, bq, axis=1)
-        causal = jnp.arange(L)[None, :] <= (i * bq + jnp.arange(bq))[:, None]
-        mask = jnp.broadcast_to(causal[None], (B, bq, L))
-        scores = None
-        if sparse:
-            scores = index_scores(rows(qi), rows(wi), ki)
-            mask = topk_mask(scores, mask, index_topk)
-        s = jnp.einsum("bqhn,bkhn->bhqk", rows(q_nope), k_nope,
-                       preferred_element_type=f32) \
-            + jnp.einsum("bqhr,bkr->bhqk", rows(q_rope), k_rope,
-                         preferred_element_type=f32)
-        s = jnp.where(mask[:, None], s * scale, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        o = jnp.einsum("bhqk,bkhv->bqhv", p, v, preferred_element_type=f32)
-        o = o.astype(x.dtype).reshape(B, bq, H * dv)
+        with part("indexer"):
+            causal = jnp.arange(L)[None, :] \
+                <= (i * bq + jnp.arange(bq))[:, None]
+            mask = jnp.broadcast_to(causal[None], (B, bq, L))
+            scores = None
+            if sparse:
+                scores = index_scores(rows(qi), rows(wi), ki)
+                mask = topk_mask(scores, mask, index_topk)
+        with part("attention"), part("attend"):
+            s = jnp.einsum("bqhn,bkhn->bhqk", rows(q_nope), k_nope,
+                           preferred_element_type=f32) \
+                + jnp.einsum("bqhr,bkr->bhqk", rows(q_rope), k_rope,
+                             preferred_element_type=f32)
+            s = jnp.where(mask[:, None], s * scale, -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            o = jnp.einsum("bhqk,bkhv->bqhv", p, v,
+                           preferred_element_type=f32)
+            o = o.astype(h.dtype).reshape(B, bq, H * dv)
         return (o, mask, scores) if want_mask else (o, None, None)
 
     def whole(a):
         return None if a is None else jnp.moveaxis(a, 0, 1).reshape(B, L, L)
-    o, mask, scores = jax.lax.map(block, jnp.arange(L // bq))
-    o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * dv)
-    return _mm(o, w["wo"]), latent, ki, whole(mask), whole(scores)
+    # the loop itself is attention's: its body names its own parts
+    with part("attention"), part("attend"):
+        o, mask, scores = jax.lax.map(block, jnp.arange(L // bq))
+    with part("attention"), part("project"):
+        o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * dv)
+        h = h + _mm(o, w["wo"])
+    with part("indexer"):
+        mask, scores = whole(mask), whole(scores)
+    return h, latent, ki, mask, scores
 
 
-def _ffn(c, w, i, x, weight=None):
-    """``(y, idx, scores, load)`` of layer ``i``'s feed-forward on raw
-    [B, L, d]: the last three None in a dense layer."""
-    x2d = x.reshape(-1, x.shape[-1])
-    if i < c.first_k_dense_replace:
-        y = _moe.swiglu(x2d, w["ffn_w1"], w["ffn_w3"], w["ffn_w2"])
-        return y.astype(x.dtype).reshape(x.shape), None, None, None
-    first, count = c.held
-    y, idx, _gates, scores = _moe.dropless_moe(
-        x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
-        n_group=c.n_group, topk_group=c.topk_group,
-        route_scale=c.routed_scaling_factor)
-    load = _jnp().append(_moe.held_load(idx, first, count, weight),
-                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
-    return y.astype(x.dtype).reshape(x.shape), idx, scores, load
+def _ffn(c, w, i, h, weight=None):
+    """``(h + y, idx, scores, load)`` of layer ``i``'s feed-forward on the
+    stream ``h`` [B, L, d], its pre-norm and its residual add with it: the
+    last three None in a dense layer."""
+    dense = i < c.first_k_dense_replace
+    with part("ffn" if dense else "experts"):
+        x2d = _rms(h, w["ffn_norm"], c.rms_norm_eps).reshape(-1, h.shape[-1])
+        if dense:
+            y = _moe.swiglu(x2d, w["ffn_w1"], w["ffn_w3"], w["ffn_w2"])
+            return h + y.astype(h.dtype).reshape(h.shape), None, None, None
+        first, count = c.held
+        y, idx, _gates, scores = _moe.dropless_moe(
+            x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
+            n_group=c.n_group, topk_group=c.topk_group,
+            route_scale=c.routed_scaling_factor)
+        load = _jnp().append(
+            _moe.held_load(idx, first, count, weight),
+            _moe.rows_computed(idx, first, w["ffn.held_w1"]))
+        return h + y.astype(h.dtype).reshape(h.shape), idx, scores, load
 
 
 def run_full(c, w, tokens, index_topk=None, want_selections=False):
@@ -260,28 +280,29 @@ def run_full(c, w, tokens, index_topk=None, want_selections=False):
     jnp = _jnp()
     index_topk = c.index_topk if index_topk is None else index_topk
     B, L = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
-    x = w["embed"][tokens]
+    with part("embed"):
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
+        x = w["embed"][tokens]
     caches, sel = [], {"positions": [], "index_scores": [], "experts": [],
                        "router_scores": []}
     for i in range(c.num_hidden_layers):
         lw = _sub(w, f"layers.{i}.")
-        a, latent, ki, mask, scores_i = _attn_full(
-            c, lw, _rms(x, lw["attn_norm"], c.rms_norm_eps), pos, index_topk,
-            want_selections)
-        x = x + a
-        y, idx, scores, _load = _ffn(
-            c, lw, i, _rms(x, lw["ffn_norm"], c.rms_norm_eps))
-        x = x + y
-        caches.append((_ring_row(c, latent).astype(c.cache_dtype),
-                       ki.astype(c.cache_dtype)))
+        x, latent, ki, mask, scores_i = _attn_full(
+            c, lw, x, pos, index_topk, want_selections)
+        x, idx, scores, _load = _ffn(c, lw, i, x)
+        with part("attention"), part("ring_write"):
+            latent = _ring_row(c, latent).astype(c.cache_dtype)
+        with part("indexer"), part("ring_write"):
+            ki = ki.astype(c.cache_dtype)
+        caches.append((latent, ki))
         sel["positions"].append(mask)
         sel["index_scores"].append(scores_i)
         if idx is not None:
             sel["experts"].append(idx)
             sel["router_scores"].append(scores)
-    logits = jnp.dot(_rms(x, w["norm"], c.rms_norm_eps), w["head"],
-                     preferred_element_type=jnp.float32)
+    with part("head"):
+        logits = jnp.dot(_rms(x, w["norm"], c.rms_norm_eps), w["head"],
+                         preferred_element_type=jnp.float32)
     return logits, caches, (sel if want_selections else None)
 
 
@@ -323,7 +344,8 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     pos = pos.astype(jnp.int32)
     act = jnp.ones((S,), jnp.int32) if active is None \
         else (active > 0).astype(jnp.int32)
-    x = w["embed"][tok][:, None]                             # [S, 1, d]
+    with part("embed"):
+        x = w["embed"][tok][:, None]                         # [S, 1, d]
     new, slots = [], jnp.arange(S)
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     sel = {"positions": [], "index_scores": [], "experts": [],
@@ -331,64 +353,75 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     for i in range(c.num_hidden_layers):
         lw = _sub(w, f"layers.{i}.")
         q_nope, q_rope, latent, qi, ki, wi = _attn_inputs(
-            c, lw, _rms(x, lw["attn_norm"], c.rms_norm_eps), pos[:, None])
+            c, lw, x, pos[:, None])
         ring_l, ring_i = caches[i]
         M = ring_l.shape[1]
         at = jnp.where(act > 0, pos % M, M)      # M: out of range, dropped
-        ring_l = ring_l.at[slots, at].set(
-            _ring_row(c, latent[:, 0]).astype(ring_l.dtype), mode="drop")
-        ring_i = ring_i.at[slots, at].set(ki[:, 0].astype(ring_i.dtype),
-                                          mode="drop")
-        n_valid = jnp.minimum(pos + 1, M)
-        valid = jnp.arange(M)[None, :] < n_valid[:, None]
-        scores = index_scores(qi, wi, ring_i.astype(x.dtype))[:, 0]  # [S, M]
-        K = min(index_topk, M)
-        vals, chosen = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), K)
-        keep = vals > -jnp.inf
-        block = _lra.kernel_block(S, H, kvr, c.qk_rope_head_dim, M,
-                                  ring_l.shape[2], x.dtype, ring_l.dtype)
-        if want_selections or block is not None:
-            mask = selection_mask(chosen, keep, M)
+        with part("attention"), part("ring_write"):
+            ring_l = ring_l.at[slots, at].set(
+                _ring_row(c, latent[:, 0]).astype(ring_l.dtype), mode="drop")
+        with part("indexer"):
+            with part("ring_write"):
+                ring_i = ring_i.at[slots, at].set(
+                    ki[:, 0].astype(ring_i.dtype), mode="drop")
+            n_valid = jnp.minimum(pos + 1, M)
+            valid = jnp.arange(M)[None, :] < n_valid[:, None]
+            scores = index_scores(qi, wi, ring_i.astype(x.dtype))[:, 0]
+            K = min(index_topk, M)
+            with part("top_k"):
+                vals, chosen = jax.lax.top_k(
+                    jnp.where(valid, scores, -jnp.inf), K)           # [S, K]
+                keep = vals > -jnp.inf
+            block = _lra.kernel_block(S, H, kvr, c.qk_rope_head_dim, M,
+                                      ring_l.shape[2], x.dtype, ring_l.dtype)
+            if want_selections or block is not None:
+                mask = selection_mask(chosen, keep, M)
         if want_selections:
             sel["positions"].append(mask)
         sel["index_scores"].append(scores)
-        wkb = lw["wkv_b"].reshape(kvr, H, n + dv)
-        q_abs = jnp.einsum("shn,chn->shc", q_nope[:, 0], wkb[..., :n],
-                           preferred_element_type=f32).astype(x.dtype)
-        if block is not None:
-            o = _lra.latent_ring_attention(q_abs, q_rope[:, 0], ring_l, mask,
-                                           n_valid, scale, block=block)
-            rows_read = _lra.rows_visited(n_valid, block)
-        else:
-            rows = jnp.take_along_axis(ring_l, chosen[:, :, None],
-                                       axis=1).astype(x.dtype)
-            s = jnp.einsum("shc,skc->shk", q_abs, rows[..., :kvr],
-                           preferred_element_type=f32) \
-                + jnp.einsum("shr,skr->shk", q_rope[:, 0],
-                             rows[..., kvr:row], preferred_element_type=f32)
-            s = jnp.where(keep[:, None], s * scale, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            o = jnp.einsum("shk,skc->shc", p, rows[..., :kvr],
-                           preferred_element_type=f32).astype(x.dtype)
-            rows_read = jnp.minimum(n_valid, K)
-        o = jnp.einsum("shc,chv->shv", o, wkb[..., n:],
-                       preferred_element_type=f32).astype(x.dtype)
-        x = x + _mm(o.reshape(S, 1, H * dv), lw["wo"])
-        y, idx, router_scores, load = _ffn(
-            c, lw, i, _rms(x, lw["ffn_norm"], c.rms_norm_eps), weight=act)
-        x = x + y
+        with part("attention"):
+            with part("project"):
+                wkb = lw["wkv_b"].reshape(kvr, H, n + dv)
+                q_abs = jnp.einsum("shn,chn->shc", q_nope[:, 0], wkb[..., :n],
+                                   preferred_element_type=f32).astype(x.dtype)
+            with part("attend"):
+                if block is not None:
+                    o = _lra.latent_ring_attention(
+                        q_abs, q_rope[:, 0], ring_l, mask, n_valid, scale,
+                        block=block)
+                    rows_read = _lra.rows_visited(n_valid, block)
+                else:
+                    rows = jnp.take_along_axis(ring_l, chosen[:, :, None],
+                                               axis=1).astype(x.dtype)
+                    s = jnp.einsum("shc,skc->shk", q_abs, rows[..., :kvr],
+                                   preferred_element_type=f32) \
+                        + jnp.einsum("shr,skr->shk", q_rope[:, 0],
+                                     rows[..., kvr:row],
+                                     preferred_element_type=f32)
+                    s = jnp.where(keep[:, None], s * scale, -1e30)
+                    p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+                    o = jnp.einsum("shk,skc->shc", p, rows[..., :kvr],
+                                   preferred_element_type=f32).astype(x.dtype)
+                    rows_read = jnp.minimum(n_valid, K)
+            with part("project"):
+                o = jnp.einsum("shc,chv->shv", o, wkb[..., n:],
+                               preferred_element_type=f32).astype(x.dtype)
+                x = x + _mm(o.reshape(S, 1, H * dv), lw["wo"])
+            seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
+                                     rows_read])).sum(axis=1).astype(jnp.int32)
+            counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2])
+        x, idx, router_scores, load = _ffn(c, lw, i, x, weight=act)
         new.append((ring_l, ring_i))
         if idx is not None:
             sel["experts"].append(idx)
             sel["router_scores"].append(router_scores)
-        seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
-                                 rows_read])).sum(axis=1).astype(jnp.int32)
-        counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2])
         if load is not None:
-            counts = counts.at[2:5].add(load[:3])
-            counts = counts.at[5].max(load[3]).at[7].add(load[4])
-    logits = jnp.dot(_rms(x[:, 0], w["norm"], c.rms_norm_eps), w["head"],
-                     preferred_element_type=f32)
+            with part("experts"):
+                counts = counts.at[2:5].add(load[:3])
+                counts = counts.at[5].max(load[3]).at[7].add(load[4])
+    with part("head"):
+        logits = jnp.dot(_rms(x[:, 0], w["norm"], c.rms_norm_eps), w["head"],
+                         preferred_element_type=f32)
     if want_selections:
         return logits, new, counts, sel
     return logits, new, counts

@@ -50,7 +50,7 @@ from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from .parts import (LANES, DrawnBias as _DrawnBias, FanInNormal,
                     index_scores, layer_norm as _layernorm,
-                    matmul as _mm, rms_norm as _rms, rope as _rope,
+                    matmul as _mm, part, rms_norm as _rms, rope as _rope,
                     sectioned_angles, selection_mask, sub_weights as _sub)
 
 __all__ = ["KeyeVL2LM", "KEYE_PUBLISHED", "tiny_keye", "trunk", "head",
@@ -113,39 +113,45 @@ def _text_positions(pos):
     return jnp.broadcast_to(pos[None], (3,) + pos.shape)
 
 
-def _inputs(c, w, u, pos3):
-    """Everything attention derives from the normed input ``u`` [..., d]
-    at three-axis positions ``pos3`` [3, ...]: ``(q [..., H, D], k and v
-    [..., KV, D], q^I [..., Hi, Di], k^I [..., Di], w [..., Hi]
-    float32)``; q and k normed per head, then rotated; the indexer's pair
-    rotated over the whole head with the sections halved."""
+def _inputs(c, w, h, pos3):
+    """Everything attention derives from the stream ``h`` [..., d] (its
+    pre-norm is here) at three-axis positions ``pos3`` [3, ...]: ``(q
+    [..., H, D], k and v [..., KV, D], q^I [..., Hi, Di], k^I [..., Di], w
+    [..., Hi] float32)``; q and k normed per head, then rotated; the
+    indexer's pair rotated over the whole head with the sections halved.
+    The first three are attention's projections, the last three the
+    indexer's."""
     jnp = _jnp()
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     Hi, Di = c.index_n_heads, c.index_head_dim
     eps = c.rms_norm_eps
-    lead = u.shape[:-1]
-    ang = sectioned_angles(pos3, D, c.rope_theta, c.mrope_section)
-    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    q = _rms(_mm(u, w["wq"]).reshape(lead + (H, D)), w["q_norm"], eps)
-    k = _rms(_mm(u, w["wk"]).reshape(lead + (KV, D)), w["k_norm"], eps)
-    v = _mm(u, w["wv"]).reshape(lead + (KV, D))
-    q, k = _rope(q, cos, sin, False), _rope(k, cos, sin, False)
-    iang = sectioned_angles(pos3, Di, c.rope_theta, c.index_section)
-    icos, isin = jnp.cos(iang), jnp.sin(iang)
-    qi = _rope(_mm(u, w["idx_wq"]).reshape(lead + (Hi, Di)),
-               icos[..., None, :], isin[..., None, :], False)
-    ki = _rope(_layernorm(_mm(u, w["idx_wk"]), w["idx_knorm_w"],
-                          w["idx_knorm_b"], eps), icos, isin, False)
-    wi = jnp.dot(u, w["idx_w"], preferred_element_type=jnp.float32) \
-        * (Hi ** -0.5 * Di ** -0.5)
+    lead = h.shape[:-1]
+    with part("attention"), part("project"):
+        u = _rms(h, w["attn_norm"], eps)
+        ang = sectioned_angles(pos3, D, c.rope_theta, c.mrope_section)
+        cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+        q = _rms(_mm(u, w["wq"]).reshape(lead + (H, D)), w["q_norm"], eps)
+        k = _rms(_mm(u, w["wk"]).reshape(lead + (KV, D)), w["k_norm"], eps)
+        v = _mm(u, w["wv"]).reshape(lead + (KV, D))
+        q, k = _rope(q, cos, sin, False), _rope(k, cos, sin, False)
+    with part("indexer"), part("project"):
+        iang = sectioned_angles(pos3, Di, c.rope_theta, c.index_section)
+        icos, isin = jnp.cos(iang), jnp.sin(iang)
+        qi = _rope(_mm(u, w["idx_wq"]).reshape(lead + (Hi, Di)),
+                   icos[..., None, :], isin[..., None, :], False)
+        ki = _rope(_layernorm(_mm(u, w["idx_wk"]), w["idx_knorm_w"],
+                              w["idx_knorm_b"], eps), icos, isin, False)
+        wi = jnp.dot(u, w["idx_w"], preferred_element_type=jnp.float32) \
+            * (Hi ** -0.5 * Di ** -0.5)
     return q, k, v, qi, ki, wi
 
 
-def _attn_full(c, w, u, pos3, index_topk, want_sel):
+def _attn_full(c, w, h, pos3, index_topk, want_sel):
     """Attention over a whole sequence [B, L, d], in blocks of
     ``q_chunk_size`` queries so that neither the heads' scores nor the
-    indexer's are ever whole.  Returns ``(out [B, L, d], k rows [B, L,
-    KV * D], v rows, k^I [B, L, Di], positions, index scores)``: the last
+    indexer's are ever whole.  Returns ``(the stream ``h`` with its output
+    added, k rows [B, L, KV * D], v rows, k^I [B, L, Di], positions, index
+    scores)``: the last
     two on request and only where the sequence is longer than
     ``index_topk`` (below it nothing is scored), the selection as
     ``top_k`` gave it, ``[B, L, K]`` indices (-1 where a query has fewer
@@ -153,10 +159,10 @@ def _attn_full(c, w, u, pos3, index_topk, want_sel):
     import jax
     jnp = _jnp()
     f32 = jnp.float32
-    B, L, _ = u.shape
+    B, L, _ = h.shape
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     G = H // KV
-    q, k, v, qi, ki, wi = _inputs(c, w, u, pos3)
+    q, k, v, qi, ki, wi = _inputs(c, w, h, pos3)
     q = q.reshape(B, L, KV, G, D)
     bq = math.gcd(L, c.query_block)
     K = index_topk
@@ -165,43 +171,58 @@ def _attn_full(c, w, u, pos3, index_topk, want_sel):
     def block(i):
         def rows(a):
             return jax.lax.dynamic_slice_in_dim(a, i * bq, bq, axis=1)
-        causal = jnp.arange(L)[None, :] <= (i * bq + jnp.arange(bq))[:, None]
-        mask = jnp.broadcast_to(causal[None], (B, bq, L))
-        chosen = vals = None
-        if sparse:
-            scores = index_scores(rows(qi), rows(wi), ki)       # [B, bq, L]
-            vals, chosen = jax.lax.top_k(
-                jnp.where(mask, scores, -jnp.inf), K)
-            keep = vals > -jnp.inf
-            mask = selection_mask(chosen.reshape(B * bq, K),
-                                  keep.reshape(B * bq, K), L
-                                  ).reshape(B, bq, L)
-            chosen = jnp.where(keep, chosen, -1)
-        s = jnp.einsum("bqkgd,bmkd->bkgqm", rows(q), k,
-                       preferred_element_type=f32) * D ** -0.5
-        p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30), axis=-1)
-        o = jnp.einsum("bkgqm,bmkd->bqkgd", p.astype(v.dtype), v,
-                       preferred_element_type=f32)
-        o = o.astype(u.dtype).reshape(B, bq, H * D)
+        with part("indexer"):
+            causal = jnp.arange(L)[None, :] \
+                <= (i * bq + jnp.arange(bq))[:, None]
+            mask = jnp.broadcast_to(causal[None], (B, bq, L))
+            chosen = vals = None
+            if sparse:
+                scores = index_scores(rows(qi), rows(wi), ki)   # [B, bq, L]
+                with part("top_k"):
+                    vals, chosen = jax.lax.top_k(
+                        jnp.where(mask, scores, -jnp.inf), K)
+                    keep = vals > -jnp.inf
+                mask = selection_mask(chosen.reshape(B * bq, K),
+                                      keep.reshape(B * bq, K), L
+                                      ).reshape(B, bq, L)
+                chosen = jnp.where(keep, chosen, -1)
+        with part("attention"), part("attend"):
+            s = jnp.einsum("bqkgd,bmkd->bkgqm", rows(q), k,
+                           preferred_element_type=f32) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30),
+                               axis=-1)
+            o = jnp.einsum("bkgqm,bmkd->bqkgd", p.astype(v.dtype), v,
+                           preferred_element_type=f32)
+            o = o.astype(h.dtype).reshape(B, bq, H * D)
         return (o, chosen, vals) if want_sel and sparse else (o, None, None)
 
     def whole(a):
         return None if a is None else jnp.moveaxis(a, 0, 1).reshape(B, L, K)
-    o, chosen, vals = jax.lax.map(block, jnp.arange(L // bq))
-    o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * D)
-    return _mm(o, w["wo"]), k.reshape(B, L, KV * D), \
-        v.reshape(B, L, KV * D), ki, whole(chosen), whole(vals)
+    # the loop itself is attention's: its body names its own parts
+    with part("attention"), part("attend"):
+        o, chosen, vals = jax.lax.map(block, jnp.arange(L // bq))
+    with part("attention"), part("project"):
+        o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * D)
+        h = h + _mm(o, w["wo"])
+    with part("indexer"):
+        chosen, vals = whole(chosen), whole(vals)
+    return h, k.reshape(B, L, KV * D), v.reshape(B, L, KV * D), ki, \
+        chosen, vals
 
 
-def _ffn(c, w, x, weight=None):
-    """``(y, idx, scores, load)`` of a layer's experts on raw [..., d]."""
+def _ffn(c, w, h, weight=None):
+    """``(h + y, idx, scores, load)`` of a layer's experts on the stream
+    ``h`` [..., d], their pre-norm and their residual add with them."""
     first, count = c.held
-    y, idx, _gates, scores = _moe.dropless_moe(
-        x.reshape(-1, x.shape[-1]), _sub(w, "ffn."),
-        k=c.num_experts_per_tok, first=first, scoring="softmax")
-    load = _jnp().append(_moe.held_load(idx, first, count, weight),
-                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
-    return y.astype(x.dtype).reshape(x.shape), idx, scores, load
+    with part("experts"):
+        x = _rms(h, w["ffn_norm"], c.rms_norm_eps)
+        y, idx, _gates, scores = _moe.dropless_moe(
+            x.reshape(-1, x.shape[-1]), _sub(w, "ffn."),
+            k=c.num_experts_per_tok, first=first, scoring="softmax")
+        load = _jnp().append(
+            _moe.held_load(idx, first, count, weight),
+            _moe.rows_computed(idx, first, w["ffn.held_w1"]))
+        return h + y.astype(h.dtype).reshape(h.shape), idx, scores, load
 
 
 def _index_row(c, ki):
@@ -225,23 +246,23 @@ def trunk(c, w, tokens, positions=None, index_topk=None,
     jnp = _jnp()
     index_topk = c.index_topk if index_topk is None else index_topk
     B, L = tokens.shape
-    pos3 = _text_positions(jnp.broadcast_to(
-        jnp.arange(L, dtype=jnp.int32)[None], (B, L))) \
-        if positions is None else positions.astype(jnp.int32)
-    x = w["embed"][tokens]
+    with part("embed"):
+        pos3 = _text_positions(jnp.broadcast_to(
+            jnp.arange(L, dtype=jnp.int32)[None], (B, L))) \
+            if positions is None else positions.astype(jnp.int32)
+        x = w["embed"][tokens]
     caches, sel = [], {"positions": [], "index_scores": [], "experts": [],
                        "router_scores": []}
     for i in range(c.num_hidden_layers):
         lw = _sub(w, f"layers.{i}.")
-        a, k, v, ki, chosen, vals = _attn_full(
-            c, lw, _rms(x, lw["attn_norm"], c.rms_norm_eps), pos3,
-            index_topk, want_selections)
-        x = x + a
-        y, idx, scores, _load = _ffn(
-            c, lw, _rms(x, lw["ffn_norm"], c.rms_norm_eps))
-        x = x + y
-        caches.append((k.astype(c.cache_dtype), v.astype(c.cache_dtype),
-                       _index_row(c, ki).astype(c.cache_dtype)))
+        x, k, v, ki, chosen, vals = _attn_full(
+            c, lw, x, pos3, index_topk, want_selections)
+        x, idx, scores, _load = _ffn(c, lw, x)
+        with part("attention"), part("ring_write"):
+            k, v = k.astype(c.cache_dtype), v.astype(c.cache_dtype)
+        with part("indexer"), part("ring_write"):
+            ki = _index_row(c, ki).astype(c.cache_dtype)
+        caches.append((k, v, ki))
         sel["positions"].append(chosen)
         sel["index_scores"].append(vals)
         sel["experts"].append(idx)
@@ -252,8 +273,9 @@ def trunk(c, w, tokens, positions=None, index_topk=None,
 def head(c, w, x):
     """Logits [..., V] float32 of the stream ``x`` [..., d]."""
     jnp = _jnp()
-    return jnp.dot(_rms(x, w["norm"], c.rms_norm_eps), w["head"],
-                   preferred_element_type=jnp.float32)
+    with part("head"):
+        return jnp.dot(_rms(x, w["norm"], c.rms_norm_eps), w["head"],
+                       preferred_element_type=jnp.float32)
 
 
 def run_full(c, w, tokens, positions=None, index_topk=None,
@@ -266,15 +288,16 @@ def run_full(c, w, tokens, positions=None, index_topk=None,
     x, caches, sel = trunk(c, w, tokens, positions, index_topk,
                            want_selections)
     if last is not None:
-        x = jnp.take_along_axis(
-            x, (last.reshape(-1, 1, 1) - 1).astype(jnp.int32), axis=1)
+        with part("head"):
+            x = jnp.take_along_axis(
+                x, (last.reshape(-1, 1, 1) - 1).astype(jnp.int32), axis=1)
     return head(c, w, x), caches, sel
 
 
-def _attend(c, q, ring_k, ring_v, chosen, keep):
+def _attend(c, q, ring_k, ring_v, mask):
     """Every query head of ``q`` [S, H, D] over its slot's rows of the
-    rings [S, M, KV * D] that the selection ``chosen`` / ``keep`` [S, K]
-    names, as a mask over the whole ring.  The heads stay side by side on
+    rings [S, M, KV * D] that the selection names, as a ``mask`` [S, M]
+    over the whole ring.  The heads stay side by side on
     the row's lanes, as in ``lfm2.py``: a head's query is laid into its
     key head's ``D`` of the row's numbers and the rest left zero, so that
     scores and values are products over whole rows and the rings are never
@@ -285,7 +308,6 @@ def _attend(c, q, ring_k, ring_v, chosen, keep):
     S, M, W = ring_k.shape
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     G = H // KV
-    mask = selection_mask(chosen, keep, M)
     # [S, KV, G, KV', D]: head (kv, g) holds its query where kv' == kv
     own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
     wide = (q.reshape(S, KV, G, 1, D) * own).reshape(S, H, W)
@@ -322,48 +344,58 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     pos3 = _text_positions(pos)
     act = jnp.ones((S,), jnp.int32) if active is None \
         else (active > 0).astype(jnp.int32)
-    x = w["embed"][tok]                                      # [S, d]
+    with part("embed"):
+        x = w["embed"][tok]                                  # [S, d]
     new, slots = [], jnp.arange(S)
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     sel = {"positions": [], "index_scores": [], "experts": [],
            "router_scores": []}
     for i in range(c.num_hidden_layers):
         lw = _sub(w, f"layers.{i}.")
-        u = _rms(x, lw["attn_norm"], c.rms_norm_eps)
-        q, k, v, qi, ki, wi = _inputs(c, lw, u, pos3)
+        q, k, v, qi, ki, wi = _inputs(c, lw, x, pos3)
         ring_k, ring_v, ring_i = caches[i]
         M, W = ring_k.shape[1:]
         at = jnp.where(act > 0, pos % M, M)      # M: out of range, dropped
-        ring_k = ring_k.at[slots, at].set(
-            k.reshape(S, W).astype(ring_k.dtype), mode="drop")
-        ring_v = ring_v.at[slots, at].set(
-            v.reshape(S, W).astype(ring_v.dtype), mode="drop")
-        ring_i = ring_i.at[slots, at].set(
-            _index_row(c, ki).astype(ring_i.dtype), mode="drop")
-        n_valid = jnp.minimum(pos + 1, M)
-        valid = jnp.arange(M)[None, :] < n_valid[:, None]
-        # the query's heads padded as the keys are: products over whole rows
-        qi = jnp.pad(qi, ((0, 0), (0, 0), (0, c.index_stride - Di)))
-        scores = index_scores(qi[:, None], wi[:, None],
-                              ring_i.astype(u.dtype))[:, 0]      # [S, M]
-        K = min(index_topk, M)
-        vals, chosen = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), K)
-        keep = vals > -jnp.inf
-        o, rows_read = _attend(c, q, ring_k, ring_v, chosen, keep)
-        x = x + _mm(o.astype(u.dtype), lw["wo"])
-        y, idx, router_scores, load = _ffn(
-            c, lw, _rms(x, lw["ffn_norm"], c.rms_norm_eps), weight=act)
-        x = x + y
+        with part("attention"), part("ring_write"):
+            ring_k = ring_k.at[slots, at].set(
+                k.reshape(S, W).astype(ring_k.dtype), mode="drop")
+            ring_v = ring_v.at[slots, at].set(
+                v.reshape(S, W).astype(ring_v.dtype), mode="drop")
+        with part("indexer"):
+            with part("ring_write"):
+                ring_i = ring_i.at[slots, at].set(
+                    _index_row(c, ki).astype(ring_i.dtype), mode="drop")
+            n_valid = jnp.minimum(pos + 1, M)
+            valid = jnp.arange(M)[None, :] < n_valid[:, None]
+            # the query's heads padded as the keys are: products over
+            # whole rows
+            qi = jnp.pad(qi, ((0, 0), (0, 0), (0, c.index_stride - Di)))
+            scores = index_scores(qi[:, None], wi[:, None],
+                                  ring_i.astype(x.dtype))[:, 0]  # [S, M]
+            K = min(index_topk, M)
+            with part("top_k"):
+                vals, chosen = jax.lax.top_k(
+                    jnp.where(valid, scores, -jnp.inf), K)
+                keep = vals > -jnp.inf
+            mask = selection_mask(chosen, keep, M)
+        with part("attention"):
+            with part("attend"):
+                o, rows_read = _attend(c, q, ring_k, ring_v, mask)
+            with part("project"):
+                x = x + _mm(o.astype(x.dtype), lw["wo"])
+        x, idx, router_scores, load = _ffn(c, lw, x, weight=act)
         new.append((ring_k, ring_v, ring_i))
         sel["positions"].append(jnp.where(keep, chosen, -1))
         sel["index_scores"].append(vals)
         sel["experts"].append(idx)
         sel["router_scores"].append(router_scores)
-        seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
-                                 rows_read, n_valid])).sum(axis=1)
-        counts = counts.at[:4].add(seen.astype(jnp.int32))
-        counts = counts.at[4].add(load[0]).at[5].add(load[2])
-        counts = counts.at[6].max(load[3]).at[7].add(load[4])
+        with part("indexer"):
+            seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
+                                     rows_read, n_valid])).sum(axis=1)
+            counts = counts.at[:4].add(seen.astype(jnp.int32))
+        with part("experts"):
+            counts = counts.at[4].add(load[0]).at[5].add(load[2])
+            counts = counts.at[6].max(load[3]).at[7].add(load[4])
     logits = head(c, w, x)
     if want_selections:
         return logits, new, counts, sel

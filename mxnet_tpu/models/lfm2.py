@@ -42,7 +42,8 @@ from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from .parts import (DrawnBias as _DrawnBias, FanInNormal, matmul as _mm,
-                    rms_norm as _rms, rope as _rope, sub_weights as _sub)
+                    part, rms_norm as _rms, rope as _rope,
+                    sub_weights as _sub)
 
 __all__ = ["LFM2MoeLM", "LFM2_PUBLISHED", "tiny_lfm2", "run_full", "decode",
            "STEP_COUNTERS"]
@@ -107,46 +108,55 @@ def _angles(c, pos):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def _qkv(c, w, x, pos):
-    """q [..., H, D], k and v [..., KV, D] of the normed input ``x``
-    [..., d] at ``pos`` [...]: q and k normed per head, then rotated."""
+def _qkv(c, w, h, pos):
+    """q [..., H, D], k and v [..., KV, D] of the stream ``h`` [..., d]
+    (its pre-norm is here) at ``pos`` [...]: q and k normed per head, then
+    rotated."""
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    lead = x.shape[:-1]
-    cos, sin = _angles(c, pos)
-    q = _rms(_mm(x, w["wq"]).reshape(lead + (H, D)), w["q_norm"], c.norm_eps)
-    k = _rms(_mm(x, w["wk"]).reshape(lead + (KV, D)), w["k_norm"],
-             c.norm_eps)
-    v = _mm(x, w["wv"]).reshape(lead + (KV, D))
-    return _rope(q, cos, sin, False), _rope(k, cos, sin, False), v
+    lead = h.shape[:-1]
+    with part("project"):
+        x = _rms(h, w["op_norm"], c.norm_eps)
+        cos, sin = _angles(c, pos)
+        q = _rms(_mm(x, w["wq"]).reshape(lead + (H, D)), w["q_norm"],
+                 c.norm_eps)
+        k = _rms(_mm(x, w["wk"]).reshape(lead + (KV, D)), w["k_norm"],
+                 c.norm_eps)
+        v = _mm(x, w["wv"]).reshape(lead + (KV, D))
+        return _rope(q, cos, sin, False), _rope(k, cos, sin, False), v
 
 
-def _attn_full(c, w, x, pos):
+def _attn_full(c, w, h, pos):
     """Causal attention over a whole sequence [B, L, d], in blocks of
-    queries.  Returns ``(out [B, L, d], k rows [B, L, KV * D], v rows)``:
-    the rows as the rings store them."""
+    queries.  Returns ``(the stream ``h`` with its output added, k rows
+    [B, L, KV * D], v rows)``: the rows as the rings store them."""
     import jax
     jnp = _jnp()
     f32 = jnp.float32
-    B, L, _ = x.shape
+    B, L, _ = h.shape
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     G = H // KV
-    q, k, v = _qkv(c, w, x, pos)
-    q = q.reshape(B, L, KV, G, D)
-    bq = math.gcd(L, QUERY_BLOCK)
+    with part("attention"):
+        q, k, v = _qkv(c, w, h, pos)
+        q = q.reshape(B, L, KV, G, D)
+        bq = math.gcd(L, QUERY_BLOCK)
 
-    def block(i):
-        rows = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=1)
-        causal = jnp.arange(L)[None, :] <= (i * bq + jnp.arange(bq))[:, None]
-        s = jnp.einsum("bqkgd,bmkd->bkgqm", rows, k,
-                       preferred_element_type=f32) * D ** -0.5
-        p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
-        o = jnp.einsum("bkgqm,bmkd->bqkgd", p.astype(v.dtype), v,
-                       preferred_element_type=f32)
-        return o.astype(x.dtype).reshape(B, bq, H * D)
+        def block(i):
+            rows = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=1)
+            causal = jnp.arange(L)[None, :] \
+                <= (i * bq + jnp.arange(bq))[:, None]
+            s = jnp.einsum("bqkgd,bmkd->bkgqm", rows, k,
+                           preferred_element_type=f32) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+            o = jnp.einsum("bkgqm,bmkd->bqkgd", p.astype(v.dtype), v,
+                           preferred_element_type=f32)
+            return o.astype(h.dtype).reshape(B, bq, H * D)
 
-    o = jax.lax.map(block, jnp.arange(L // bq))
-    o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * D)
-    return _mm(o, w["wo"]), k.reshape(B, L, KV * D), v.reshape(B, L, KV * D)
+        with part("attend"):
+            o = jax.lax.map(block, jnp.arange(L // bq))
+        with part("project"):
+            o = jnp.moveaxis(o, 0, 1).reshape(B, L, H * D)
+            return h + _mm(o, w["wo"]), k.reshape(B, L, KV * D), \
+                v.reshape(B, L, KV * D)
 
 
 def _conv_taps(w, rows):
@@ -160,46 +170,53 @@ def _conv_taps(w, rows):
     return z
 
 
-def _conv_full(c, w, x, valid_length):
+def _conv_full(c, w, h, valid_length):
     """The gated short convolution over a whole sequence [B, L, d].
-    Returns ``(out [B, L, d], state [B, K, d])``: the last ``K`` rows of
-    ``B * x`` before position ``valid_length`` [B] (zeros before the
-    first)."""
+    Returns ``(the stream ``h`` with its output added, state [B, K, d])``:
+    the last ``K`` rows of ``B * x`` before position ``valid_length`` [B]
+    (zeros before the first)."""
     import jax
     jnp = _jnp()
     K = c.conv_L_cache
-    b, gate, xx = jnp.split(_mm(x, w["conv_in"]), 3, axis=-1)
-    bx = b * xx
-    L = bx.shape[1]
-    # K zeros in front: row t of bx is row K + t
-    padded = jnp.pad(bx, ((0, 0), (K, 0), (0, 0)))
-    z = _conv_taps(w, [padded[:, 1 + j:1 + j + L] for j in range(K)])
-    out = _mm(gate * z.astype(x.dtype), w["conv_out"])
-    state = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-        rows, n, K, axis=0))(padded, valid_length)
-    return out, state
+    with part("conv"):
+        x = _rms(h, w["op_norm"], c.norm_eps)
+        b, gate, xx = jnp.split(_mm(x, w["conv_in"]), 3, axis=-1)
+        bx = b * xx
+        L = bx.shape[1]
+        # K zeros in front: row t of bx is row K + t
+        padded = jnp.pad(bx, ((0, 0), (K, 0), (0, 0)))
+        z = _conv_taps(w, [padded[:, 1 + j:1 + j + L] for j in range(K)])
+        out = _mm(gate * z.astype(x.dtype), w["conv_out"])
+        state = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+            rows, n, K, axis=0))(padded, valid_length)
+        return h + out, state
 
 
-def _ffn(c, w, i, x, weight=None):
-    """``(y, idx, scores, load)`` of layer ``i``'s feed-forward on raw
-    [..., d]: the last three None in a dense layer."""
-    x2d = x.reshape(-1, x.shape[-1])
-    if i < c.num_dense_layers:
-        y = _moe.swiglu(x2d, w["ffn_w1"], w["ffn_w3"], w["ffn_w2"])
-        return y.astype(x.dtype).reshape(x.shape), None, None, None
-    first, count = c.held
-    y, idx, _gates, scores = _moe.dropless_moe(
-        x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
-        route_scale=c.routed_scaling_factor, norm_eps=GATE_NORM_EPS)
-    load = _jnp().append(_moe.held_load(idx, first, count, weight),
-                         _moe.rows_computed(idx, first, w["ffn.held_w1"]))
-    return y.astype(x.dtype).reshape(x.shape), idx, scores, load
+def _ffn(c, w, i, h, weight=None):
+    """``(h + y, idx, scores, load)`` of layer ``i``'s feed-forward on the
+    stream ``h`` [..., d], its pre-norm and its residual add with it: the
+    last three None in a dense layer."""
+    dense = i < c.num_dense_layers
+    with part("ffn" if dense else "experts"):
+        x2d = _rms(h, w["ffn_norm"], c.norm_eps).reshape(-1, h.shape[-1])
+        if dense:
+            y = _moe.swiglu(x2d, w["ffn_w1"], w["ffn_w3"], w["ffn_w2"])
+            return h + y.astype(h.dtype).reshape(h.shape), None, None, None
+        first, count = c.held
+        y, idx, _gates, scores = _moe.dropless_moe(
+            x2d, _sub(w, "ffn."), k=c.num_experts_per_tok, first=first,
+            route_scale=c.routed_scaling_factor, norm_eps=GATE_NORM_EPS)
+        load = _jnp().append(
+            _moe.held_load(idx, first, count, weight),
+            _moe.rows_computed(idx, first, w["ffn.held_w1"]))
+        return h + y.astype(h.dtype).reshape(h.shape), idx, scores, load
 
 
 def _head(c, w, x):
     jnp = _jnp()
-    return jnp.einsum("...d,vd->...v", _rms(x, w["norm"], c.norm_eps),
-                      w["embed"], preferred_element_type=jnp.float32)
+    with part("head"):
+        return jnp.einsum("...d,vd->...v", _rms(x, w["norm"], c.norm_eps),
+                          w["embed"], preferred_element_type=jnp.float32)
 
 
 def run_full(c, w, tokens, valid_length=None, want_selections=False):
@@ -212,39 +229,41 @@ def run_full(c, w, tokens, valid_length=None, want_selections=False):
     layer]}``."""
     jnp = _jnp()
     B, L = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
-    vl = jnp.full((B,), L, jnp.int32) if valid_length is None \
-        else valid_length.reshape(B).astype(jnp.int32)
-    x = w["embed"][tokens]
+    with part("embed"):
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
+        vl = jnp.full((B,), L, jnp.int32) if valid_length is None \
+            else valid_length.reshape(B).astype(jnp.int32)
+        x = w["embed"][tokens]
     caches, sel = [], {"experts": [], "router_scores": []}
     for i, kind in enumerate(c.layer_types):
         lw = _sub(w, f"layers.{i}.")
-        u = _rms(x, lw["op_norm"], c.norm_eps)
         if kind == "conv":
-            a, state = _conv_full(c, lw, u, vl)
-            caches.append((state.astype(c.cache_dtype),))
+            x, state = _conv_full(c, lw, x, vl)
+            with part("conv"):
+                caches.append((state.astype(c.cache_dtype),))
         else:
-            a, k, v = _attn_full(c, lw, u, pos)
-            caches.append((k.astype(c.cache_dtype), v.astype(c.cache_dtype)))
-        x = x + a
-        y, idx, scores, _load = _ffn(
-            c, lw, i, _rms(x, lw["ffn_norm"], c.norm_eps))
-        x = x + y
+            x, k, v = _attn_full(c, lw, x, pos)
+            with part("attention"), part("ring_write"):
+                caches.append((k.astype(c.cache_dtype),
+                               v.astype(c.cache_dtype)))
+        x, idx, scores, _load = _ffn(c, lw, i, x)
         if idx is not None:
             sel["experts"].append(idx)
             sel["router_scores"].append(scores)
     return _head(c, w, x), caches, (sel if want_selections else None)
 
 
-def _attn_step(c, w, u, ring_k, ring_v, pos, act):
-    """One position a slot against the rings [S, M, KV * D]: the new rows
+def _attn_step(c, w, h, ring_k, ring_v, pos, act):
+    """One position a slot of the stream ``h`` [S, d] (attention's
+    pre-norm and its residual add are here) against the rings
+    [S, M, KV * D]: the new rows
     land at ``pos % M`` of the active slots (one scatter a ring), and
     every query head attends over its slot's valid positions.  The heads
     stay side by side on the row's lanes: a head's query is laid into its
     key head's 64 of the row's 512 numbers and the rest left zero, so that
     scores and values are products over whole rows and the ring is never
     reshaped (a ring split by heads has 64 numbers on the lanes, and the
-    chip then copies it whole).  Returns ``(out [S, H * D], rings,
+    chip then copies it whole).  Returns ``(h + out [S, d], rings,
     positions read [S])``."""
     import jax
     jnp = _jnp()
@@ -252,42 +271,51 @@ def _attn_step(c, w, u, ring_k, ring_v, pos, act):
     S, M, W = ring_k.shape
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     G = H // KV
-    q, k, v = _qkv(c, w, u, pos)
-    at = jnp.where(act > 0, pos % M, M)          # M: out of range, dropped
-    slots = jnp.arange(S)
-    ring_k = ring_k.at[slots, at].set(
-        k.reshape(S, W).astype(ring_k.dtype), mode="drop")
-    ring_v = ring_v.at[slots, at].set(
-        v.reshape(S, W).astype(ring_v.dtype), mode="drop")
-    n_valid = jnp.minimum(pos + 1, M)
-    valid = jnp.arange(M)[None, :] < n_valid[:, None]
-    # [S, KV, G, KV', D]: head (kv, g) holds its query where kv' == kv
-    own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
-    wide = (q.reshape(S, KV, G, 1, D) * own).reshape(S, H, W)
-    s = jnp.einsum("shw,smw->shm", wide, ring_k.astype(u.dtype),
-                   preferred_element_type=f32) * D ** -0.5
-    p = jax.nn.softmax(jnp.where(valid[:, None], s, -1e30), axis=-1)
-    o = jnp.einsum("shm,smw->shw", p.astype(u.dtype), ring_v.astype(u.dtype),
-                   preferred_element_type=f32)
-    o = (o.reshape(S, KV, G, KV, D) * own.astype(f32)).sum(3)
-    return _mm(o.astype(u.dtype).reshape(S, H * D), w["wo"]), \
-        ring_k, ring_v, n_valid
+    with part("attention"):
+        q, k, v = _qkv(c, w, h, pos)
+        with part("ring_write"):
+            at = jnp.where(act > 0, pos % M, M)  # M: out of range, dropped
+            slots = jnp.arange(S)
+            ring_k = ring_k.at[slots, at].set(
+                k.reshape(S, W).astype(ring_k.dtype), mode="drop")
+            ring_v = ring_v.at[slots, at].set(
+                v.reshape(S, W).astype(ring_v.dtype), mode="drop")
+        with part("attend"):
+            n_valid = jnp.minimum(pos + 1, M)
+            valid = jnp.arange(M)[None, :] < n_valid[:, None]
+            # [S, KV, G, KV', D]: head (kv, g) holds its query where
+            # kv' == kv
+            own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
+            wide = (q.reshape(S, KV, G, 1, D) * own).reshape(S, H, W)
+            s = jnp.einsum("shw,smw->shm", wide, ring_k.astype(h.dtype),
+                           preferred_element_type=f32) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(valid[:, None], s, -1e30), axis=-1)
+            o = jnp.einsum("shm,smw->shw", p.astype(h.dtype),
+                           ring_v.astype(h.dtype),
+                           preferred_element_type=f32)
+            o = (o.reshape(S, KV, G, KV, D) * own.astype(f32)).sum(3)
+        with part("project"):
+            return h + _mm(o.astype(h.dtype).reshape(S, H * D), w["wo"]), \
+                ring_k, ring_v, n_valid
 
 
-def _conv_step(c, w, u, state, act):
-    """One position a slot against the state [S, K, d]: shifted by one
-    row with this step's ``B * x`` appended, in the active slots; the
-    others keep theirs.  Returns ``(out [S, d], state)``."""
+def _conv_step(c, w, h, state, act):
+    """One position a slot of the stream ``h`` [S, d] (the convolution's
+    pre-norm and its residual add are here) against the state [S, K, d]:
+    shifted by one row with this step's ``B * x`` appended, in the active
+    slots; the others keep theirs.  Returns ``(h + out [S, d], state)``."""
     jnp = _jnp()
     K = c.conv_L_cache
-    b, gate, xx = jnp.split(_mm(u, w["conv_in"]), 3, axis=-1)
-    bx = b * xx
-    z = _conv_taps(w, [state[:, j].astype(u.dtype) for j in range(1, K)]
-                   + [bx])
-    shifted = jnp.concatenate(
-        [state[:, 1:], bx[:, None].astype(state.dtype)], axis=1)
-    state = jnp.where(act[:, None, None] > 0, shifted, state)
-    return _mm(gate * z.astype(u.dtype), w["conv_out"]), state
+    with part("conv"):
+        u = _rms(h, w["op_norm"], c.norm_eps)
+        b, gate, xx = jnp.split(_mm(u, w["conv_in"]), 3, axis=-1)
+        bx = b * xx
+        z = _conv_taps(w, [state[:, j].astype(u.dtype) for j in range(1, K)]
+                       + [bx])
+        shifted = jnp.concatenate(
+            [state[:, 1:], bx[:, None].astype(state.dtype)], axis=1)
+        state = jnp.where(act[:, None, None] > 0, shifted, state)
+        return h + _mm(gate * z.astype(u.dtype), w["conv_out"]), state
 
 
 def decode(c, w, tok, caches, pos, active=None, want_selections=False):
@@ -301,31 +329,30 @@ def decode(c, w, tok, caches, pos, active=None, want_selections=False):
     pos = pos.astype(jnp.int32)
     act = jnp.ones((S,), jnp.int32) if active is None \
         else (active > 0).astype(jnp.int32)
-    x = w["embed"][tok]                                      # [S, d]
+    with part("embed"):
+        x = w["embed"][tok]                                  # [S, d]
     new = []
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     sel = {"experts": [], "router_scores": []}
     for i, kind in enumerate(c.layer_types):
         lw = _sub(w, f"layers.{i}.")
-        u = _rms(x, lw["op_norm"], c.norm_eps)
         if kind == "conv":
-            a, state = _conv_step(c, lw, u, caches[i][0], act)
+            x, state = _conv_step(c, lw, x, caches[i][0], act)
             new.append((state,))
         else:
-            a, ring_k, ring_v, n_valid = _attn_step(c, lw, u, *caches[i],
+            x, ring_k, ring_v, n_valid = _attn_step(c, lw, x, *caches[i],
                                                     pos, act)
             new.append((ring_k, ring_v))
-            counts = counts.at[3].add((act * n_valid).sum())
-        x = x + a
-        y, idx, scores, load = _ffn(
-            c, lw, i, _rms(x, lw["ffn_norm"], c.norm_eps), weight=act)
-        x = x + y
+            with part("attention"):
+                counts = counts.at[3].add((act * n_valid).sum())
+        x, idx, scores, load = _ffn(c, lw, i, x, weight=act)
         if idx is not None:
             sel["experts"].append(idx)
             sel["router_scores"].append(scores)
-            counts = counts.at[0].add(load[0])
-            counts = counts.at[1].add(load[2])
-            counts = counts.at[2].max(load[3]).at[4].add(load[4])
+            with part("experts"):
+                counts = counts.at[0].add(load[0])
+                counts = counts.at[1].add(load[2])
+                counts = counts.at[2].max(load[3]).at[4].add(load[4])
     logits = _head(c, w, x)
     if want_selections:
         return logits, new, counts, sel

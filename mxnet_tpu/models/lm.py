@@ -18,6 +18,7 @@ from __future__ import annotations
 from ..gluon.block import HybridBlock
 from ..gluon import nn
 from .. import initializer as init
+from ..telemetry import part
 from .bert import BERTEncoder
 
 __all__ = ["TransformerLM", "tiny_lm"]
@@ -68,15 +69,20 @@ class TransformerLM(HybridBlock):
 
     def _embed(self, tokens):
         """Token + learned-position embedding for a left-aligned batch."""
-        x = self.embed(tokens)
-        L = x.shape[1]
-        return x + self.encoder.position_weight.data()[:L] \
-            .reshape(1, L, self._units)
+        with part("embed"):
+            x = self.embed(tokens)
+            L = x.shape[1]
+            return x + self.encoder.position_weight.data()[:L] \
+                .reshape(1, L, self._units)
+
+    def _head(self, x):
+        with part("head"):
+            return self.proj(x)
 
     def forward(self, tokens, valid_length=None):
         """Full causal forward: (B, L) ids -> (B, L, vocab) logits."""
         x = self._embed(tokens)
-        return self.proj(self.encoder(x, None, valid_length))
+        return self._head(self.encoder(x, None, valid_length))
 
     hybrid_forward = None
 
@@ -86,28 +92,28 @@ class TransformerLM(HybridBlock):
         with one (B, H, L, D) K/V pair per layer for the caller's cache."""
         x = self._embed(tokens)
         out, kvs = self.encoder.prefill(x, valid_length)
-        return self.proj(out), kvs
+        return self._head(out), kvs
 
     def decode_step(self, tokens, caches, position, active=None):
         """One token per sequence: (B,) ids at (B,) positions against the
         per-layer ring caches.  Returns ``(logits (B, vocab), caches')``."""
         import jax.numpy as jnp
         from ..ndarray.ndarray import NDArray, unwrap
-        tok = unwrap(tokens).reshape(-1)
-        B = tok.shape[0]
-        pos = unwrap(position).astype(jnp.int32)
-        x = unwrap(self.embed(NDArray(tok.reshape(B, 1))))
-        # positions past the learned table clamp to its last row — the
-        # ring buffer (not this table) is the true context bound
-        pw = unwrap(self.encoder.position_weight.data())
-        penc = jnp.take(pw, jnp.clip(pos, 0, self._max_length - 1),
-                        axis=0)[:, None, :]
-        x = NDArray(x + penc.astype(x.dtype))
+        with part("embed"):
+            tok = unwrap(tokens).reshape(-1)
+            B = tok.shape[0]
+            pos = unwrap(position).astype(jnp.int32)
+            x = unwrap(self.embed(NDArray(tok.reshape(B, 1))))
+            # positions past the learned table clamp to its last row — the
+            # ring buffer (not this table) is the true context bound
+            pw = unwrap(self.encoder.position_weight.data())
+            penc = jnp.take(pw, jnp.clip(pos, 0, self._max_length - 1),
+                            axis=0)[:, None, :]
+            x = NDArray(x + penc.astype(x.dtype))
         out, caches = self.encoder.decode_step(x, caches, position,
                                                active=active)
-        logits = self.proj(out)
-        from ..ndarray.ndarray import unwrap as _u
-        return NDArray(_u(logits)[:, 0]), caches
+        with part("head"):
+            return NDArray(unwrap(self.proj(out))[:, 0]), caches
 
 
 def tiny_lm(vocab_size=128, **kwargs):

@@ -13,10 +13,11 @@ import numpy as onp
 
 from .. import initializer as init
 from .. import random as _random
+from ..telemetry import part
 
 __all__ = ["rms_norm", "layer_norm", "matmul", "rope", "sectioned_angles",
            "index_scores", "topk_mask", "selection_mask", "sub_weights",
-           "FanInNormal", "DrawnBias", "LANES"]
+           "FanInNormal", "DrawnBias", "LANES", "part"]
 
 # the chip's lane width: a ring whose row is a multiple of it lies with the
 # rows contiguous, and :func:`selection_mask` splits a position by it
@@ -87,9 +88,10 @@ def index_scores(qi, wi, ki):
     product through the matrix unit would round ``w`` and the ReLUs."""
     import jax
     import jax.numpy as jnp
-    s = jnp.einsum("bqhd,bkd->bqhk", qi, ki,
-                   preferred_element_type=jnp.float32)
-    return (jax.nn.relu(s) * wi[..., None]).sum(axis=2)
+    with part("scores"):
+        s = jnp.einsum("bqhd,bkd->bqhk", qi, ki,
+                       preferred_element_type=jnp.float32)
+        return (jax.nn.relu(s) * wi[..., None]).sum(axis=2)
 
 
 def topk_mask(scores, valid, k):
@@ -99,9 +101,11 @@ def topk_mask(scores, valid, k):
     import jax.numpy as jnp
     if k >= scores.shape[-1]:
         return valid
-    masked = jnp.where(valid, scores, -jnp.inf)
-    kth = jax.lax.top_k(masked, k)[0][..., -1:]
-    return valid & (masked >= kth)
+    with part("top_k"):
+        masked = jnp.where(valid, scores, -jnp.inf)
+        kth = jax.lax.top_k(masked, k)[0][..., -1:]
+    with part("mask"):
+        return valid & (masked >= kth)
 
 
 def selection_mask(chosen, keep, ring_len):
@@ -117,17 +121,18 @@ def selection_mask(chosen, keep, ring_len):
     6,144 on a v5e: PERF.md, PR 34)."""
     import jax.numpy as jnp
     S = chosen.shape[0]
-    if ring_len % LANES:
-        return jnp.zeros((S, ring_len), bool).at[
-            jnp.arange(S)[:, None], chosen].set(keep)
-    high = jnp.where(keep, chosen // LANES, -1)[:, None, :]
-    low = (chosen % LANES)[:, :, None]
-    high = (high == jnp.arange(ring_len // LANES)[None, :, None])
-    low = (low == jnp.arange(LANES)[None, None, :])
-    hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
-                      low.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
-    return (hits > 0).reshape(S, ring_len)
+    with part("mask"):
+        if ring_len % LANES:
+            return jnp.zeros((S, ring_len), bool).at[
+                jnp.arange(S)[:, None], chosen].set(keep)
+        high = jnp.where(keep, chosen // LANES, -1)[:, None, :]
+        low = (chosen % LANES)[:, :, None]
+        high = (high == jnp.arange(ring_len // LANES)[None, :, None])
+        low = (low == jnp.arange(LANES)[None, None, :])
+        hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
+                          low.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+        return (hits > 0).reshape(S, ring_len)
 
 
 def sub_weights(w, prefix):

@@ -649,48 +649,55 @@ class SPMDTrainer:
             # matmuls). The barrier materializes grads first; the extra
             # read is epsilon next to the matmul win.
             grads = jax.lax.optimization_barrier(grads)
-            finite = jnp.asarray(True)
-            if guard:
-                finite = jnp.isfinite(loss)
+            # the update, the non-finite skip and LAMB's norms: one part
+            # of the step in a device trace.  The forward's operations
+            # carry jvp(...) on their name stack and the backward's
+            # transpose(jvp(...)), beside the model's own parts
+            with _telemetry_mod.part("optimizer"):
+                finite = jnp.asarray(True)
+                if guard:
+                    finite = jnp.isfinite(loss)
+                    for i in range(n):
+                        if trainables[i]:
+                            finite = jnp.logical_and(
+                                finite, jnp.all(jnp.isfinite(grads[i])))
+                new_params, new_states = [], []
                 for i in range(n):
                     if trainables[i]:
-                        finite = jnp.logical_and(
-                            finite, jnp.all(jnp.isfinite(grads[i])))
-            new_params, new_states = [], []
-            for i in range(n):
-                if trainables[i]:
-                    g = grads[i] * rescale.astype(grads[i].dtype)
-                    w, s = optimizer.step_multi_precision(
-                        param_raws[i], g, states[i], lr * lr_mults[i],
-                        optimizer.wd * wd_mults[i], t=t, mp=mp_flags[i])
-                    if self._zero == 2 and grad_sh[i] is not None:
-                        # each replica updates only its 1/N weight shard;
-                        # the replicated out_sharding then all-gathers the
-                        # fresh params in-step (one collective per block)
-                        w = jax.lax.with_sharding_constraint(w, grad_sh[i])
-                    if guard:
-                        # skip-step select: old values win when any
-                        # grad/loss is non-finite (a no-op update fused
-                        # into the same program — zero extra dispatches)
-                        w = jnp.where(finite, w, param_raws[i])
-                        s = jax.tree_util.tree_map(
-                            lambda sn, so: jnp.where(finite, sn, so),
-                            s, states[i])
-                else:
-                    w, s = param_raws[i], states[i]
-                new_params.append(w)
-                new_states.append(s)
-            if guard and aux_box and aux_box[0]:
-                # aux (BN running stats) must skip too: without this a
-                # NaN batch leaves weights intact but poisons mean/var,
-                # making every later forward non-finite anyway
-                pos = {id(p): i for i, p in enumerate(ps)}
-                aux = [jnp.where(finite, a, param_raws[pos[id(p)]])
-                       if id(p) in pos else a
-                       for p, a in zip(aux_box[0], aux)]
+                        g = grads[i] * rescale.astype(grads[i].dtype)
+                        w, s = optimizer.step_multi_precision(
+                            param_raws[i], g, states[i], lr * lr_mults[i],
+                            optimizer.wd * wd_mults[i], t=t, mp=mp_flags[i])
+                        if self._zero == 2 and grad_sh[i] is not None:
+                            # each replica updates only its 1/N weight shard;
+                            # the replicated out_sharding then all-gathers the
+                            # fresh params in-step (one collective per block)
+                            w = jax.lax.with_sharding_constraint(w, grad_sh[i])
+                        if guard:
+                            # skip-step select: old values win when any
+                            # grad/loss is non-finite (a no-op update fused
+                            # into the same program — zero extra dispatches)
+                            w = jnp.where(finite, w, param_raws[i])
+                            s = jax.tree_util.tree_map(
+                                lambda sn, so: jnp.where(finite, sn, so),
+                                s, states[i])
+                    else:
+                        w, s = param_raws[i], states[i]
+                    new_params.append(w)
+                    new_states.append(s)
+                if guard and aux_box and aux_box[0]:
+                    # aux (BN running stats) must skip too: without this a
+                    # NaN batch leaves weights intact but poisons mean/var,
+                    # making every later forward non-finite anyway
+                    pos = {id(p): i for i, p in enumerate(ps)}
+                    aux = [jnp.where(finite, a, param_raws[pos[id(p)]])
+                           if id(p) in pos else a
+                           for p, a in zip(aux_box[0], aux)]
             if diag_fn is not None:
-                diag = diag_fn(loss, rescale, *param_raws, *grads,
-                               *new_params)
+                with _telemetry_mod.part("optimizer"), \
+                        _telemetry_mod.part("health"):
+                    diag = diag_fn(loss, rescale, *param_raws, *grads,
+                                   *new_params)
                 return loss, new_params, new_states, aux, finite, diag
             return loss, new_params, new_states, aux, finite
 

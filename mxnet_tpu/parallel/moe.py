@@ -35,6 +35,7 @@ from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
 from .. import initializer as init
 from ..ops import grouped_product as _gp
+from ..telemetry import part as _part
 
 __all__ = ["MoE", "moe_dispatch", "moe_sharding_rules", "aux_loss_scope",
            "collected_aux_loss", "DroplessMoE", "noaux_route",
@@ -307,24 +308,32 @@ def _experts(x2d, idx, gates, w1, w3, w2, first, tm):
     f32 = jnp.float32
     T, k = idx.shape
     count = w1.shape[0]
-    key, sizes = _held_pairs(idx, first, count)
-    order = jnp.argsort(key)                          # stable
-    token = (jnp.arange(T * k, dtype=jnp.int32) // k)[order]
+    # the part again, inside the jitted function: XLA's expansion of the
+    # scatter-add names its sort and its fusion by the innermost jit's
+    # own stack ("mx.combine/scatter-add"), without the caller's
+    with _part("experts"):
+        with _part("sort"):
+            key, sizes = _held_pairs(idx, first, count)
+            order = jnp.argsort(key)                      # stable
+            token = (jnp.arange(T * k, dtype=jnp.int32) // k)[order]
+            xs = x2d[token]
 
-    def product(a, w):
-        if tm is None:
-            return jax.lax.ragged_dot(a, w, sizes,
-                                      preferred_element_type=f32)
-        return _gp.grouped_product(a, w, sizes, tm)
-    xs = x2d[token]
-    h = jax.nn.silu(product(xs, w1)) * product(xs, w3)
-    y = product(h.astype(x2d.dtype), w2)
-    # rows past the held pairs belong to no group: whatever is there is
-    # not a result
-    g = jnp.where(key < count, gates.reshape(-1), 0.0)[order].astype(f32)
-    live = jnp.arange(T * k) < sizes.sum()
-    y = jnp.where(live[:, None], y, 0.0) * g[:, None]
-    return jnp.zeros((T, x2d.shape[-1]), f32).at[token].add(y)
+        def product(a, w):
+            if tm is None:
+                return jax.lax.ragged_dot(a, w, sizes,
+                                          preferred_element_type=f32)
+            return _gp.grouped_product(a, w, sizes, tm)
+        with _part("product"):
+            h = jax.nn.silu(product(xs, w1)) * product(xs, w3)
+            y = product(h.astype(x2d.dtype), w2)
+        with _part("combine"):
+            # rows past the held pairs belong to no group: whatever is there
+            # is not a result
+            g = jnp.where(key < count, gates.reshape(-1), 0.0)[order]
+            g = g.astype(f32)
+            live = jnp.arange(T * k) < sizes.sum()
+            y = jnp.where(live[:, None], y, 0.0) * g[:, None]
+            return jnp.zeros((T, x2d.shape[-1]), f32).at[token].add(y)
 
 
 @functools.lru_cache(maxsize=None)
@@ -378,25 +387,28 @@ def dropless_moe(x2d, w, k, first, n_group=1, topk_group=1, route_scale=1.0,
     router's product and its ``scoring`` are float32: ``"sigmoid"`` of
     each logit, or ``"softmax"`` over all ``E`` (the gates are then the
     chosen probabilities renormalised).  Returns ``(y [T, d] float32, idx,
-    gates, scores)``."""
+    gates, scores)``.  The caller names the part (``experts``, with the
+    layer's pre-norm and residual add); the sub-parts are named here:
+    ``router``, ``sort``, ``product``, ``combine``, ``shared``."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    logits = jnp.dot(x2d.astype(f32), w["gate_weight"].astype(f32))
-    if scoring == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-    elif scoring == "softmax":
-        scores = jax.nn.softmax(logits, axis=-1)
-    else:
+    if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"scoring {scoring!r} is neither sigmoid nor softmax")
-    bias = w["select_bias"].astype(f32) if "select_bias" in w \
-        else jnp.zeros((scores.shape[-1],), f32)
-    idx, gates = noaux_route(scores, bias, k, n_group, topk_group,
-                             route_scale, norm_eps)
+    with _part("router"):
+        logits = jnp.dot(x2d.astype(f32), w["gate_weight"].astype(f32))
+        scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        bias = w["select_bias"].astype(f32) if "select_bias" in w \
+            else jnp.zeros((scores.shape[-1],), f32)
+        idx, gates = noaux_route(scores, bias, k, n_group, topk_group,
+                                 route_scale, norm_eps)
     y = dropless_experts(x2d, idx, gates, w["held_w1"], w["held_w3"],
                          w["held_w2"], first)
     if with_shared and "shared_w1" in w:
-        y = y + swiglu(x2d, w["shared_w1"], w["shared_w3"], w["shared_w2"])
+        with _part("shared"):
+            y = y + swiglu(x2d, w["shared_w1"], w["shared_w3"],
+                           w["shared_w2"])
     return y, idx, gates, scores
 
 

@@ -719,26 +719,33 @@ class GenerationEngine:
                     return model.prefill(NDArray(tok), NDArray(vl), **kw)
 
             res, _aux = _run_with_params(ps, raws, call)
-            # (1, Lb, V), or (1, 1, V): the row at vl - 1 alone, from a
-            # model whose vocabulary times the bucket is gigabytes
-            lraw = unwrap(res[0])
-            row = lraw[0, 0] if lraw.shape[1] == 1 \
-                else jnp.take(lraw[0], vl[0] - 1, axis=0)
-            first = jnp.argmax(row).astype(jnp.int32)
-            # the host reads `first`; the slot's next decode step reads it
-            # from the vector, without waiting for the host
-            out = [first, jax.lax.dynamic_update_slice(
-                last, first[None], (slot,))]
+            with _telemetry.part("head"):
+                # (1, Lb, V), or (1, 1, V): the row at vl - 1 alone, from
+                # a model whose vocabulary times the bucket is gigabytes
+                lraw = unwrap(res[0])
+                row = lraw[0, 0] if lraw.shape[1] == 1 \
+                    else jnp.take(lraw[0], vl[0] - 1, axis=0)
+                first = jnp.argmax(row).astype(jnp.int32)
+                # the host reads `first`; the slot's next decode step
+                # reads it from the vector, without waiting for the host
+                out = [first, jax.lax.dynamic_update_slice(
+                    last, first[None], (slot,))]
             rows = [unwrap(r) for layer in res[1] for r in layer]
-            for ring, new in zip(cache_flat, rows):
+            for (kind, _s, _d), ring, new in zip(self._ring_specs,
+                                                 cache_flat, rows):
                 # what the model keeps, written whole at the slot.  Of a
                 # ring indexed by position the padded rows beyond vl are
                 # dead: decode overwrites index j at position j before
                 # the mask reaches it.  A state has no dead part: the
-                # model owes it as of vl, not of the bucket's end
-                out.append(jax.lax.dynamic_update_slice(
-                    ring, new.astype(ring.dtype),
-                    (slot,) + (0,) * (ring.ndim - 1)))
+                # model owes it as of vl, not of the bucket's end.  In a
+                # device trace the write is the part's whose name the
+                # ring's kind is (conv, indexer), else attention's
+                with _telemetry.part(kind if kind in _telemetry.PARTS
+                                     else "attention"), \
+                        _telemetry.part("ring_write"):
+                    out.append(jax.lax.dynamic_update_slice(
+                        ring, new.astype(ring.dtype),
+                        (slot,) + (0,) * (ring.ndim - 1)))
             if probe:
                 out.append({"logits": row, **(res[2] if kw else {})})
             return tuple(out)
@@ -767,7 +774,8 @@ class GenerationEngine:
 
         def pure_decode(raws, pos, act, last, *cache_flat):
             # a slot that does not ride reads as token 0, whatever it held
-            tok = jnp.where(act > 0, last, 0)
+            with _telemetry.part("embed"):
+                tok = jnp.where(act > 0, last, 0)
             caches = [tuple(NDArray(r) for r in layer)
                       for layer in self._by_layer(cache_flat)]
 
@@ -779,13 +787,16 @@ class GenerationEngine:
                                              active=NDArray(act), **kw)
 
             res, _aux = _run_with_params(ps, raws, call)
-            nxt = jnp.argmax(unwrap(res[0]), axis=-1).astype(jnp.int32)
-            # the riders' new tokens stay on the device for the next step
-            keep = jnp.where(act > 0, nxt, last)
-            if self._step_counters:
-                # one array back to the host: the tokens, then the counts
-                nxt = jnp.concatenate(
-                    [nxt, unwrap(res[2]).astype(jnp.int32)])
+            with _telemetry.part("head"):
+                nxt = jnp.argmax(unwrap(res[0]), axis=-1).astype(jnp.int32)
+                # the riders' new tokens stay on the device for the next
+                # step
+                keep = jnp.where(act > 0, nxt, last)
+                if self._step_counters:
+                    # one array back to the host: the tokens, then the
+                    # counts
+                    nxt = jnp.concatenate(
+                        [nxt, unwrap(res[2]).astype(jnp.int32)])
             out = (nxt, keep) + tuple(unwrap(r) for layer in res[1]
                                       for r in layer)
             if probe:

@@ -65,6 +65,7 @@ __all__ = [
     "tracing_enabled", "set_trace_sample", "request_scope", "request_span",
     "maybe_spool", "flush_trace_spool", "inflight_trace_ids",
     "format_request_waterfall", "set_memory_sampler",
+    "part", "PARTS", "SUB_PARTS", "PARTS_VERSION",
 ]
 
 _NAME_RE = re.compile(r"^[a-z0-9_]+/[a-z0-9_]+$")
@@ -613,6 +614,37 @@ class _Phase:
         add_span(self._name, self._t0 // 1000, (t1 - self._t0) / 1000,
                  **self._attrs)
         return False
+
+
+# ---------------------------------------------------------------------------
+# the device's side: the parts of a model's step
+# ---------------------------------------------------------------------------
+# What a device trace may call a piece of a step program.  A part's
+# pre-norm and its residual add belong to it; a second scope inside one
+# names a sub-part ("attention/ring_write").  docs/OBSERVABILITY.md says
+# what each holds and which metric or record reads it.
+PARTS = ("embed", "attention", "indexer", "conv", "ffn", "experts", "head",
+         "loss", "optimizer")
+SUB_PARTS = ("project", "attend", "ring_write", "scores", "top_k", "mask",
+             "router", "sort", "product", "combine", "shared", "health")
+
+# Both compile caches key a program with its debug locations stripped,
+# and a scope's name lives there: an executable compiled before a scope
+# was added, renamed or moved would be a warm hit under the old names.
+# Bump this with any such change: ``compile.version_stamp`` and
+# ``compile.enable_persistent_cache`` fold it into both keys.
+PARTS_VERSION = 2
+
+
+def part(name):
+    """``with telemetry.part("attention"):`` — files the work *traced*
+    under it as that part of the model: ``mx.<name>`` on the name stack
+    of every operation (``jax.named_scope``), which XLA carries as the
+    instruction's ``op_name`` into the executable and into the profiler's
+    device events.  It acts while a program is traced and costs nothing
+    when it runs; it is not gated by ``MXNET_TELEMETRY``."""
+    import jax
+    return jax.named_scope("mx." + name)
 
 
 def phase(name, **attrs):
