@@ -49,9 +49,10 @@ from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from .parts import (LANES, DrawnBias as _DrawnBias, FanInNormal,
-                    index_scores, layer_norm as _layernorm,
-                    matmul as _mm, part, rms_norm as _rms, rope as _rope,
-                    sectioned_angles, selection_mask, sub_weights as _sub)
+                    grouped_ring_attend, index_scores,
+                    layer_norm as _layernorm, matmul as _mm, part,
+                    rms_norm as _rms, rope as _rope, sectioned_angles,
+                    selection_mask, sub_weights as _sub)
 
 __all__ = ["KeyeVL2LM", "KEYE_PUBLISHED", "tiny_keye", "trunk", "head",
            "run_full", "decode", "STEP_COUNTERS"]
@@ -85,9 +86,11 @@ STEP_COUNTERS = (
                               "slots and layers"),
     ("index_selected_positions", "positions attended after the top-k, "
                                  "summed likewise"),
-    ("kv_rows_read", "rows of the k ring the attention read, summed "
-                     "over slots and layers: the whole ring, the "
-                     "selection being a mask over it"),
+    ("kv_rows_read", "rows of the k ring (and as many of the v ring) the "
+                     "attention read, summed over slots and layers, the "
+                     "selection being a mask over them: whole blocks up to "
+                     "each slot's valid positions where the kernel ran, "
+                     "the whole ring where the einsums did"),
     ("attn_valid_positions", "cached positions of the k and v rings that "
                              "were valid, summed likewise"),
     ("routed_pairs", "(token, expert) pairs the routers chose"),
@@ -294,32 +297,6 @@ def run_full(c, w, tokens, positions=None, index_topk=None,
     return head(c, w, x), caches, sel
 
 
-def _attend(c, q, ring_k, ring_v, mask):
-    """Every query head of ``q`` [S, H, D] over its slot's rows of the
-    rings [S, M, KV * D] that the selection names, as a ``mask`` [S, M]
-    over the whole ring.  The heads stay side by side on
-    the row's lanes, as in ``lfm2.py``: a head's query is laid into its
-    key head's ``D`` of the row's numbers and the rest left zero, so that
-    scores and values are products over whole rows and the rings are never
-    reshaped.  Returns ``(out [S, H * D] float32, rows read a slot)``."""
-    import jax
-    jnp = _jnp()
-    f32 = jnp.float32
-    S, M, W = ring_k.shape
-    H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    G = H // KV
-    # [S, KV, G, KV', D]: head (kv, g) holds its query where kv' == kv
-    own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
-    wide = (q.reshape(S, KV, G, 1, D) * own).reshape(S, H, W)
-    s = jnp.einsum("shw,smw->shm", wide, ring_k.astype(q.dtype),
-                   preferred_element_type=f32) * D ** -0.5
-    p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
-    o = jnp.einsum("shm,smw->shw", p.astype(q.dtype), ring_v.astype(q.dtype),
-                   preferred_element_type=f32)
-    o = (o.reshape(S, KV, G, KV, D) * own.astype(f32)).sum(3)
-    return o.reshape(S, H * D), jnp.full((S,), M, jnp.int32)
-
-
 def decode(c, w, tok, caches, pos, active=None, index_topk=None,
            want_selections=False):
     """One token a slot, ``tok`` [S] at ``pos`` [S] (text: the three axes
@@ -327,11 +304,13 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     ring [S, M, stride]) a layer].  The new rows land at ``pos % M`` of
     the active slots (one scatter a ring); the indexer scores the slot's
     valid positions, ``top_k`` keeps ``index_topk`` of them, and every
-    head attends over the rings under that selection as a mask: the one
-    form the tree has.  At 40 slots x 12,288 with contexts of 6.4-9.9 k on
-    a v5e it took 2.10 ms a layer where a gather of the 2,048 selected
-    rows from both rings and products over them took 2.93 (a whole step
-    19.35 against 22.70 ms: PERF.md, PR 35).  Returns ``(logits [S, V] float32,
+    head attends over the rings under that selection as a mask
+    (:func:`parts.grouped_ring_attend`: on one TPU a kernel over the
+    slot's valid blocks, else einsums over the whole rings).  At 40 slots
+    x 12,288 with contexts of 6.4-9.9 k on a v5e the masked einsums took
+    2.10 ms a layer where a gather of the 2,048 selected rows from both
+    rings and products over them took 2.93 (a whole step 19.35 against
+    22.70 ms: PERF.md, PR 35).  Returns ``(logits [S, V] float32,
     rings, counts [len(STEP_COUNTERS)] int32)``, and with
     ``want_selections`` a fourth: :func:`trunk`'s selections for this one
     position a slot (indices into the ring and their scores [S, K])."""
@@ -380,7 +359,8 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
             mask = selection_mask(chosen, keep, M)
         with part("attention"):
             with part("attend"):
-                o, rows_read = _attend(c, q, ring_k, ring_v, mask)
+                o, rows_read = grouped_ring_attend(q, ring_k, ring_v,
+                                                   n_valid, mask)
             with part("project"):
                 x = x + _mm(o.astype(x.dtype), lw["wo"])
         x, idx, router_scores, load = _ffn(c, lw, x, weight=act)

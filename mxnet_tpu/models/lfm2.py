@@ -41,9 +41,9 @@ from .. import initializer as init
 from ..base import np_dtype
 from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
-from .parts import (DrawnBias as _DrawnBias, FanInNormal, matmul as _mm,
-                    part, rms_norm as _rms, rope as _rope,
-                    sub_weights as _sub)
+from .parts import (DrawnBias as _DrawnBias, FanInNormal,
+                    grouped_ring_attend, matmul as _mm, part,
+                    rms_norm as _rms, rope as _rope, sub_weights as _sub)
 
 __all__ = ["LFM2MoeLM", "LFM2_PUBLISHED", "tiny_lfm2", "run_full", "decode",
            "STEP_COUNTERS"]
@@ -88,6 +88,10 @@ STEP_COUNTERS = (
                              "experts multiplied (row tiles visited x tile "
                              "rows), summed over the expert layers: over "
                              "the held pairs, the product's redundancy"),
+    ("kv_rows_read", "rows of the k ring (and as many of the v ring) the "
+                     "attention read, summed over slots and layers: whole "
+                     "blocks up to each slot's valid positions where the "
+                     "kernel ran, the whole ring where the einsums did"),
 )
 
 
@@ -258,19 +262,12 @@ def _attn_step(c, w, h, ring_k, ring_v, pos, act):
     pre-norm and its residual add are here) against the rings
     [S, M, KV * D]: the new rows
     land at ``pos % M`` of the active slots (one scatter a ring), and
-    every query head attends over its slot's valid positions.  The heads
-    stay side by side on the row's lanes: a head's query is laid into its
-    key head's 64 of the row's 512 numbers and the rest left zero, so that
-    scores and values are products over whole rows and the ring is never
-    reshaped (a ring split by heads has 64 numbers on the lanes, and the
-    chip then copies it whole).  Returns ``(h + out [S, d], rings,
-    positions read [S])``."""
-    import jax
+    every query head attends over its slot's valid positions
+    (:func:`parts.grouped_ring_attend`: on one TPU a kernel over the
+    slot's valid blocks, else einsums over the whole rings).  Returns
+    ``(h + out [S, d], rings, positions valid [S], ring rows read [S])``."""
     jnp = _jnp()
-    f32 = jnp.float32
     S, M, W = ring_k.shape
-    H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    G = H // KV
     with part("attention"):
         q, k, v = _qkv(c, w, h, pos)
         with part("ring_write"):
@@ -282,21 +279,10 @@ def _attn_step(c, w, h, ring_k, ring_v, pos, act):
                 v.reshape(S, W).astype(ring_v.dtype), mode="drop")
         with part("attend"):
             n_valid = jnp.minimum(pos + 1, M)
-            valid = jnp.arange(M)[None, :] < n_valid[:, None]
-            # [S, KV, G, KV', D]: head (kv, g) holds its query where
-            # kv' == kv
-            own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
-            wide = (q.reshape(S, KV, G, 1, D) * own).reshape(S, H, W)
-            s = jnp.einsum("shw,smw->shm", wide, ring_k.astype(h.dtype),
-                           preferred_element_type=f32) * D ** -0.5
-            p = jax.nn.softmax(jnp.where(valid[:, None], s, -1e30), axis=-1)
-            o = jnp.einsum("shm,smw->shw", p.astype(h.dtype),
-                           ring_v.astype(h.dtype),
-                           preferred_element_type=f32)
-            o = (o.reshape(S, KV, G, KV, D) * own.astype(f32)).sum(3)
+            o, rows_read = grouped_ring_attend(q, ring_k, ring_v, n_valid)
         with part("project"):
-            return h + _mm(o.astype(h.dtype).reshape(S, H * D), w["wo"]), \
-                ring_k, ring_v, n_valid
+            return h + _mm(o.astype(h.dtype), w["wo"]), ring_k, ring_v, \
+                n_valid, rows_read
 
 
 def _conv_step(c, w, h, state, act):
@@ -340,11 +326,12 @@ def decode(c, w, tok, caches, pos, active=None, want_selections=False):
             x, state = _conv_step(c, lw, x, caches[i][0], act)
             new.append((state,))
         else:
-            x, ring_k, ring_v, n_valid = _attn_step(c, lw, x, *caches[i],
-                                                    pos, act)
+            x, ring_k, ring_v, n_valid, rows_read = _attn_step(
+                c, lw, x, *caches[i], pos, act)
             new.append((ring_k, ring_v))
             with part("attention"):
                 counts = counts.at[3].add((act * n_valid).sum())
+                counts = counts.at[5].add((act * rows_read).sum())
         x, idx, scores, load = _ffn(c, lw, i, x, weight=act)
         if idx is not None:
             sel["experts"].append(idx)

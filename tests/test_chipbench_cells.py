@@ -40,7 +40,7 @@ def test_run_py_rehearses_the_cell(cell, trace):
                 "wire_write_us.dsv32", "writer_batch_tokens.dsv32"} <= names
     elif cell.startswith("lfm2") and trace:
         assert {"experts_touched.lfm2", "expert_load_max.lfm2",
-                "expert_rows_computed.lfm2",
+                "expert_rows_computed.lfm2", "kv_rows_read.lfm2",
                 "kv_context_mean.lfm2", "batch_occupancy.lfm2",
                 "compile_s.lfm2", "overlap_share.lfm2",
                 "loop_offcpu_us.lfm2", "emit_to_wire_us.lfm2",

@@ -569,7 +569,8 @@ METRIC_SETS = {
     "lfm2": ("serve_lfm2", "lfm2_24b.decode_rollout",         # PR 33
              "writer_batch_tokens.dsv32",
              {"experts_touched", "expert_load_max", "kv_context_mean",
-              "decode_step_roofline", "expert_rows_computed"},      # PR 36
+              "decode_step_roofline", "expert_rows_computed",       # PR 36
+              "kv_rows_read"},                                      # PR 38
              {"index_selected_share", "routed_held_share",
               "latent_rows_read"}),
     "keye": ("serve_keye", "keye_vl2.decode_doc",             # PR 35
@@ -615,7 +616,8 @@ def test_a_sets_metric_file_is_an_entry_of_its_cell(name):
         assert {k: v for k, v in spec.items() if k != "jobs"} \
             == {k: v for k, v in other.items() if k != "jobs"}
     else:
-        assert name in ("kv_context_mean.lfm2", "kv_rows_read.keye")
+        assert name in ("kv_context_mean.lfm2", "kv_rows_read.keye",
+                        "kv_rows_read.lfm2")
 
 
 @pytest.mark.parametrize("suffix", sorted(METRIC_SETS))

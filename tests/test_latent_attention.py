@@ -1,8 +1,8 @@
 """``ops.latent_ring_attention`` on the CPU: the kernel through Pallas'
 interpreter against the plain masked softmax, ``deepseek.decode`` with the
 kernel forced against ``decode`` in the XLA form, the counter that says
-which form ran, and the kernel at the benchmark's widths through the
-chip's compiler (no chip)."""
+which form ran, and the kernels of the decode steps at the benchmark's
+widths through the chip's compiler (no chip)."""
 import functools
 
 import jax
@@ -292,6 +292,44 @@ def test_the_kernel_compiles_for_a_v5e_at_the_benchmarks_widths(one_chip):
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < ring_bytes // 8
     assert stats.output_size_in_bytes == slots * heads * 512 * 2
+
+
+# a decode step's attention of the two cells with grouped rings: (slots,
+# ring positions, whether a selection masks them); 32 heads over rows of 512
+@pytest.mark.parametrize("slots,ring_len,masked", [
+    (128, 5120, False), (40, 12288, True)], ids=["lfm2", "keye"])
+def test_the_grouped_ring_kernel_compiles_for_a_v5e_at_the_cells_widths(
+        one_chip, slots, ring_len, masked):
+    """Mosaic takes the kernel at the block the dispatch picks, both rings
+    go in as they lie (no copy of either in the program) and nothing
+    ring-sized comes out.  (Here and not in
+    ``test_grouped_ring_attention.py``: one file describes the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops import grouped_ring_attention as gra
+    heads, width = 32, 512
+
+    def shape(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+    ring = shape(slots, ring_len, width)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(functools.partial(
+            gra.grouped_ring_attention, scale=0.1)).lower(
+            shape(slots, heads, width), ring, ring,
+            shape(slots, dt=jnp.int32),
+            **({"mask": shape(slots, ring_len, dt=bool)} if masked else {})
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"bf16[{slots},{ring_len},{width}]" in text
+    ring_bytes = slots * ring_len * width * 2
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < ring_bytes // 8
+    assert stats.output_size_in_bytes == slots * heads * width * 4
 
 
 # a decode step of the three MoE cells: (pairs, held experts, d, hidden)

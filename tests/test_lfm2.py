@@ -129,6 +129,8 @@ def test_prefill_hands_the_state_over_at_the_valid_length(valid):
         counts = dict(zip(COUNTERS, counts.asnumpy()))
         assert counts["routed_pairs"] == 2 * 4 * 4
         assert counts["attn_valid_positions"] == 2 * int(pos.sum())
+        # on a CPU the attention is einsums over both whole rings
+        assert counts["kv_rows_read"] == 2 * 2 * M
         assert 1 <= counts["expert_load_max"] <= 2
         assert 4 <= counts["experts_touched"] <= 2 * 4 * 4
         assert len(counts) == len(lfm2.STEP_COUNTERS)
