@@ -46,7 +46,7 @@ from ..ops import latent_ring_attention as _lra
 from .parts import (LANES, FanInNormal as _FanInNormal, index_scores,
                     layer_norm as _layernorm, matmul as _mm, part,
                     rms_norm as _rms, rope as _rope, selection_mask,
-                    sub_weights as _sub, topk_mask)
+                    sparse_block_attend, sub_weights as _sub, topk_mask)
 
 __all__ = ["DeepSeekV32LM", "V32_PUBLISHED", "tiny_v32", "run_full", "decode",
            "yarn_inv_freq", "softmax_scale", "STEP_COUNTERS"]
@@ -195,20 +195,29 @@ def _attn_inputs(c, w, h, pos):
 def _attn_full(c, w, h, pos, index_topk, want_mask):
     """Attention over a whole sequence in the expanded form, in blocks of
     queries so that neither the heads' scores nor the indexer's are ever
-    whole.  Returns ``(the stream ``h`` [B,L,d] with attention's output
-    added, latent rows, k^I, mask or None, index scores or None)``: the
-    last two on request, the scores only where the sequence is longer
-    than ``index_topk`` (below it nothing is scored)."""
+    whole; a block's heads attend under its selection through
+    :func:`parts.sparse_block_attend` (on one TPU a kernel that keeps the
+    scores in VMEM, else einsums and a masked softmax).  Returns ``(the
+    stream ``h`` [B,L,d] with attention's output added, latent rows, k^I,
+    mask or None, index scores or None)``: the last two on request, the
+    scores only where the sequence is longer than ``index_topk`` (below it
+    nothing is scored)."""
     import jax
     jnp = _jnp()
     f32 = jnp.float32
     B, L, _ = h.shape
     H, n, dv = c.num_attention_heads, c.qk_nope_head_dim, c.v_head_dim
-    kvr = c.kv_lora_rank
+    kvr, r = c.kv_lora_rank, c.qk_rope_head_dim
     q_nope, q_rope, latent, qi, ki, wi = _attn_inputs(c, w, h, pos)
     with part("attention"), part("project"):
-        kvb = _mm(latent[..., :kvr], w["wkv_b"]).reshape(B, L, H, n + dv)
-        k_nope, v, k_rope = kvb[..., :n], kvb[..., n:], latent[..., kvr:]
+        # keys and values head-major [B, H, L, .] out of the product itself:
+        # no transpose of them; k_rope is every head's
+        kvb = jnp.einsum("blc,che->bhle", latent[..., :kvr],
+                         w["wkv_b"].reshape(kvr, H, n + dv),
+                         preferred_element_type=f32).astype(h.dtype)
+        k = jnp.concatenate([kvb[..., :n], jnp.broadcast_to(
+            latent[:, None, :, kvr:], (B, H, L, r))], axis=-1)
+        v = kvb[..., n:]
     scale = softmax_scale(c)
     bq = math.gcd(L, QUERY_BLOCK)
     sparse = L > index_topk
@@ -225,15 +234,10 @@ def _attn_full(c, w, h, pos, index_topk, want_mask):
                 scores = index_scores(rows(qi), rows(wi), ki)
                 mask = topk_mask(scores, mask, index_topk)
         with part("attention"), part("attend"):
-            s = jnp.einsum("bqhn,bkhn->bhqk", rows(q_nope), k_nope,
-                           preferred_element_type=f32) \
-                + jnp.einsum("bqhr,bkr->bhqk", rows(q_rope), k_rope,
-                             preferred_element_type=f32)
-            s = jnp.where(mask[:, None], s * scale, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-            o = jnp.einsum("bhqk,bkhv->bqhv", p, v,
-                           preferred_element_type=f32)
-            o = o.astype(h.dtype).reshape(B, bq, H * dv)
+            q = jnp.moveaxis(jnp.concatenate([rows(q_nope), rows(q_rope)],
+                                             axis=-1), 2, 1)  # [B, H, bq, .]
+            o = sparse_block_attend(q, k, v, mask, i * bq, scale)
+            o = o.astype(h.dtype)
         return (o, mask, scores) if want_mask else (o, None, None)
 
     def whole(a):

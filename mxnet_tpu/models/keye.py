@@ -52,7 +52,8 @@ from .parts import (LANES, DrawnBias as _DrawnBias, FanInNormal,
                     grouped_ring_attend, index_scores,
                     layer_norm as _layernorm, matmul as _mm, part,
                     rms_norm as _rms, rope as _rope, sectioned_angles,
-                    selection_mask, sub_weights as _sub)
+                    selection_mask, sparse_block_attend,
+                    sub_weights as _sub)
 
 __all__ = ["KeyeVL2LM", "KEYE_PUBLISHED", "tiny_keye", "trunk", "head",
            "run_full", "decode", "STEP_COUNTERS"]
@@ -152,21 +153,23 @@ def _inputs(c, w, h, pos3):
 def _attn_full(c, w, h, pos3, index_topk, want_sel):
     """Attention over a whole sequence [B, L, d], in blocks of
     ``q_chunk_size`` queries so that neither the heads' scores nor the
-    indexer's are ever whole.  Returns ``(the stream ``h`` with its output
-    added, k rows [B, L, KV * D], v rows, k^I [B, L, Di], positions, index
-    scores)``: the last
-    two on request and only where the sequence is longer than
+    indexer's are ever whole; a block's heads attend under its selection
+    through :func:`parts.sparse_block_attend` (on one TPU a kernel that
+    keeps the scores in VMEM, else einsums and a masked softmax).  Returns
+    ``(the stream ``h`` with its output added, k rows [B, L, KV * D], v
+    rows, k^I [B, L, Di], positions, index scores)``: the last two on
+    request and only where the sequence is longer than
     ``index_topk`` (below it nothing is scored), the selection as
     ``top_k`` gave it, ``[B, L, K]`` indices (-1 where a query has fewer
     valid positions) and their scores."""
     import jax
     jnp = _jnp()
-    f32 = jnp.float32
     B, L, _ = h.shape
     H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     G = H // KV
     q, k, v, qi, ki, wi = _inputs(c, w, h, pos3)
-    q = q.reshape(B, L, KV, G, D)
+    with part("attention"), part("project"):
+        kh, vh = jnp.moveaxis(k, 2, 1), jnp.moveaxis(v, 2, 1)  # [B, KV, L, D]
     bq = math.gcd(L, c.query_block)
     K = index_topk
     sparse = L > K
@@ -190,13 +193,11 @@ def _attn_full(c, w, h, pos3, index_topk, want_sel):
                                       ).reshape(B, bq, L)
                 chosen = jnp.where(keep, chosen, -1)
         with part("attention"), part("attend"):
-            s = jnp.einsum("bqkgd,bmkd->bkgqm", rows(q), k,
-                           preferred_element_type=f32) * D ** -0.5
-            p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30),
-                               axis=-1)
-            o = jnp.einsum("bkgqm,bmkd->bqkgd", p.astype(v.dtype), v,
-                           preferred_element_type=f32)
-            o = o.astype(h.dtype).reshape(B, bq, H * D)
+            # [B, KV, G * bq, D]: a key head's query heads one after another
+            qb = jnp.transpose(rows(q).reshape(B, bq, KV, G, D),
+                               (0, 2, 3, 1, 4)).reshape(B, KV, G * bq, D)
+            o = sparse_block_attend(qb, kh, vh, mask, i * bq, D ** -0.5)
+            o = o.astype(h.dtype)
         return (o, chosen, vals) if want_sel and sparse else (o, None, None)
 
     def whole(a):

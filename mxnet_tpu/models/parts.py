@@ -2,8 +2,9 @@
 weights share (``deepseek.py``, ``lfm2.py``, ``keye.py``): the norms, the
 product, the rotation and its sectioned angles, the learned selection of
 positions (the indexer's scores, the top-k and its mask), a decode step's
-attention over grouped key / value rings, the weights of one layer, and
-the initializer of a served model built from a seed.
+attention over grouped key / value rings, a prefill's attention under the
+selection a block of queries at a time, the weights of one layer, and the
+initializer of a served model built from a seed.
 Weights are stored [in, out]; norms, rotations and index scores are float32
 inside whatever the activations are."""
 from __future__ import annotations
@@ -15,11 +16,12 @@ import numpy as onp
 from .. import initializer as init
 from .. import random as _random
 from ..ops import grouped_ring_attention as _gra
+from ..ops import sparse_prefill_attention as _spa
 from ..telemetry import part
 
 __all__ = ["rms_norm", "layer_norm", "matmul", "rope", "sectioned_angles",
            "index_scores", "topk_mask", "selection_mask",
-           "grouped_ring_attend", "sub_weights",
+           "grouped_ring_attend", "sparse_block_attend", "sub_weights",
            "FanInNormal", "DrawnBias", "LANES", "part"]
 
 # the chip's lane width: a ring whose row is a multiple of it lies with the
@@ -189,6 +191,47 @@ def grouped_ring_attend(q, ring_k, ring_v, n_valid, mask=None):
         rows_read = jnp.full((S,), M, jnp.int32)
     o = (o.reshape(S, KV, G, KV, D) * own.astype(f32)).sum(3)
     return o.reshape(S, H * D), rows_read
+
+
+def sparse_block_attend(q, k, v, mask, q_start, scale):
+    """A prefill's attention for one block of ``bq`` queries: every query
+    head of ``q`` [B, KV, G * bq, Dk] (key head ``kv``'s ``G`` query heads
+    one after the other) over the positions its block's ``mask``
+    [B, bq, L] keeps (the indexer's selection, causal included; none past
+    ``q_start + bq - 1``) of ``k`` [B, KV, L, Dk] and ``v`` [B, KV, L, Dv].
+    Scores float32, probabilities in ``v``'s type, products accumulated in
+    float32.
+
+    * on one TPU, for a sequence a block divides,
+      :func:`mxnet_tpu.ops.sparse_prefill_attention.sparse_prefill_attention`
+      keeps scores and probabilities in VMEM and skips the key blocks past
+      the block's last query;
+    * on a CPU, under a mesh, or where the compiler refuses the kernel,
+      two einsums over all ``L`` positions and the masked softmax between
+      them.
+
+    One layer's blocks on a v5e took 8.85 ms in the kernel where the
+    einsums took 34.5 (DeepSeek, 2,816 positions), 5.23 against 39.4
+    (Keye, 8,192): a DeepSeek prefill 340 -> 210 ms (PERF.md, section 6).
+    Returns ``o`` [B, bq, KV * G * Dv] float32, the heads side by side."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    B, KV, R, Dk = q.shape
+    L, Dv = k.shape[2], v.shape[3]
+    bq = mask.shape[1]
+    G = R // bq
+    block = _spa.kernel_block(B, KV, G, bq, L, Dk, Dv, q.dtype)
+    if block is not None:
+        return _spa.sparse_prefill_attention(q, k, v, mask, q_start, scale,
+                                             block_k=block)
+    s = jnp.einsum("bkrd,bkmd->bkrm", q, k,
+                   preferred_element_type=f32).reshape(B, KV, G, bq, L)
+    s = jnp.where(mask[:, None, None], s * scale, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype).reshape(B, KV, R, L)
+    o = jnp.einsum("bkrm,bkmd->bkrd", p, v, preferred_element_type=f32)
+    return jnp.moveaxis(o.reshape(B, KV, G, bq, Dv), 3, 1).reshape(
+        B, bq, KV * G * Dv)
 
 
 def sub_weights(w, prefix):

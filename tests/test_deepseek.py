@@ -97,6 +97,39 @@ def test_index_scores_and_selection_with_topk_below_the_context(net,
         assert not (mask & ~valid).any()
 
 
+@pytest.mark.parametrize("batch,length", [(1, 24), (2, 32)])
+def test_full_forward_with_the_prefill_kernel_is_the_xla_form(
+        monkeypatch, batch, length):
+    """``run_full`` with ``parts.sparse_block_attend`` forced to its kernel
+    (Pallas' interpreter, key blocks of 8) against the XLA form: logits
+    within float32's rounding of the order of a sum, and the selections
+    bit for bit: the indexer and its mask are the same code in both.  The
+    first layer's index scores are the same bits; a later layer's follow
+    a stream that differs in the last ones."""
+    import functools
+    from mxnet_tpu.ops import sparse_prefill_attention as spa
+    net = _net(held=(4, 8))
+    toks = jnp.asarray(_tokens(length, batch=batch))
+    c, w = net.config, net.raw_weights()
+    want = deepseek.run_full(c, w, toks, want_selections=True)
+    with monkeypatch.context() as patch:
+        patch.setattr(spa, "kernel_block", lambda *a: 8)
+        patch.setattr(spa, "sparse_prefill_attention", functools.partial(
+            spa.sparse_prefill_attention, interpret=True))
+        got = deepseek.run_full(c, w, toks, want_selections=True)
+    assert length > c.index_topk                # the selection is live
+    assert float(jnp.abs(got[0] - want[0]).max()) < 2e-5
+    for (latent, ki), (w_latent, w_ki) in zip(got[1], want[1]):
+        assert float(jnp.abs(latent - w_latent).max()) < 2e-5
+        assert float(jnp.abs(ki - w_ki).max()) < 2e-5
+    for a, b in zip(got[2]["positions"], want[2]["positions"]):
+        assert (onp.asarray(a) == onp.asarray(b)).all()
+    first, *later = zip(got[2]["index_scores"], want[2]["index_scores"])
+    assert (onp.asarray(first[0]) == onp.asarray(first[1])).all()
+    for a, b in later:
+        assert float(jnp.abs(a - b).max()) < 2e-5
+
+
 def test_router_groups_and_gates_against_a_loop():
     rng = onp.random.RandomState(1)
     T, E, G, keep, k, scale = 40, 32, 8, 3, 4, 2.5

@@ -137,6 +137,48 @@ def test_full_forward_is_the_reference(length, positions):
             rtol=1e-5)
 
 
+@pytest.mark.parametrize("batch,length,positions", [
+    (1, 24, None), (2, 32, None), (1, 24, "patches")],
+    ids=["one", "two", "three_axes"])
+def test_full_forward_with_the_prefill_kernel_is_the_xla_form(
+        monkeypatch, batch, length, positions):
+    """``run_full`` with ``parts.sparse_block_attend`` forced to its kernel
+    (Pallas' interpreter, key blocks of 8; query blocks of 8 a key head's
+    two query heads) against the XLA form: logits within float32's
+    rounding of the order of a sum, and the selections bit for bit: the
+    indexer and its ``top_k`` are the same code in both.  The first layer's
+    index scores are the same bits; a later layer's follow a stream that
+    differs in the last ones."""
+    import functools
+    from mxnet_tpu.ops import sparse_prefill_attention as spa
+    net = _net()
+    toks = jnp.asarray(_tokens(length, batch=batch))
+    p3 = None if positions is None else jnp.asarray(onp.broadcast_to(
+        _patch_positions(length)[:, None], (3, batch, length)))
+    c, w = net.config, net.raw_weights()
+    want = keye.run_full(c, w, toks, p3, want_selections=True)
+    with monkeypatch.context() as patch:
+        patch.setattr(spa, "kernel_block", lambda *a: 8)
+        patch.setattr(spa, "sparse_prefill_attention", functools.partial(
+            spa.sparse_prefill_attention, interpret=True))
+        got = keye.run_full(c, w, toks, p3, want_selections=True)
+    assert length > TOPK                        # the selection is live
+    assert float(jnp.abs(got[0] - want[0]).max()) < 2e-5
+    for layer_got, layer_want in zip(got[1], want[1]):
+        for a, b in zip(layer_got, layer_want):
+            assert float(jnp.abs(a - b).max()) < 2e-5
+    for a, b in zip(got[2]["positions"], want[2]["positions"]):
+        assert (onp.asarray(a) == onp.asarray(b)).all()
+    first, *later = zip(got[2]["index_scores"], want[2]["index_scores"])
+    assert (onp.asarray(first[0]) == onp.asarray(first[1])).all()
+    for a, b in later:
+        a, b = onp.asarray(a), onp.asarray(b)
+        # -inf where a query has fewer valid positions than top_k keeps
+        finite = onp.isfinite(a)
+        assert (finite == onp.isfinite(b)).all()
+        assert onp.abs(a[finite] - b[finite]).max() < 2e-5
+
+
 def test_sectioned_rotary_with_equal_axes_is_plain_rotary():
     rs = onp.random.RandomState(0)
     x = jnp.asarray(rs.randn(2, 7, 3, 16), jnp.float32)

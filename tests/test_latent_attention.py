@@ -332,6 +332,48 @@ def test_the_grouped_ring_kernel_compiles_for_a_v5e_at_the_cells_widths(
     assert stats.output_size_in_bytes == slots * heads * width * 4
 
 
+# one query block of a prefill in the two sparse cells: (key heads, query
+# heads a key head, query block, bucket, key width, value width)
+@pytest.mark.parametrize("kv,groups,bq,length,dk,dv", [
+    (128, 1, 256, 2816, 192, 128), (4, 8, 512, 8192, 128, 128)],
+    ids=["deepseek_v32", "keye"])
+def test_the_sparse_prefill_kernel_compiles_for_a_v5e_at_the_cells_widths(
+        one_chip, kv, groups, bq, length, dk, dv):
+    """Mosaic takes the kernel at the block the dispatch picks, inside the
+    VMEM it asks for, and no score reaches memory: the program holds
+    nothing near the XLA form's float32 scores of the block (369 MB in
+    DeepSeek), and what comes out is the heads' outputs side by side.
+    (Here and not in ``test_sparse_prefill_attention.py``: one file
+    describes the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops import sparse_prefill_attention as spa
+    block = spa.pick_block(length)
+
+    def shape(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(functools.partial(
+            spa.sparse_prefill_attention, scale=0.1, block_k=block)).lower(
+            shape(1, kv, groups * bq, dk), shape(1, kv, length, dk),
+            shape(1, kv, length, dv), shape(1, bq, length, dt=bool),
+            shape(dt=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[1,{kv},{groups * bq},{length}]" not in text
+    stats = compiled.memory_analysis()
+    # at most the keys laid out for the kernel (a width of 192 on whole
+    # lanes) and the selection as a bias
+    lanes = -(-dk // 128) * 128
+    assert stats.temp_size_in_bytes <= (kv * length * lanes * 2
+                                        + bq * length * 4)
+    assert stats.output_size_in_bytes == bq * kv * groups * dv * 4
+
+
 # a decode step of the three MoE cells: (pairs, held experts, d, hidden)
 @pytest.mark.parametrize("pairs,count,d,hidden", [
     (512, 64, 2048, 1536), (512, 16, 7168, 2048), (320, 128, 2048, 768)],
