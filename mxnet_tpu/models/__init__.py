@@ -21,3 +21,4 @@ from .yolo import (  # noqa: F401
 from .deepseek import DeepSeekV32LM, tiny_v32  # noqa: F401
 from .lfm2 import LFM2MoeLM, tiny_lfm2  # noqa: F401
 from .keye import KeyeVL2LM, tiny_keye  # noqa: F401
+from .solar import SolarOpen2LM, tiny_solar  # noqa: F401

@@ -1,9 +1,11 @@
 """What the decoder families written as pure functions of a dict of raw
-weights share (``deepseek.py``, ``lfm2.py``, ``keye.py``): the norms, the
-product, the rotation and its sectioned angles, the learned selection of
-positions (the indexer's scores, the top-k and its mask), a decode step's
-attention over grouped key / value rings, a prefill's attention under the
-selection a block of queries at a time, the weights of one layer, and the
+weights share (``deepseek.py``, ``lfm2.py``, ``keye.py``, ``solar.py``): the
+norms, the product, the rotation and its sectioned angles, the learned
+selection of positions (the indexer's scores, the top-k and its mask), a
+decode step's attention over grouped key / value rings, a prefill's
+attention under the selection a block of queries at a time, a depthwise
+short convolution and the gated delta rule in their full-sequence and
+one-step forms, a sigmoid output gate, the weights of one layer, and the
 initializer of a served model built from a seed.
 Weights are stored [in, out]; norms, rotations and index scores are float32
 inside whatever the activations are."""
@@ -19,10 +21,12 @@ from ..ops import grouped_ring_attention as _gra
 from ..ops import sparse_prefill_attention as _spa
 from ..telemetry import part
 
-__all__ = ["rms_norm", "layer_norm", "matmul", "rope", "sectioned_angles",
-           "index_scores", "topk_mask", "selection_mask",
-           "grouped_ring_attend", "sparse_block_attend", "sub_weights",
-           "FanInNormal", "DrawnBias", "LANES", "part"]
+__all__ = ["rms_norm", "layer_norm", "l2_norm", "matmul", "rope",
+           "sectioned_angles", "index_scores", "topk_mask", "selection_mask",
+           "grouped_ring_attend", "sparse_block_attend", "short_conv",
+           "short_conv_step", "delta_rule_chunked", "delta_rule_step",
+           "output_gate", "sub_weights", "FanInNormal", "DrawnBias", "LANES",
+           "part"]
 
 # the chip's lane width: a ring whose row is a multiple of it lies with the
 # rows contiguous, and :func:`selection_mask` splits a position by it
@@ -34,6 +38,13 @@ def rms_norm(x, g, eps):
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return (x32 / jnp.sqrt(ms + eps) * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def l2_norm(x, eps):
+    """``x`` over its last axis's Euclidean length, in float32."""
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    return x32 / jnp.sqrt(jnp.sum(jnp.square(x32), -1, keepdims=True) + eps)
 
 
 def layer_norm(x, g, b, eps):
@@ -232,6 +243,135 @@ def sparse_block_attend(q, k, v, mask, q_start, scale):
     o = jnp.einsum("bkrm,bkmd->bkrd", p, v, preferred_element_type=f32)
     return jnp.moveaxis(o.reshape(B, KV, G, bq, Dv), 3, 1).reshape(
         B, bq, KV * G * Dv)
+
+
+def short_conv(x, taps, valid_length):
+    """A depthwise causal convolution over a whole sequence: ``z_t = sum_j
+    taps[j] * x_{t - (K - 1) + j}`` for ``x`` [B, L, C] and ``taps`` [K, C]
+    (oldest first), zeros before the first row, summed in float32.
+    Returns ``(z [B, L, C] float32, state [B, (K - 1) * C])``: the ``K -
+    1`` rows of ``x`` before position ``valid_length`` [B], oldest first,
+    side by side (zeros before the first), in ``x``'s type, which is what
+    :func:`short_conv_step` takes."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    K = taps.shape[0]
+    B, L, C = x.shape
+    t = taps.astype(f32)
+    # K - 1 zeros in front: row t of x is row K - 1 + t
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    z = padded[:, :L].astype(f32) * t[0]
+    for j in range(1, K):
+        z = z + padded[:, j:j + L].astype(f32) * t[j]
+    state = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+        rows, n, K - 1, axis=0))(padded, valid_length)
+    return z, state.reshape(B, (K - 1) * C)
+
+
+def short_conv_step(x, state, taps, act):
+    """One row a slot ``x`` [S, C] of :func:`short_conv` against ``state``
+    [S, (K - 1) * C] (the rows before it side by side: a row axis of 3
+    would be padded to a whole tile on the chip).  Returns ``(z [S, C]
+    float32, state)``: shifted by one row with ``x`` appended in the slots
+    ``act`` [S] marks; the others keep theirs."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    K, C = taps.shape
+    t = taps.astype(f32)
+    z = x.astype(f32) * t[K - 1]
+    for j in range(K - 1):
+        z = z + state[:, j * C:(j + 1) * C].astype(f32) * t[j]
+    shifted = jnp.concatenate([state[:, C:], x.astype(state.dtype)], axis=1)
+    return z, jnp.where(act[:, None] > 0, shifted, state)
+
+
+def delta_rule_chunked(q, k, v, g, beta, state, chunk):
+    """The gated delta rule over a whole sequence, ``chunk`` positions at a
+    time.  Per head, with ``S`` [K, V]:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T,
+        o_t = S_t^T q_t
+
+    for ``q``, ``k`` [B, L, H, K], ``v`` [B, L, H, V], the log decay ``g``
+    [B, L, H, K] (<= 0), ``beta`` [B, L, H] and the state before the first
+    position [B, H, K, V], all float32; ``L`` a multiple of ``chunk``.  A
+    position with ``beta = 0`` and ``g = 0`` passes the state through, so a
+    padded tail leaves it as of the last valid position.  Returns ``(o [B,
+    L, H, V], S_L)``.
+
+    Inside a chunk the state is ``Diag(Gamma_t) S_0 + sum_{i <= t}
+    Diag(Gamma_t / Gamma_i) k_i u_i^T`` (``Gamma`` the cumulative decay
+    from the chunk's start): the pseudo-values ``u`` solve one unit lower
+    triangular system, ``(I + Diag(beta) A) U = Diag(beta) (V - (K *
+    Gamma) S_0)`` with ``A_ti = sum_c k_t k_i exp(G_t - G_i)`` (i < t).  The
+    decay between two positions is taken pairwise in log space, ``exp(G_t
+    - G_i)`` with ``t >= i``, which never exceeds 1: the inverse of a
+    cumulative product would overflow float32 within a chunk where the
+    decay is steep.  Products are at full float32 precision."""
+    import jax
+    import jax.numpy as jnp
+    hp = jax.lax.Precision.HIGHEST
+    B, L, H, K = q.shape
+    n = L // chunk
+
+    def chunks(a):
+        """[B, L, H, ...] -> [n, B, H, chunk, ...]"""
+        a = a.reshape((B, n, chunk, H) + a.shape[3:])
+        return jnp.swapaxes(jnp.moveaxis(a, 1, 0), 2, 3)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+
+    def one(S, xs):
+        qc, kc, vc, gc, bc = xs             # [B, H, C, ...], bc [B, H, C]
+        G = jnp.cumsum(gc, axis=2)
+        # exp(G_t - G_i) for t >= i, 0 above the diagonal: [B, H, t, i, K]
+        decay = jnp.exp(jnp.where(causal[:, :, None],
+                                  G[:, :, :, None] - G[:, :, None], -jnp.inf))
+        dk = decay * kc[:, :, None]
+        A = jnp.where(strict, (kc[:, :, :, None] * dk).sum(-1), 0.0)
+        QK = jnp.where(causal, (qc[:, :, :, None] * dk).sum(-1), 0.0)
+        gamma = jnp.exp(G)
+        rhs = bc[..., None] * (vc - jnp.einsum(
+            "bhtk,bhkv->bhtv", kc * gamma, S, precision=hp))
+        U = jax.scipy.linalg.solve_triangular(
+            jnp.eye(chunk, dtype=q.dtype) + bc[..., None] * A, rhs,
+            lower=True, unit_diagonal=True)
+        o = jnp.einsum("bhtk,bhkv->bhtv", qc * gamma, S, precision=hp) \
+            + jnp.einsum("bhti,bhiv->bhtv", QK, U, precision=hp)
+        to_end = jnp.exp(G[:, :, -1:] - G)                  # [B, H, C, K]
+        S = gamma[:, :, -1, :, None] * S + jnp.einsum(
+            "bhik,bhiv->bhkv", kc * to_end, U, precision=hp)
+        return S, o
+    S, o = jax.lax.scan(one, state, tuple(
+        chunks(a) for a in (q, k, v, g, beta)))
+    # [n, B, H, C, V] -> [B, L, H, V]
+    o = jnp.moveaxis(jnp.swapaxes(o, 2, 3), 0, 1)
+    return o.reshape(B, L, H, v.shape[-1]), S
+
+
+def delta_rule_step(q, k, v, g, beta, state):
+    """One position a slot of :func:`delta_rule_chunked`'s rule: ``q``,
+    ``k``, ``g`` [S, H, K], ``v`` [S, H, V], ``beta`` [S, H] and the state
+    [S, H, K, V], float32.  Returns ``(o [S, H, V], state')``.  The
+    products over ``K`` are elementwise and summed in float32; the state
+    is read for the decay and both products, and written once."""
+    import jax.numpy as jnp
+    S1 = state * jnp.exp(g)[..., None]
+    u = beta[..., None] * (v - (S1 * k[..., None]).sum(-2))
+    # S'^T q = S1^T q + (k . q) u: the new state is not read back
+    o = (S1 * q[..., None]).sum(-2) + (q * k).sum(-1, keepdims=True) * u
+    return o, S1 + k[..., None] * u[..., None, :]
+
+
+def output_gate(o, gate):
+    """``o * sigmoid(gate)`` in float32, in ``gate``'s type: the
+    elementwise gate on an attention or mixer output before its output
+    projection."""
+    import jax
+    import jax.numpy as jnp
+    return (o.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(gate.dtype)
 
 
 def sub_weights(w, prefix):

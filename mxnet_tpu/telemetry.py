@@ -626,7 +626,8 @@ class _Phase:
 PARTS = ("embed", "attention", "indexer", "conv", "ffn", "experts", "head",
          "loss", "optimizer")
 SUB_PARTS = ("project", "attend", "ring_write", "scores", "top_k", "mask",
-             "router", "sort", "product", "combine", "shared", "health")
+             "router", "sort", "product", "combine", "shared", "health",
+             "short_conv", "scan", "gate")
 
 # Both compile caches key a program with its debug locations stripped,
 # and a scope's name lives there: an executable compiled before a scope

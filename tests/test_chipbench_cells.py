@@ -1,4 +1,4 @@
-"""The benchmark cells PR 28, PR 33 and PR 35 add, rehearsed end to end through
+"""The benchmark's serving cells of the MoE models, rehearsed end to end through
 ``chipbench/run.py --rehearse`` on the CPU (tiny sizes, every value null);
 ``bert_base.pretrain_dp4``'s files wait in the tree for a ``benchmark`` PR
 (PERF.md section 7.1) and are rehearsed with it."""
@@ -18,7 +18,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
                                         ("lfm2_24b.decode_rollout", 1),
                                         ("lfm2_24b.decode_rollout", 0),
                                         ("keye_vl2.decode_doc", 1),
-                                        ("keye_vl2.decode_doc", 0)])
+                                        ("keye_vl2.decode_doc", 0),
+                                        ("solar_open2.decode_rollout", 1),
+                                        ("solar_open2.decode_rollout", 0)])
 def test_run_py_rehearses_the_cell(cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
@@ -53,7 +55,11 @@ def test_run_py_rehearses_the_cell(cell, trace):
                 "compile_s.keye", "overlap_share.keye",
                 "loop_offcpu_us.keye", "emit_to_wire_us.keye",
                 "wire_write_us.keye", "writer_batch_tokens.keye"} <= names
-    elif cell.startswith(("deepseek", "lfm2", "keye")):
+    elif cell.startswith("solar") and trace:
+        assert {"experts_touched.solar", "expert_rows_computed.solar",
+                "kv_rows_read.solar", "batch_occupancy.solar",
+                "compile_s.solar"} <= names
+    elif cell.startswith(("deepseek", "lfm2", "keye", "solar")):
         assert names == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
     else:
         assert "compile_s" in names and result["device"]["count"] == 4

@@ -42,6 +42,7 @@ PARTS_OF = {
     "dsv32": {"embed", "attention", "indexer", "ffn", "experts", "head"},
     "lfm2": {"embed", "attention", "conv", "ffn", "experts", "head"},
     "keye": {"embed", "attention", "indexer", "experts", "head"},
+    "solar": {"embed", "attention", "experts", "head"},
     "lm": {"embed", "attention", "ffn", "head"},
 }
 SUB_PARTS_OF = {
@@ -65,6 +66,15 @@ SUB_PARTS_OF = {
     ("keye", "prefill"): {"attention/attend", "indexer/scores",
                           "indexer/top_k", "indexer/mask",
                           "indexer/ring_write", "experts/product"},
+    ("solar", "decode"): {"attention/project", "attention/short_conv",
+                          "attention/scan", "attention/gate",
+                          "attention/ring_write", "attention/attend",
+                          "experts/router", "experts/product",
+                          "experts/shared"},
+    ("solar", "prefill"): {"attention/project", "attention/short_conv",
+                           "attention/scan", "attention/gate",
+                           "attention/attend", "attention/ring_write",
+                           "conv/ring_write", "experts/product"},
     ("lm", "decode"): {"attention/ring_write", "attention/project",
                        "attention/attend"},
     ("lm", "prefill"): {"attention/project", "attention/attend",
@@ -105,7 +115,8 @@ def serving_programs():
     from mxnet_tpu import models
     from mxnet_tpu.serving import GenerationEngine
     makers = {"dsv32": models.tiny_v32, "lfm2": models.tiny_lfm2,
-              "keye": models.tiny_keye, "lm": models.tiny_lm}
+              "keye": models.tiny_keye, "solar": models.tiny_solar,
+              "lm": models.tiny_lm}
     texts = {}
 
     def get(model, role):
@@ -333,7 +344,7 @@ def test_ten_metrics_and_their_benchmark_entries():
         cell_of[common.load("configs", w["config"])["job"]] = w["name"]
     entries = {m["name"]: m for m in bench["per_layer"]}
     metrics = new_metrics()
-    assert len(metrics) == 10
+    assert len(metrics) == 14
     for m in metrics:
         assert entries[m["name"]]["workloads"] == \
             [cell_of[j] for j in m["jobs"]]
