@@ -17,6 +17,7 @@ import numpy as onp
 
 from .. import initializer as init
 from .. import random as _random
+from ..ops import delta_rule_step as _drs
 from ..ops import grouped_ring_attention as _gra
 from ..ops import sparse_prefill_attention as _spa
 from ..telemetry import part
@@ -350,18 +351,39 @@ def delta_rule_chunked(q, k, v, g, beta, state, chunk):
     return o.reshape(B, L, H, v.shape[-1]), S
 
 
-def delta_rule_step(q, k, v, g, beta, state):
+def delta_rule_step(q, k, v, g, beta, state, act):
     """One position a slot of :func:`delta_rule_chunked`'s rule: ``q``,
-    ``k``, ``g`` [S, H, K], ``v`` [S, H, V], ``beta`` [S, H] and the state
-    [S, H, K, V], float32.  Returns ``(o [S, H, V], state')``.  The
-    products over ``K`` are elementwise and summed in float32; the state
-    is read for the decay and both products, and written once."""
+    ``k``, ``g`` [S, H, K], ``v`` [S, H, V], ``beta`` [S, H], the state
+    [S, H, K, V] and ``act`` [S]: the active slots' states move on, the
+    others' are kept bit for bit.  Returns ``(o [S, H, V] float32, state'
+    in the state's type, passes)``: ``passes`` is how many times the step
+    moved each state, reads and writes counted.  The rule is float32, its
+    products over ``K`` elementwise and summed in float32.
+
+    * on one TPU, for a float32 state a block of heads divides,
+      :func:`mxnet_tpu.ops.delta_rule_step.delta_rule_step` reads each
+      state once and writes it back in place: 2 passes;
+    * on a CPU, under a mesh, for a state of another type, or where the
+      compiler refuses the kernel, XLA splits the rule at its reduction
+      over ``K``: each state is read for ``S1^T k`` and ``S1^T q``, read
+      again for the decay, the rank-one write and the select, and written:
+      3 passes."""
     import jax.numpy as jnp
-    S1 = state * jnp.exp(g)[..., None]
+    S, H, K = q.shape
+    V = v.shape[-1]
+    heads = _drs.kernel_heads(S, H, K, V, state.dtype)
+    if heads is not None:
+        o, new = _drs.delta_rule_step(q, k, v, g, beta, state, act,
+                                      heads=heads)
+        return o, new, 2
+    S0 = state.astype(jnp.float32)
+    S1 = S0 * jnp.exp(g)[..., None]
     u = beta[..., None] * (v - (S1 * k[..., None]).sum(-2))
     # S'^T q = S1^T q + (k . q) u: the new state is not read back
     o = (S1 * q[..., None]).sum(-2) + (q * k).sum(-1, keepdims=True) * u
-    return o, S1 + k[..., None] * u[..., None, :]
+    new = S1 + k[..., None] * u[..., None, :]
+    return o, jnp.where(act[:, None, None, None] > 0,
+                        new.astype(state.dtype), state), 3
 
 
 def output_gate(o, gate):

@@ -111,6 +111,11 @@ STEP_COUNTERS = (
                      "kernel ran, the whole ring where the einsums did"),
     ("delta_state_kib", "KiB of delta-rule state the step read and wrote "
                         "(each once), summed over slots and KDA layers"),
+    ("delta_state_kib_moved", "KiB of delta-rule state the step actually "
+                              "read and wrote, summed over slots and KDA "
+                              "layers: twice the state where the kernel "
+                              "ran, three times where XLA's two fusions "
+                              "did"),
 )
 
 
@@ -198,9 +203,8 @@ def _kda_step(c, w, h, conv_state, state, act):
     """One position a slot of the stream ``h`` [S, d] against its conv
     state [S, 3 * 3HK] and delta state [S, H, K, V]: both move on in the
     active slots, the others keep theirs.  Returns ``(h + out, conv state,
-    delta state)``."""
-    jnp = _jnp()
-    f32 = jnp.float32
+    delta state, passes over the delta state)``
+    (:func:`parts.delta_rule_step`)."""
     with part("attention"):
         with part("project"):
             x = _rms(h, w["op_norm"], c.rms_norm_eps)
@@ -210,10 +214,8 @@ def _kda_step(c, w, h, conv_state, state, act):
                                             act)
         q, k, v, g, beta = _kda_inputs(c, w, x, z)
         with part("scan"):
-            o, new = delta_rule_step(q, k, v, g, beta, state.astype(f32))
-            state = jnp.where(act[:, None, None, None] > 0,
-                              new.astype(state.dtype), state)
-        return _kda_output(c, w, h, x, o), conv_state, state
+            o, state, passes = delta_rule_step(q, k, v, g, beta, state, act)
+        return _kda_output(c, w, h, x, o), conv_state, state, passes
 
 
 def _gqa_qkv(c, w, h):
@@ -390,11 +392,13 @@ def decode(c, w, tok, caches, pos, active=None, want_selections=False):
     for i, kind in enumerate(c.layer_types):
         lw = _sub(w, f"layers.{i}.")
         if kind == "kda":
-            x, conv_state, state = _kda_step(c, lw, x, *caches[i], act)
+            x, conv_state, state, passes = _kda_step(c, lw, x, *caches[i],
+                                                     act)
             new.append((conv_state, state))
             with part("attention"):
                 kib = onp.prod(state.shape[1:]) * state.dtype.itemsize // 1024
                 counts = counts.at[7].add(act.sum() * int(2 * kib))
+                counts = counts.at[8].add(act.sum() * int(passes * kib))
         else:
             x, ring_k, ring_v, n_valid, rows_read = _gqa_step(
                 c, lw, x, *caches[i], pos, act)

@@ -409,3 +409,44 @@ def test_the_grouped_product_compiles_for_a_v5e_at_the_cells_shapes(
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
+
+
+def test_the_delta_rule_kernel_compiles_for_a_v5e_at_solars_widths(one_chip):
+    """A decode step's delta rule of Solar's cell, 128 slots x 64 heads of
+    128 x 128 float32 states, at the block of heads the dispatch picks:
+    Mosaic takes the kernel, the donated state is its output (aliased, no
+    copy of it in the program) and the program keeps no state-sized
+    temporary.  (Here and not in ``test_delta_rule_kernel.py``: one file
+    describes the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops import delta_rule_step as drs
+    slots, heads, width = 128, 64, 128
+    state = f"f32[{slots},{heads},{width},{width}]"
+
+    def shape(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+    row = shape(slots, heads, width)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(functools.partial(
+            drs.delta_rule_step, heads=drs.pick_heads(heads)),
+            donate_argnums=(5,)).lower(
+            row, row, row, row, shape(slots, heads),
+            shape(slots, heads, width, width),
+            shape(slots, dt=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert state in text
+    assert not [line for line in text.splitlines()
+                if f"{state}" in line and " copy(" in line]
+    state_bytes = slots * heads * width * width * 4
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == state_bytes
+    assert stats.temp_size_in_bytes < state_bytes // 64
+    # the state and o, and the outputs' tuple table
+    o_bytes = slots * heads * width * 4
+    assert 0 <= stats.output_size_in_bytes - state_bytes - o_bytes <= 4096

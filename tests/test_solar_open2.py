@@ -5,6 +5,7 @@ of cache a slot holds (the convolution's rows and a float32 delta-rule
 state with no position axis, key and value rings) through the generation
 engine, the share of the experts a chip holds, and the benchmark's
 check."""
+import functools
 import os
 import sys
 
@@ -180,8 +181,9 @@ def test_the_chunked_scan_is_the_recurrence_with_padded_tails(L, valid):
     # the one-step form, position by position, from the chunked state
     S1 = S
     for t in range(3):
-        o1, S1 = parts.delta_rule_step(q[:, t], k[:, t], v[:, t], g[:, t],
-                                       beta[:, t], S1)
+        o1, S1, _passes = parts.delta_rule_step(
+            q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], S1,
+            jnp.ones((B,), jnp.int32))
         want = (S1 * q[:, t, :, :, None]).sum(-2)
         assert onp.abs(onp.asarray(o1 - want)).max() < 1e-5
 
@@ -255,6 +257,59 @@ def test_a_slot_that_sits_out_a_step_keeps_its_states_and_rings():
         nd.array(onp.asarray([P, P + 1], "int32")))
     assert onp.abs(lg.asnumpy()[0] - full[0, P]).max() < TOL
     assert onp.abs(lg.asnumpy()[1] - full[1, P + 1]).max() < TOL
+
+
+def test_decode_with_the_kernel_is_decode_in_the_xla_form(monkeypatch):
+    """Three decode steps of three slots, a different one sitting out each
+    step, with the delta rule's kernel (interpreted, two blocks of heads)
+    against the XLA form: the same logits and states to float32 rounding,
+    a sitting slot's delta states bit for bit, and the step counter of the
+    state moved reading 1.0 times ``delta_state_kib`` with the kernel
+    (read once, written once) and 1.5 times with XLA's two fusions."""
+    from mxnet_tpu.ops import delta_rule_step as drs
+    net = _net()
+    P, M, B = 12, 24, 3
+    toks = _tokens(P + 4, batch=B, seed=6)
+    _lg, kvs = net.prefill(nd.array(toks[:, :P]))
+
+    def run():
+        caches = _caches(kvs, M, P)
+        pos = onp.full(B, P, "int32")
+        steps = []
+        for t in range(3):
+            act = onp.ones(B, "float32")
+            act[t] = 0.0
+            before = [layer[1].asnumpy() for layer, kind in
+                      zip(caches, kinds_of(caches)) if kind == ("kda",)]
+            lg, caches, counts = net.decode_step(
+                nd.array(toks[onp.arange(B), pos]), caches, nd.array(pos),
+                active=nd.array(act))
+            after = [layer[1].asnumpy() for layer, kind in
+                     zip(caches, kinds_of(caches)) if kind == ("kda",)]
+            for was, now in zip(before, after):
+                assert (now[t] == was[t]).all()
+            steps.append((lg.asnumpy(), after,
+                          dict(zip(COUNTERS, counts.asnumpy()))))
+            pos = pos + (act > 0)
+        return steps
+
+    xla = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(drs, "kernel_heads", lambda *a: 2)
+        patch.setattr(drs, "delta_rule_step", functools.partial(
+            drs.delta_rule_step, interpret=True))
+        kernel = run()
+    for (lg_k, st_k, n_k), (lg_x, st_x, n_x) in zip(kernel, xla):
+        assert onp.abs(lg_k - lg_x).max() < 1e-5
+        for a, b in zip(st_k, st_x):
+            assert onp.abs(a - b).max() < 1e-5
+        # two riders x 3 KDA layers x 4 heads x 8 x 8 float32, twice
+        assert n_k["delta_state_kib"] * 1024 == 2 * 3 * 4 * 8 * 8 * 4 * 2
+        assert n_k["delta_state_kib"] == n_x["delta_state_kib"]
+        assert n_k["delta_state_kib_moved"] == n_k["delta_state_kib"]
+        assert 2 * n_x["delta_state_kib_moved"] == 3 * n_x["delta_state_kib"]
+        assert {k: v for k, v in n_k.items() if "delta" not in k} \
+            == {k: v for k, v in n_x.items() if "delta" not in k}
 
 
 def test_no_position_signal_a_shifted_ring_gives_the_same_logits():
