@@ -44,8 +44,8 @@ from ..ndarray.ndarray import NDArray, unwrap
 from ..parallel import moe as _moe
 from ..ops import latent_ring_attention as _lra
 from .parts import (LANES, FanInNormal as _FanInNormal, index_scores,
-                    layer_norm as _layernorm, matmul as _mm, part,
-                    rms_norm as _rms, rope as _rope, selection_mask,
+                    layer_norm as _layernorm, mask_positions,
+                    matmul as _mm, part, rms_norm as _rms, rope as _rope,
                     sparse_block_attend, sub_weights as _sub, topk_mask)
 
 __all__ = ["DeepSeekV32LM", "V32_PUBLISHED", "tiny_v32", "run_full", "decode",
@@ -102,6 +102,10 @@ STEP_COUNTERS = (
                              "experts multiplied (row tiles visited x tile "
                              "rows), summed over the expert layers: over "
                              "the held pairs, the product's redundancy"),
+    ("index_tie_rows", "rows (slot x layer) in which scores equal to the "
+                       "k-th straddled the cut, so that the lowest "
+                       "positions were kept: where the selection can "
+                       "differ from top_k's own choice"),
 )
 
 
@@ -232,7 +236,7 @@ def _attn_full(c, w, h, pos, index_topk, want_mask):
             scores = None
             if sparse:
                 scores = index_scores(rows(qi), rows(wi), ki)
-                mask = topk_mask(scores, mask, index_topk)
+                mask, _tie = topk_mask(scores, mask, index_topk)
         with part("attention"), part("attend"):
             q = jnp.moveaxis(jnp.concatenate([rows(q_nope), rows(q_rope)],
                                              axis=-1), 2, 1)  # [B, H, bq, .]
@@ -315,8 +319,9 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     """One token a slot, ``tok`` [S] at ``pos`` [S], through the rings
     ``caches`` = [(latent [S, M, stride], indexer [S, M, Di]) a layer].
     The new rows land at ``pos % M`` of the active slots (one scatter a
-    ring); the indexer scores the slot's valid positions, ``top_k`` keeps
-    ``index_topk`` of them, and attention runs in the absorbed form over
+    ring); the indexer scores the slot's valid positions,
+    :func:`parts.topk_mask` keeps ``index_topk`` of them as a mask, and
+    attention runs in the absorbed form over
     the selected latent rows alone, in one of two forms that share no
     line and are chosen by what the code sees, with no switch:
 
@@ -326,8 +331,8 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
       skips the blocks past a slot's valid positions and writes neither
       rows, scores nor probabilities to memory;
     * on a CPU, under a mesh, or where the compiler refuses the kernel, a
-      gather of the selected rows and plain einsums: the kernel's tested
-      reference.
+      gather of the rows the mask keeps and plain einsums: the kernel's
+      tested reference.
 
     At 64 slots x 6,144 on a v5e masked dense attention over the whole
     ring took 2.5 times as long as the gather form (PERF.md, PR 28); the
@@ -372,14 +377,9 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
             valid = jnp.arange(M)[None, :] < n_valid[:, None]
             scores = index_scores(qi, wi, ring_i.astype(x.dtype))[:, 0]
             K = min(index_topk, M)
-            with part("top_k"):
-                vals, chosen = jax.lax.top_k(
-                    jnp.where(valid, scores, -jnp.inf), K)           # [S, K]
-                keep = vals > -jnp.inf
+            mask, tie = topk_mask(scores, valid, K)
             block = _lra.kernel_block(S, H, kvr, c.qk_rope_head_dim, M,
                                       ring_l.shape[2], x.dtype, ring_l.dtype)
-            if want_selections or block is not None:
-                mask = selection_mask(chosen, keep, M)
         if want_selections:
             sel["positions"].append(mask)
         sel["index_scores"].append(scores)
@@ -395,6 +395,7 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
                         block=block)
                     rows_read = _lra.rows_visited(n_valid, block)
                 else:
+                    chosen, keep = mask_positions(mask, K)
                     rows = jnp.take_along_axis(ring_l, chosen[:, :, None],
                                                axis=1).astype(x.dtype)
                     s = jnp.einsum("shc,skc->shk", q_abs, rows[..., :kvr],
@@ -412,8 +413,10 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
                                preferred_element_type=f32).astype(x.dtype)
                 x = x + _mm(o.reshape(S, 1, H * dv), lw["wo"])
             seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
-                                     rows_read])).sum(axis=1).astype(jnp.int32)
-            counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2])
+                                     rows_read, tie])).sum(axis=1).astype(
+                jnp.int32)
+            counts = counts.at[:2].add(seen[:2]).at[6].add(seen[2]) \
+                .at[8].add(seen[3])
         x, idx, router_scores, load = _ffn(c, lw, i, x, weight=act)
         new.append((ring_l, ring_i))
         if idx is not None:

@@ -21,10 +21,12 @@ bias but the indexer's LayerNorm.  Weights are stored [in, out].
   ReLU(q^I_j(t) . k^I(s))`` over ``indexer_num_heads`` small heads and one
   key head, both rotated over the whole head with the sections halved; a
   query attends only to the ``topk`` positions ``s <= t`` of largest
-  ``I``, one set for all heads: ``top_k``'s own indices, ties and all, as
-  a mask (:func:`mxnet_tpu.models.parts.selection_mask`) in the
-  full-sequence form and the one-step form alike.  **The indexer's cache
-  row is ``k^I``.**  So a slot holds three rings a layer.
+  ``I``, one set for all heads, as a mask
+  (:func:`mxnet_tpu.models.parts.topk_mask`: ``top_k``'s set but for
+  which of scores exactly equal to the k-th it keeps, the lowest
+  positions) in the full-sequence form and the one-step form alike.
+  **The indexer's cache row is ``k^I``.**  So a slot holds three rings a
+  layer.
 * **Experts.**  :func:`mxnet_tpu.parallel.moe.dropless_moe`, the layer
   ``DeepSeekV32LM`` and ``LFM2MoeLM`` run, with ``scoring="softmax"``, one
   group, no selection bias and no shared expert.
@@ -52,8 +54,7 @@ from .parts import (LANES, DrawnBias as _DrawnBias, FanInNormal,
                     grouped_ring_attend, index_scores,
                     layer_norm as _layernorm, matmul as _mm, part,
                     rms_norm as _rms, rope as _rope, sectioned_angles,
-                    selection_mask, sparse_block_attend,
-                    sub_weights as _sub)
+                    sparse_block_attend, sub_weights as _sub, topk_mask)
 
 __all__ = ["KeyeVL2LM", "KEYE_PUBLISHED", "tiny_keye", "trunk", "head",
            "run_full", "decode", "STEP_COUNTERS"]
@@ -103,6 +104,10 @@ STEP_COUNTERS = (
                              "experts multiplied (row tiles visited x tile "
                              "rows), summed over the expert layers: over "
                              "the held pairs, the product's redundancy"),
+    ("index_tie_rows", "rows (slot x layer) in which scores equal to the "
+                       "k-th straddled the cut, so that the lowest "
+                       "positions were kept: where the selection can "
+                       "differ from top_k's own choice"),
 )
 
 
@@ -159,9 +164,10 @@ def _attn_full(c, w, h, pos3, index_topk, want_sel):
     ``(the stream ``h`` with its output added, k rows [B, L, KV * D], v
     rows, k^I [B, L, Di], positions, index scores)``: the last two on
     request and only where the sequence is longer than
-    ``index_topk`` (below it nothing is scored), the selection as
-    ``top_k`` gave it, ``[B, L, K]`` indices (-1 where a query has fewer
-    valid positions) and their scores."""
+    ``index_topk`` (below it nothing is scored), the selection
+    :func:`parts.topk_mask` made, as ``[B, L, K]`` indices by descending
+    score (-1 where a query has fewer valid positions) and their
+    scores."""
     import jax
     jnp = _jnp()
     B, L, _ = h.shape
@@ -184,14 +190,9 @@ def _attn_full(c, w, h, pos3, index_topk, want_sel):
             chosen = vals = None
             if sparse:
                 scores = index_scores(rows(qi), rows(wi), ki)   # [B, bq, L]
-                with part("top_k"):
-                    vals, chosen = jax.lax.top_k(
-                        jnp.where(mask, scores, -jnp.inf), K)
-                    keep = vals > -jnp.inf
-                mask = selection_mask(chosen.reshape(B * bq, K),
-                                      keep.reshape(B * bq, K), L
-                                      ).reshape(B, bq, L)
-                chosen = jnp.where(keep, chosen, -1)
+                mask, _tie = topk_mask(scores, mask, K)
+                if want_sel:
+                    chosen, vals = _as_indices(scores, mask, K)
         with part("attention"), part("attend"):
             # [B, KV, G * bq, D]: a key head's query heads one after another
             qb = jnp.transpose(rows(q).reshape(B, bq, KV, G, D),
@@ -212,6 +213,17 @@ def _attn_full(c, w, h, pos3, index_topk, want_sel):
         chosen, vals = whole(chosen), whole(vals)
     return h, k.reshape(B, L, KV * D), v.reshape(B, L, KV * D), ki, \
         chosen, vals
+
+
+def _as_indices(scores, mask, K):
+    """The positions ``mask`` [..., N] keeps as ``top_k`` would list them
+    (``[..., K]`` indices by descending score, -1 past the kept) and
+    their scores: the probed programs' form of the selection."""
+    import jax
+    jnp = _jnp()
+    with part("top_k"):
+        vals, chosen = jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), K)
+        return jnp.where(vals > -jnp.inf, chosen, -1), vals
 
 
 def _ffn(c, w, h, weight=None):
@@ -304,8 +316,8 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
     equal), through ``caches`` = [(k ring [S, M, KV * D], v ring, indexer
     ring [S, M, stride]) a layer].  The new rows land at ``pos % M`` of
     the active slots (one scatter a ring); the indexer scores the slot's
-    valid positions, ``top_k`` keeps ``index_topk`` of them, and every
-    head attends over the rings under that selection as a mask
+    valid positions, :func:`parts.topk_mask` keeps ``index_topk`` of them
+    as a mask, and every head attends over the rings under it
     (:func:`parts.grouped_ring_attend`: on one TPU a kernel over the
     slot's valid blocks, else einsums over the whole rings).  At 40 slots
     x 12,288 with contexts of 6.4-9.9 k on a v5e the masked einsums took
@@ -353,11 +365,7 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
             scores = index_scores(qi[:, None], wi[:, None],
                                   ring_i.astype(x.dtype))[:, 0]  # [S, M]
             K = min(index_topk, M)
-            with part("top_k"):
-                vals, chosen = jax.lax.top_k(
-                    jnp.where(valid, scores, -jnp.inf), K)
-                keep = vals > -jnp.inf
-            mask = selection_mask(chosen, keep, M)
+            mask, tie = topk_mask(scores, valid, K)
         with part("attention"):
             with part("attend"):
                 o, rows_read = grouped_ring_attend(q, ring_k, ring_v,
@@ -366,14 +374,18 @@ def decode(c, w, tok, caches, pos, active=None, index_topk=None,
                 x = x + _mm(o.astype(x.dtype), lw["wo"])
         x, idx, router_scores, load = _ffn(c, lw, x, weight=act)
         new.append((ring_k, ring_v, ring_i))
-        sel["positions"].append(jnp.where(keep, chosen, -1))
-        sel["index_scores"].append(vals)
-        sel["experts"].append(idx)
-        sel["router_scores"].append(router_scores)
+        if want_selections:
+            with part("indexer"):
+                chosen, vals = _as_indices(scores, mask, K)
+            sel["positions"].append(chosen)
+            sel["index_scores"].append(vals)
+            sel["experts"].append(idx)
+            sel["router_scores"].append(router_scores)
         with part("indexer"):
             seen = (act * jnp.stack([n_valid, jnp.minimum(n_valid, K),
-                                     rows_read, n_valid])).sum(axis=1)
-            counts = counts.at[:4].add(seen.astype(jnp.int32))
+                                     rows_read, n_valid, tie])).sum(axis=1)
+            seen = seen.astype(jnp.int32)
+            counts = counts.at[:4].add(seen[:4]).at[8].add(seen[4])
         with part("experts"):
             counts = counts.at[4].add(load[0]).at[5].add(load[2])
             counts = counts.at[6].max(load[3]).at[7].add(load[4])

@@ -20,17 +20,18 @@ from .. import random as _random
 from ..ops import delta_rule_step as _drs
 from ..ops import grouped_ring_attention as _gra
 from ..ops import sparse_prefill_attention as _spa
+from ..ops import topk_select as _tks
 from ..telemetry import part
 
 __all__ = ["rms_norm", "layer_norm", "l2_norm", "matmul", "rope",
-           "sectioned_angles", "index_scores", "topk_mask", "selection_mask",
+           "sectioned_angles", "index_scores", "topk_mask", "mask_positions",
            "grouped_ring_attend", "sparse_block_attend", "short_conv",
            "short_conv_step", "delta_rule_chunked", "delta_rule_step",
            "output_gate", "sub_weights", "FanInNormal", "DrawnBias", "LANES",
            "part"]
 
 # the chip's lane width: a ring whose row is a multiple of it lies with the
-# rows contiguous, and :func:`selection_mask` splits a position by it
+# rows contiguous
 LANES = 128
 
 
@@ -112,44 +113,55 @@ def index_scores(qi, wi, ki):
 
 
 def topk_mask(scores, valid, k):
-    """The ``k`` largest of ``scores`` [..., N] among ``valid``, as a
-    mask (all of ``valid`` where it has no more than ``k``)."""
-    import jax
+    """The ``k`` largest of ``scores`` [..., N] float32 among ``valid``, as
+    a mask: exactly ``min(k, n_valid)`` a row (all of ``valid`` where it
+    has no more than ``k``), and ``tie`` [...] bool, the rows in which
+    scores exactly equal to the k-th straddled the cut.  The set is
+    ``lax.top_k``'s except in which of such ties it keeps: the lowest
+    positions here, an order of its own in ``top_k`` on a TPU.  With no
+    sort and no index: the k-th largest score is found by bisection over
+    its bits, and the mask is a comparison
+    (:mod:`mxnet_tpu.ops.topk_select`):
+
+    * on one TPU, :func:`mxnet_tpu.ops.topk_select.topk_select`, one
+      Pallas call over blocks of rows held in VMEM;
+    * on a CPU, under a mesh, or where the compiler refuses the kernel,
+      the same passes in ``jnp``, equal to it bit for bit.
+
+    At the sparse cells' decode shapes on a v5e a sort by ``top_k`` and
+    one-hot products that made its indices a mask took 0.40 ms a layer
+    (DeepSeek, 64 x 6,144) and 0.39 (Keye, 40 x 12,288), the kernel 0.036
+    and 0.045; a Keye prefill block of 512 x 8,192, 2.0 ms against 0.26
+    (PERF.md, section 6)."""
     import jax.numpy as jnp
-    if k >= scores.shape[-1]:
-        return valid
+    lead, N = scores.shape[:-1], scores.shape[-1]
+    if k >= N:
+        return valid, jnp.zeros(lead, bool)
     with part("top_k"):
-        masked = jnp.where(valid, scores, -jnp.inf)
-        kth = jax.lax.top_k(masked, k)[0][..., -1:]
+        key = _tks.keys(scores, valid).reshape(-1, N)
+        rows = _tks.kernel_rows(key.shape[0], N, k)
+        if rows is not None:
+            mask, tie = _tks.topk_select(key, k, rows=rows)
+        else:
+            t, cut, tie = _tks.threshold_xla(key, k)
     with part("mask"):
-        return valid & (masked >= kth)
+        if rows is None:
+            mask = _tks.mask_at(key, t, cut)
+        return mask.reshape(scores.shape), tie.reshape(lead)
 
 
-def selection_mask(chosen, keep, ring_len):
-    """``top_k``'s indices ``chosen`` [S, K] (distinct a slot) as a mask
-    [S, ring_len], true at ``chosen[s, k]`` where ``keep[s, k]``: the
-    selection itself, ties and all (on a TPU ``top_k`` does not break ties
-    by position, so no threshold on the scores gives it).  Where the ring
-    is whole lanes the mask is the product of two one-hot matrices,
-    ``position // 128`` [S, ring_len / 128, K] and ``position % 128``
-    [S, K, 128]: exact, a position being chosen at most once, and one
-    fusion on the matrix unit where a scatter of S x K single elements is
-    a loop over them (0.25 ms against 0.71 a layer at 64 x 2,048 into
-    6,144 on a v5e: PERF.md, PR 34)."""
+def mask_positions(mask, K):
+    """The positions ``mask`` [S, N] keeps, at most ``K`` a row, as
+    ``(indices [S, K] int32 in ascending order, keep [S, K])``: a scatter
+    of each kept position to its rank, no sort."""
     import jax.numpy as jnp
-    S = chosen.shape[0]
-    with part("mask"):
-        if ring_len % LANES:
-            return jnp.zeros((S, ring_len), bool).at[
-                jnp.arange(S)[:, None], chosen].set(keep)
-        high = jnp.where(keep, chosen // LANES, -1)[:, None, :]
-        low = (chosen % LANES)[:, :, None]
-        high = (high == jnp.arange(ring_len // LANES)[None, :, None])
-        low = (low == jnp.arange(LANES)[None, None, :])
-        hits = jnp.einsum("sak,skb->sab", high.astype(jnp.bfloat16),
-                          low.astype(jnp.bfloat16),
-                          preferred_element_type=jnp.float32)
-        return (hits > 0).reshape(S, ring_len)
+    S, N = mask.shape
+    rank = jnp.where(mask, jnp.cumsum(mask, axis=-1) - 1, K)
+    chosen = jnp.zeros((S, K), jnp.int32).at[
+        jnp.arange(S)[:, None], rank].set(
+        jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (S, N)),
+        mode="drop")
+    return chosen, jnp.arange(K)[None, :] < mask.sum(-1, keepdims=True)
 
 
 def grouped_ring_attend(q, ring_k, ring_v, n_valid, mask=None):

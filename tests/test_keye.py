@@ -218,9 +218,11 @@ def test_cache_spec_names_three_rings_a_layer():
 
 @pytest.mark.parametrize("ring_len", [128, 48], ids=["lanes", "scatter"])
 def test_a_decode_steps_selection_is_top_ks_own(ring_len):
-    """Both branches of ``parts.selection_mask`` under the model: prefill,
-    then steps whose contexts are past ``index_topk``: the positions each
-    step selected are the full forward's row, and so are its logits."""
+    """A ring of whole lanes and one that is not, under the model:
+    prefill, then steps whose contexts are past ``index_topk``: the
+    positions each step selected (``parts.topk_mask`` over the ring) are
+    the full forward's row (the same function over the sequence), and so
+    are its logits."""
     net = _net()
     P, N = 20, 4
     toks = _tokens(P + N, batch=2, seed=2)

@@ -11,7 +11,7 @@ import numpy as onp
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu.models import deepseek, parts, tiny_v32
+from mxnet_tpu.models import deepseek, tiny_v32
 from mxnet_tpu.ops import latent_ring_attention as lra
 
 COUNTERS = [name for name, _help in deepseek.STEP_COUNTERS]
@@ -113,28 +113,6 @@ def test_no_kernel_on_a_cpu_or_for_a_ring_no_block_divides():
                                   0.3)                 # 32 rows
 
 
-@pytest.mark.parametrize("ring_len", [256, 48], ids=["lanes", "scatter"])
-def test_selection_mask_is_top_ks_selection_ties_and_all(ring_len):
-    """Both forms of the mask against a loop over ``top_k``'s indices: index
-    scores that tie (quantised), slots with fewer valid positions than K."""
-    slots, K = 4, 20
-    rs = onp.random.RandomState(0)
-    scores = jnp.asarray(onp.round(rs.randn(slots, ring_len) * 2) / 2)
-    n_valid = jnp.asarray([1, 19, 21, ring_len])
-    valid = jnp.arange(ring_len)[None] < n_valid[:, None]
-    vals, chosen = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), K)
-    keep = vals > -jnp.inf
-    got = onp.asarray(parts.selection_mask(chosen, keep, ring_len))
-    want = onp.zeros((slots, ring_len), bool)
-    for s in range(slots):
-        for k in range(K):
-            if keep[s, k]:
-                want[s, int(chosen[s, k])] = True
-    assert got.dtype == bool and (got == want).all()
-    assert got.sum(1).tolist() == [1, 19, 20, 20]
-    assert not got[onp.asarray(~valid)].any()
-
-
 # -- decode -------------------------------------------------------------------
 def _net(seed=3):
     mx.random.seed(seed)
@@ -184,8 +162,7 @@ def test_decode_with_the_kernel_is_decode_in_the_xla_form(monkeypatch,
                                                           ring_len):
     net = _net()
     rings = _rings(net, 3, ring_len)
-    # 40 has wrapped a ring of 32; a ring of 128 is whole lanes, so its
-    # mask is the one-hot product
+    # 40 has wrapped a ring of 32; a ring of 128 is whole lanes
     tok, pos = [5, 6, 7], [3, 17, 40]
     want = _decode(net, tok, rings, pos)
     with monkeypatch.context() as patch:
@@ -208,7 +185,7 @@ def test_decode_with_the_kernel_is_decode_in_the_xla_form(monkeypatch,
                          jnp.where(jnp.isfinite(b), b, 0)) < 2e-5
     got_n, want_n = (dict(zip(COUNTERS, onp.asarray(c))) for c in
                      (got[2], want[2]))
-    assert len(got[2]) == len(want[2]) == len(deepseek.STEP_COUNTERS) == 8
+    assert len(got[2]) == len(want[2]) == len(deepseek.STEP_COUNTERS) == 9
     for name in COUNTERS:
         if name != "latent_rows_read":
             assert got_n[name] == want_n[name]
@@ -450,3 +427,36 @@ def test_the_delta_rule_kernel_compiles_for_a_v5e_at_solars_widths(one_chip):
     # the state and o, and the outputs' tuple table
     o_bytes = slots * heads * width * 4
     assert 0 <= stats.output_size_in_bytes - state_bytes - o_bytes <= 4096
+
+
+# the indexer's selection of the two sparse cells: (rows, ring positions
+# or a prefill's columns) of a decode step and of a prefill's query block
+@pytest.mark.parametrize("R,N", [
+    (64, 6144), (40, 12288), (256, 2816), (512, 3072), (512, 7168),
+    (512, 8192)], ids=["dsv32_decode", "keye_decode", "dsv32_prefill_2816",
+                       "dsv32_prefill_3072", "keye_prefill_7168",
+                       "keye_prefill_8192"])
+def test_the_selection_kernel_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, R, N):
+    """Mosaic takes ``topk_select`` at the rows and chunk the dispatch
+    picks, in the VMEM it asks for: keys in, the mask and a flag a row out,
+    and no sort and no temporary of the keys' size in the program.  (Here
+    and not in ``test_topk_select.py``: one file describes the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops import topk_select as tks
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(functools.partial(
+            tks.topk_select, k=2048, rows=tks.pick_rows(R, N))).lower(
+            jax.ShapeDtypeStruct((R, N), jnp.int32,
+                                 sharding=one_chip)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " sort(" not in text
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes <= R * N * 4
+    assert stats.output_size_in_bytes <= R * N + R + 1024
